@@ -14,6 +14,7 @@ facade keeps the reference's function names and byte semantics.  The
 
 from __future__ import annotations
 
+import os
 from typing import Optional
 
 import jax
@@ -21,7 +22,7 @@ import jax
 __all__ = ["get_device", "set_device", "device_count", "is_compiled_with_cuda",
            "memory_allocated", "memory_reserved", "max_memory_allocated",
            "max_memory_reserved", "memory_stats", "empty_cache", "cuda",
-           "synchronize"]
+           "synchronize", "enable_compile_cache"]
 
 _current = None
 
@@ -101,6 +102,27 @@ def synchronize(device=None) -> None:
             x.block_until_ready()
         except Exception:
             pass
+
+
+# the checkout root (the directory that holds the package): where the
+# persistent compile cache lives when the environment names no other
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it
+    itself and this sets nothing; otherwise the cache is the fixed,
+    git-ignored ``<checkout>/.jax_cache``.  A cache that moves never
+    hits, so the directory is never a temp name, a pid or a timestamp —
+    and no other code path sets one."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
+    return _CHECKOUT_CACHE
 
 
 class _CudaNamespace:

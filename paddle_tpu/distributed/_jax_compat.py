@@ -1,54 +1,14 @@
-"""jax version shim for the distributed stack's ``shard_map``.
+"""The distributed stack's ``shard_map`` / ``axis_size`` import point.
 
-The call sites in this package are written against the current jax
-surface (top-level ``jax.shard_map`` with ``check_vma=`` and
-``axis_names=``).  Older jax (< 0.5) only has
-``jax.experimental.shard_map.shard_map`` with the pre-rename kwargs
-(``check_rep=``, ``auto=`` holding the COMPLEMENT of the manual axes).
-Every call site in the package imports through here so both pins work.
+Thin re-exports of the jax surface this repo is installed against
+(pyproject.toml pins it): top-level ``jax.shard_map`` with
+``check_vma=`` / ``axis_names=``, and ``jax.lax.axis_size``.  Every call
+site in the package imports through here.
 """
 
 from __future__ import annotations
 
-try:
-    from jax import shard_map as _shard_map
-    _PRE_RENAME = False
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _PRE_RENAME = True
+from jax import shard_map
+from jax.lax import axis_size
 
-_UNSET = object()
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=_UNSET,
-              axis_names=_UNSET):
-    kw = {}
-    if not _PRE_RENAME:
-        if check_vma is not _UNSET:
-            kw["check_vma"] = check_vma
-        if axis_names is not _UNSET:
-            kw["axis_names"] = axis_names
-    else:
-        # the pre-rename replication checker has false positives the
-        # current checker does not (e.g. psum-derived replicated outputs
-        # inside scanned pipeline bodies raise _SpecError), so on the old
-        # pin it is off unless the caller explicitly asked for it
-        kw["check_rep"] = check_vma if check_vma is not _UNSET else False
-        if axis_names is not _UNSET:
-            # pre-rename partial-manual mode: ``auto`` names the axes that
-            # STAY automatic, i.e. the complement of the manual set
-            kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
-def axis_size(axis_name):
-    """Static size of a named mapped axis, from inside a shard_map/pmap
-    body.  Current jax spells this ``jax.lax.axis_size``; on older pins
-    the long-standing ``psum(1, axis)`` idiom returns the same value as a
-    concrete Python int (unit constants are reduced at trace time)."""
-    import jax
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
+__all__ = ["shard_map", "axis_size"]

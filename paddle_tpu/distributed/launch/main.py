@@ -10,9 +10,12 @@ Reference call stack (SURVEY.md §3.3):
        (fleet/elastic/manager.py — ElasticManager, max_restart)
 
 TPU-native deltas (documented, deliberate):
-  * one process per HOST (jax single-controller drives all local chips);
-    ``--nproc_per_node`` still exists for CPU-simulation jobs where each
-    process gets a virtual device slice.
+  * one process per HOST (jax single-controller drives all local chips).
+    ``--nproc_per_node`` > 1 is for CPU SIMULATION ONLY, each process
+    with its own virtual devices: a chip belongs to one process at a
+    time, so two workers on a chip host contend for the same chips and
+    fail or hang.  This parent never touches ``jax.devices()`` — a
+    parent that had would hold the chip its one child needs.
   * rendezvous = jax.distributed's coordinator (PADDLE_MASTER ->
     coordinator_address); no etcd — TPU slices fail whole, so elasticity
     is restart-from-checkpoint (§5 "Failure detection"), implemented here

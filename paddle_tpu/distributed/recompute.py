@@ -56,9 +56,18 @@ def remat_wrap(fn: Callable, remat) -> Callable:
     a string names a jax.checkpoint_policies policy."""
     if not remat:
         return fn
+
+    # a fresh function per wrap: jax.checkpoint caches its trace on the
+    # function's identity, and a Layer's parameters are closed over, not
+    # passed — a second trace of the same block (another jit of the same
+    # model at the same shapes) would otherwise be handed the first
+    # trace's jaxpr with that trace's parameter tracers inside it
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
     if isinstance(remat, str):
-        return jax.checkpoint(fn, policy=resolve_remat_policy(remat))
-    return jax.checkpoint(fn)
+        return jax.checkpoint(call, policy=resolve_remat_policy(remat))
+    return jax.checkpoint(call)
 
 
 def recompute(function: Callable, *args, **kwargs):

@@ -5,8 +5,11 @@ multiprocessing entry that forks N workers with the trainer env contract
 set (SURVEY.md §2.4 "spawn").
 
 TPU-native note: on a real TPU host a single process drives all local
-chips, so nprocs defaults to 1; multi-process spawn is chiefly for
-CPU-simulated multi-host tests (each child gets its own JAX runtime).
+chips, so nprocs defaults to 1.  Multi-process spawn is for
+CPU-SIMULATED multi-host tests only (each child gets its own JAX
+runtime): a chip belongs to one process at a time, and a parent that
+has touched JAX holds the chip — children spawned from it that need
+the chip fail or hang.
 Uses the 'spawn' start method — fork would inherit an initialized,
 multithreaded JAX runtime.
 """

@@ -183,17 +183,12 @@ class HybridCommunicateGroup:
         missing #3)."""
         arr = np.asarray(devices)
         if arr.size > 1:
-            try:
-                from jax.experimental import mesh_utils
-                return mesh_utils.create_device_mesh(
-                    tuple(shape), devices=list(devices),
-                    allow_split_physical_axes=True)
-            except Exception as e:
-                import warnings
-                warnings.warn(
-                    f"ICI-aware mesh assignment unavailable ({e}); "
-                    f"falling back to enumeration-order reshape",
-                    RuntimeWarning, stacklevel=2)
+            # no enumeration-order fallback: a mesh that ignores the
+            # torus still runs, only slower, and nothing would say why
+            from jax.experimental import mesh_utils
+            return mesh_utils.create_device_mesh(
+                tuple(shape), devices=list(devices),
+                allow_split_physical_axes=True)
         return arr.reshape(shape)
 
     # --- mesh access (TPU-native surface) ------------------------------

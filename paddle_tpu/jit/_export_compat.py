@@ -1,19 +1,12 @@
-"""jax.export version shim for the AOT save/load paths.
+"""The AOT save/load paths' ``jax.export`` import point.
 
-jit.save / save_program are written against the public ``jax.export``
-module (jax >= 0.5 surface).  Some older pins ship the identical
-functionality only under ``jax._src.export`` (the public alias is
-absent).  Everything in this package resolves the four symbols it needs
-through here so both pins work.
+Thin re-export of the four public ``jax.export`` symbols jit.save /
+save_program use; everything in this package resolves them through
+here.
 """
 
 from __future__ import annotations
 
-try:
-    from jax.export import (SymbolicScope, deserialize, export,
-                            symbolic_shape)
-except ImportError:
-    from jax._src.export._export import deserialize, export
-    from jax._src.export.shape_poly import SymbolicScope, symbolic_shape
+from jax.export import SymbolicScope, deserialize, export, symbolic_shape
 
 __all__ = ["export", "deserialize", "symbolic_shape", "SymbolicScope"]

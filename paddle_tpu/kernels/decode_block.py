@@ -1,5 +1,5 @@
-"""Decode-block megakernel: a transformer layer's decode step as two
-VMEM-resident Pallas TPU kernels.
+"""Decode-block megakernel: a transformer layer's decode step as
+tile-streaming Pallas TPU kernels.
 
 Reference: the whole-layer fusion of
 paddle/phi/kernels/fusion/gpu/fused_multi_transformer_op.cu — the
@@ -10,36 +10,42 @@ same point for modern serving: decode latency lives at BLOCK-level
 fusion, because the [B, 1, D] activation is tiny and every per-op HBM
 round-trip costs more than the compute it carries.
 
-Kernel pair (one grid for the whole layer would have to keep QKV +
+Kernels (one grid for the whole layer would have to keep QKV +
 out-proj + both MLP matrices resident at once — infeasible past small
 hidden sizes under the ~16 MB VMEM budget, so the layer splits at its
-natural seam):
+natural seams; every weight STREAMS in tiles, nothing is resident):
 
-  * **attention block** — grid ``(KH, B)`` (kv-head outer so each
-    weight slice streams from HBM exactly ONCE; slot inner).  Per
-    program: fused LayerNorm/RMSNorm of the slot's [1, D] row -> q/k/v
-    projection for this kv-head's query group (GQA: ``rep`` q heads per
-    program as one [1, D] x [D, rep*Dh] matmul) -> optional rotary
-    embedding (matrix form: ``x*cos + (x@R)*sin`` with a constant
-    rotate-half matrix — no lane-slicing, Mosaic-friendly at any head
-    dim) -> the fresh K/V row is DMA'd **in-kernel** into the
+  * **norm + projection** — grid ``(N // bn,)``, once each for q, k and
+    v: fused LayerNorm/RMSNorm of the ``[B, D]`` rows into VMEM scratch
+    at step 0, then one ``[B, D] x [D, bn]`` dot per streamed weight
+    column tile (all slots share each MXU weight load).
+  * **slab attention** — grid ``(B,)``, one slot per program over ALL
+    its kv heads (the slabs are ``[B, S, KH, Dh]`` with ``(KH, Dh)``
+    the tiled minor dims, so whole-head rows are the only slab window a
+    DMA can address): optional rotary embedding (matrix form:
+    ``x*cos + (x@R)*sin`` with a constant rotate-half matrix — no
+    lane-slicing) -> the fresh K/V row is DMA'd **in-kernel** into the
     ``serving.kv_pool`` slot slab at this slot's ``seq_pos`` (the slab
     rides through as an aliased ANY-space operand, so the pool buffer
     is updated in place — no extra copy of the slab, ever) -> decode
-    attention streams the slab's live tiles through a double-buffered
-    VMEM window ONCE with the same online-softmax recurrence and
-    masking semantics as ``kernels/decode_attention.py`` (ragged
-    per-slot ``seq_pos``; tiles past the live length are never even
-    DMA'd — a strict improvement over the BlockSpec pipeline, which
-    streams dead tiles and masks them) -> the fresh token's own K/V
-    folds in last, always valid.
-  * **proj+MLP block** — grid ``(F // bf,)``: out-projection
-    (+residual) at step 0 with the [H*Dh, D] weight resident, fused
-    norm2 into f32 scratch, then the MLP streams its two (three for
-    SwiGLU) weight matrices tile-by-tile, accumulating the down-
-    projection in a [B, D] f32 scratch; the second residual lands in
-    the final tile.  The activation never leaves VMEM between the
-    out-projection and the layer output.
+    attention streams the slab's live ``[bk, KH, Dh]`` tiles through a
+    double-buffered VMEM window ONCE with the same online-softmax
+    recurrence and masking semantics as ``kernels/decode_attention.py``
+    (ragged per-slot ``seq_pos``; tiles past the live length are never
+    even DMA'd — a strict improvement over the BlockSpec pipeline,
+    which streams dead tiles and masks them) -> the fresh token's own
+    K/V folds in last, always valid.
+  * **proj+MLP block** — grid ``(HD // bo + F // bf,)``: the
+    out-projection accumulates over contraction-row tiles of ``wo``
+    (+residual), fused norm2 into VMEM scratch, then the MLP streams
+    its two (three for SwiGLU) weight matrices tile-by-tile,
+    accumulating the down-projection in a [B, D] f32 scratch; the
+    second residual lands in the final tile.  The activation never
+    leaves VMEM between the out-projection and the layer output.
+
+Numerics: norms, softmax and every accumulation run in f32; each dot's
+operands are in the WEIGHT dtype (activations round to it first, as
+the composed path's do), so no weight tile is ever up-cast in VMEM.
 
 Masking contract (exactly ``decode_attention``'s semantics specialised
 to sq=1, matching the unfused ``append_kv`` + ``decode_attention_auto``
@@ -51,14 +57,16 @@ therefore overwrites its last row, and a free slot (``pos == 0``)
 attends only to its own ride-along token — byte-identical lifecycle
 behaviour to the unfused engine path.
 
-VMEM budgeting (``plan_decode_block``): the kv tile ``block_k`` and MLP
-tile ``block_f`` shrink until the working set fits ``vmem_budget``
-(default 12 MiB of the 16 MiB core budget, headroom for Mosaic's own
-temporaries); if the irreducible residents (the per-head weight slices,
-the out-projection matrix) cannot fit at ANY tile size the plan refuses
-and ``fusion_legal`` reports the reason — the routed fallback is the
-composed unfused path (see kernels/routing.py and docs/serving.md's
-fallback matrix).
+VMEM budgeting (``plan_decode_block``): the tiles shrink until each
+kernel's working set — residents plus TWO copies of every streamed
+tile, which is how the grid pipeline allocates them — fits
+``vmem_budget`` (default 12 MiB; every call hands Mosaic
+``VMEM_LIMIT`` = 16 MiB, the rest is the compiler's own temporaries);
+if the irreducible residents cannot fit at ANY tile size the plan
+refuses and ``fusion_legal`` reports the reason, as it does for slab
+rows Mosaic cannot window (``_mosaic_slab_rule``) — the routed
+fallback is the composed unfused path (see kernels/routing.py and
+docs/serving.md's fallback matrix).
 
 CPU tier-1 runs the exact same kernels under ``interpret=True``
 (default off-TPU), including the in-kernel DMA append and the aliased
@@ -68,7 +76,7 @@ slab update, so every contract here is exercised on every CPU test run.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import numpy as np
 
@@ -86,6 +94,14 @@ _NEG_INF = float("-inf")
 # Mosaic's own spills/temporaries (same posture as fused_norm's 4 MiB
 # per-block cap, scaled to a whole-layer working set)
 VMEM_BUDGET = 12 * 1024 * 1024
+# the scoped-VMEM limit every decode-block pallas_call hands Mosaic
+# explicitly: the planned working set (<= VMEM_BUDGET) plus the
+# compiler's own temporaries.  Passing it pins the limit to the same
+# value standalone and inside the engine's program (XLA's default
+# scoped limit differs between the two contexts)
+VMEM_LIMIT = 16 * 1024 * 1024
+# slab rows the attention kernel up-casts and reduces at a time
+ATTN_CHUNK = 16
 
 # graftmem marker (tools/analysis/memory.py): the memory-budget rule
 # re-derives this plan's per-grid-step working set through an integer
@@ -126,20 +142,92 @@ def _norm_f32(x, w, b, norm: str, eps: float):
     return y + b if b is not None else y
 
 
+def _grid_params(interpret: bool):
+    """Mosaic parameters of every decode-block kernel (all 1-D grids
+    whose steps carry VMEM state forward): the explicit scoped-VMEM
+    limit; nothing when interpreted."""
+    if interpret:
+        return None
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                vmem_limit_bytes=VMEM_LIMIT)
+
+
+def _mxu_dot(a, w):
+    """``a @ w`` with f32 accumulation, operands as stored.  bf16
+    operands take ONE native MXU pass whatever the ambient
+    ``jax_default_matmul_precision`` (their products are exact in f32,
+    and asked for an f32-precision contraction of bf16 operands Mosaic
+    refuses: "Bad lhs type"); f32 operands keep the ambient precision."""
+    precision = jax.lax.Precision.DEFAULT \
+        if a.dtype == jnp.bfloat16 else None
+    return jax.lax.dot_general(a, w, (((1,), (0,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
 # ======================================================== planning / legality
+
+def _fit_tile(dim: int, per_unit: int, fixed: int, budget: int):
+    """Largest tile dividing ``dim`` whose streamed working set
+    ``fixed + per_unit * tile`` fits ``budget``; 128-multiples
+    preferred (the Mosaic lane rule), any divisor as the shrink
+    fallback — the same never-escalate posture as ``_dividing_tile``.
+    None when no divisor fits."""
+    lane = [t for t in range(128, dim + 1, 128) if dim % t == 0]
+    for t in sorted(lane, reverse=True):
+        if fixed + per_unit * t <= budget:
+            return t
+    for t in sorted((t for t in range(1, dim + 1) if dim % t == 0),
+                    reverse=True):
+        if fixed + per_unit * t <= budget:
+            return t
+    return None
+
+
+def _slab_row_bytes(kv_heads: int, head_dim: int, itemsize: int):
+    """VMEM bytes of ONE slab row ``[KH, Dh]`` in the storage dtype and
+    as an f32 working copy: ``(KH, Dh)`` are the tiled minor dims, so
+    each pads to the dtype's (sublane, 128-lane) tile."""
+    lanes = -(-head_dim // 128) * 128
+    sub = 8 * (4 // itemsize)
+    return (-(-kv_heads // sub) * sub * lanes * itemsize,
+            -(-kv_heads // 8) * 8 * lanes * 4)
+
+
+def _plan_slab_attention(max_seq: int, kv_heads: int, rep: int,
+                         head_dim: int, itemsize: int, vmem_budget: int):
+    """``(block_k, bytes)`` for the slab attention kernel, or
+    ``(None, bytes)`` when even the smallest window busts the budget.
+    Residents: the double-buffered K and V windows, the f32 working
+    copies of one ``ATTN_CHUNK``-row chunk (k, v, k*q, p*v), and the
+    per-query-head q / accumulator / fresh-row tiles."""
+    row, row32 = _slab_row_bytes(kv_heads, head_dim, itemsize)
+    fixed = (4 * min(ATTN_CHUNK, max_seq) * row32
+             + (2 * rep + 4) * row32 + 2 * row
+             + 3 * head_dim * max(head_dim, 128) * 4)        # rope tables + R
+    bk = min(1024, max_seq)
+    while max_seq % bk:
+        bk //= 2
+    while bk > 8 and fixed + 4 * bk * row > vmem_budget:
+        bk //= 2
+    need = fixed + 4 * bk * row
+    return (bk if need <= vmem_budget else None), need
+
 
 def plan_decode_block(*, max_seq: int, hidden: int, heads: int,
                       kv_heads: int, head_dim: int, ffn: int, batch: int,
                       itemsize: int, gated: bool = False, tp: int = 1,
                       vmem_budget: int = VMEM_BUDGET):
-    """Pick (block_k, block_f) under the VMEM budget, or explain why no
-    tiling fits.  Returns ``(plan_dict, None)`` or ``(None, reason)``.
+    """Pick the tiles of the three kernels under the VMEM budget, or
+    explain why no tiling fits.  Returns ``(plan_dict, None)`` or
+    ``(None, reason)``.
 
-    The attention kernel's residents: the kv-head's weight slices
-    (q group + k + v), the double-buffered kv tile window, and small f32
-    scratch.  The MLP kernel's residents: the FULL out-projection matrix
-    (it cannot tile without a second cross-program reduction), the
-    double-buffered MLP weight tiles, and three [B, D] f32 scratch rows.
+    Every weight streams, double-buffered by the grid pipeline, so each
+    kernel's working set is its resident activations plus TWO copies of
+    every tile in flight: ``block_n`` columns of a QKV projection,
+    ``block_k`` slab rows of every kv head, ``block_o`` contraction
+    rows of the out-projection next to ``block_f`` columns/rows of the
+    MLP matrices (one grid, so both sets are allocated together).
     Shrinking the tiles is the only lever; when the irreducible parts
     alone bust the budget the layer cannot fuse at this shape.
 
@@ -159,48 +247,67 @@ def plan_decode_block(*, max_seq: int, hidden: int, heads: int,
     rep = heads // kv_heads
     dh = head_dim
 
-    # ---- attention kernel: fixed residents
-    attn_fixed = (hidden * (rep + 2) * dh * itemsize      # wq slice, wk, wv
-                  + hidden * itemsize                     # x row
-                  + 2 * hidden * 4                        # norm params (f32 work)
-                  + 2 * rep * 128 * 4                     # m + l scratch rows
-                  + rep * dh * 4 + 2 * dh * 4             # acc + fresh k/v
-                  + 2 * dh * dh * 4)                      # rope tables + R
-    bk = min(1024, max_seq)
-    while max_seq % bk:
-        bk //= 2
-    while bk > 8 and attn_fixed + 2 * 2 * bk * dh * itemsize > vmem_budget:
-        bk //= 2
-    if attn_fixed + 2 * 2 * bk * dh * itemsize > vmem_budget:
-        return None, (f"vmem: attention residents "
-                      f"{attn_fixed + 4 * bk * dh * itemsize} bytes exceed "
-                      f"budget {vmem_budget} even at block_k={bk}")
+    # ---- slab attention kernel
+    bk, vmem_attn = _plan_slab_attention(max_seq, kv_heads, rep, dh,
+                                         itemsize, vmem_budget)
+    if bk is None:
+        return None, (f"vmem: attention residents {vmem_attn} bytes exceed "
+                      f"budget {vmem_budget} even at block_k=8")
 
-    # ---- MLP kernel: the out-projection must be fully resident
-    mlp_fixed = (heads * dh * hidden * itemsize           # wo
-                 + batch * (hidden + heads * dh) * itemsize   # x + attn rows
-                 + 3 * batch * hidden * 4                 # xmid/h/acc scratch
-                 + 4 * hidden * 4)                        # norm/bias params
+    # ---- norm + projection kernel: x and its normed copy resident,
+    # weight / bias / f32 output column tiles stream
+    proj_fixed = batch * hidden * 2 * itemsize + 2 * hidden * 4
+    proj_unit = 2 * (hidden * itemsize + itemsize + batch * 4)
+    bn = _fit_tile(kv_heads * dh, proj_unit, proj_fixed, vmem_budget)
+    if bn is None:
+        return None, (f"vmem: projection residents {proj_fixed} bytes + "
+                      f"weight tiles exceed budget {vmem_budget} at any "
+                      f"tile of the K/V width {kv_heads * dh}")
+
+    # ---- out-projection + MLP kernel
+    mlp_fixed = (batch * hidden * 2 * itemsize            # x in, y out
+                 + batch * hidden * (8 + itemsize)        # xmid/acc + h
+                 + 4 * hidden * 4)                        # norm/bias rows
+    o_unit = 2 * (hidden + batch) * itemsize              # wo rows + attn
     n_mats = 3 if gated else 2
-    # candidate tiles: divisors of ffn that are 128-multiples (Mosaic
-    # lane rule for a [D, bf] block), or the whole ffn when it is small
-    cands = [f for f in range(128, ffn + 1, 128) if ffn % f == 0]
-    if not cands:
-        cands = [ffn]                   # tiny configs: one full tile
-    bf = None
+    f_unit = 2 * (n_mats * hidden + 1) * itemsize
+    bo = bf = None
+    # candidate MLP tiles: divisors of ffn that are 128-multiples
+    # (Mosaic lane rule for a [D, bf] block), or the whole ffn when it
+    # is small; the out-projection takes what the largest fitting MLP
+    # tile leaves
+    cands = [f for f in range(128, ffn + 1, 128) if ffn % f == 0] or [ffn]
     for c in sorted(cands, reverse=True):
-        if mlp_fixed + n_mats * 2 * hidden * c * itemsize <= vmem_budget:
+        bo = _fit_tile(heads * dh, o_unit, mlp_fixed + f_unit * c,
+                       vmem_budget)
+        if bo is not None:
             bf = c
             break
     if bf is None:
-        need = mlp_fixed + n_mats * 2 * hidden * min(cands) * itemsize
+        need = mlp_fixed + f_unit * min(cands) + o_unit
         return None, (f"vmem: proj+MLP residents {need} bytes exceed "
-                      f"budget {vmem_budget} even at block_f={min(cands)} "
-                      f"(out-projection [{heads * dh}, {hidden}] must stay "
-                      f"resident)")
-    return {"block_k": bk, "block_f": bf,
-            "vmem_attn": attn_fixed + 4 * bk * dh * itemsize,
-            "vmem_mlp": mlp_fixed + n_mats * 2 * hidden * bf * itemsize}, None
+                      f"budget {vmem_budget} even at block_f={min(cands)}")
+    return {"block_k": bk, "block_n": bn, "block_o": bo, "block_f": bf,
+            "vmem_attn": vmem_attn,
+            "vmem_proj": proj_fixed + proj_unit * bn,
+            "vmem_mlp": mlp_fixed + o_unit * bo + f_unit * bf}, None
+
+
+def _mosaic_slab_rule(kv_heads: int, head_dim: int):
+    """Why Mosaic cannot window this device's ``[.., KH, Dh]`` slab
+    rows, or None.  ``(KH, Dh)`` are the slab's tiled minor dims and
+    ``tpu.memref_slice`` takes whole tiles only; the compiler's words
+    are quoted so the refusal is static, never a failed dispatch (the
+    interpreted CPU kernels have no such limit)."""
+    if head_dim % 128:
+        return (f"mosaic: head_dim {head_dim} is not a multiple of the "
+                f"128-lane tile ('Slice shape along dimension 3 must be "
+                f"aligned to tiling (128), but is {head_dim}')")
+    if kv_heads % 8 and kv_heads not in (2, 4):
+        return (f"mosaic: {kv_heads} kv heads per device do not fill "
+                f"the slab's sublane tile ('Slice shape along dimension "
+                f"2 must be aligned to tiling (8), but is {kv_heads}')")
+    return None
 
 
 def fusion_legal(*, max_seq: int, hidden: int, heads: int, kv_heads: int,
@@ -228,6 +335,10 @@ def fusion_legal(*, max_seq: int, hidden: int, heads: int, kv_heads: int,
         return False, f"heads {heads} not a multiple of kv_heads {kv_heads}"
     if head_dim % 2:
         return False, f"head_dim {head_dim} must be even (rotary halves)"
+    if kv_heads % tp == 0 and jax.default_backend() != "cpu":
+        why = _mosaic_slab_rule(kv_heads // tp, head_dim)
+        if why is not None:
+            return False, why
     if tp > 1:
         if kv_heads % tp:
             return False, (f"kv_heads {kv_heads} not divisible by "
@@ -308,54 +419,115 @@ def resolve_fused_decode(model, *, batch: int, kv_len: int, tp: int = 1):
     return supported(batch=batch, kv_len=kv_len, tp=tp)
 
 
-# ============================================================ attention block
+# ======================================================= norm + projection
 
-def _attn_kernel(pos_ref, x_ref, nw_ref, nb_ref, wq_ref, wk_ref, wv_ref,
-                 bq_ref, bk_ref, bv_ref, cos_ref, sin_ref, rot_ref,
-                 k_any, v_any,
-                 attn_ref, ko_any, vo_any,
-                 m_sc, l_sc, acc_sc, knew_sc, vnew_sc, kbuf, vbuf,
-                 rsem, wsem, *,
-                 S, rep, dh, bk, eps, scale, norm, has_bias, use_rope):
-    kh = pl.program_id(0)
-    b = pl.program_id(1)
-    pos = pos_ref[0]
+def _proj_kernel(x_ref, nw_ref, nb_ref, w_ref, b_ref, o_ref, h_sc, *,
+                 eps, norm):
+    """One column tile of ``norm(x) @ w + b``: the normed rows are
+    computed once (grid step 0) into VMEM scratch in the WEIGHT dtype,
+    then every step runs one [B, D] x [D, bn] dot with f32 accumulation
+    — the composed path's numerics, with no weight tile ever up-cast in
+    VMEM."""
+    @pl.when(pl.program_id(0) == 0)
+    def _norm():
+        nb = nb_ref[...].astype(jnp.float32) if norm == "layer" else None
+        h_sc[...] = _norm_f32(x_ref[...].astype(jnp.float32),
+                              nw_ref[...].astype(jnp.float32), nb, norm,
+                              eps).astype(h_sc.dtype)
 
-    # ---- fused norm + this kv-head group's q/k/v projection (f32)
-    xr = x_ref[0].astype(jnp.float32)                       # [1, D]
-    nb = nb_ref[...].astype(jnp.float32) if norm == "layer" else None
-    xn = _norm_f32(xr, nw_ref[...].astype(jnp.float32), nb, norm, eps)
+    o_ref[...] = _mxu_dot(h_sc[...], w_ref[...]) \
+        + b_ref[...].astype(jnp.float32)
+
+
+def _norm_proj(x2, nw, nb, w, bias, *, norm, eps, block_n, interpret):
+    """``norm(x2) @ w (+ bias)`` as f32 ``[B, N]``: x2 [B, D] stays
+    resident, ``[D, block_n]`` weight tiles stream."""
+    b, d = x2.shape
+    n = w.shape[1]
+    bn = _dividing_tile(n, block_n)
+    bias2 = (bias if bias is not None
+             else jnp.zeros((n,), w.dtype)).reshape(1, n)
+    return pl.pallas_call(
+        functools.partial(_proj_kernel, eps=float(eps), norm=norm),
+        grid=(n // bn,),
+        in_specs=[
+            pl.BlockSpec((b, d), lambda i: (0, 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
+            pl.BlockSpec((d, bn), lambda i: (0, i)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+        ],
+        out_specs=pl.BlockSpec((b, bn), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((b, d), w.dtype)],
+        compiler_params=_grid_params(interpret),
+        interpret=interpret,
+    )(x2, nw, nb, w, bias2)
+
+
+def _dividing_tile(dim: int, want: Optional[int]) -> int:
+    """The largest tile <= ``want`` that divides ``dim``, preferring
+    128-multiples (the Mosaic lane rule), else any divisor — never
+    escalating toward full residency (that is the exact failure the
+    VMEM plan exists to prevent)."""
+    t = min(want or dim, dim)
+    if dim % t == 0:
+        return t
+    cand = (t // 128) * 128
+    while cand >= 128 and dim % cand:
+        cand -= 128
+    if cand >= 128:
+        return cand
+    while dim % t:
+        t -= 1
+    return t
+
+
+# ========================================================== slab attention
+
+def _slab_attn_kernel(pos_ref, q_ref, kn_ref, vn_ref, cos_ref, sin_ref,
+                      rot_ref, k_any, v_any,
+                      attn_ref, ko_any, vo_any,
+                      knew_sc, vnew_sc, kbuf, vbuf, rsem, wsem, *,
+                      S, rep, bk, ck, scale, use_rope):
+    """One slot's decode attention over ALL its (local) kv heads.
+
+    The slabs are ``[B, S, KH, Dh]`` with ``(KH, Dh)`` the tiled minor
+    dims, so the only slab windows a DMA can address are whole-head
+    rows ``[rows, KH, Dh]`` — a single kv head is not sliceable.  The
+    kernel therefore streams ``[bk, KH, Dh]`` tiles and runs the
+    online-softmax recurrence for every head at once on the VPU:
+    scores are a lane reduction of ``K * q`` (sq=1 leaves the MXU one
+    row per head anyway), the value sum a leading-dim reduction of
+    ``p * V``."""
+    b = pl.program_id(0)
+    pos = pos_ref[b]
     dims = (((1,), (0,)), ((), ()))
-    q = jax.lax.dot_general(xn, wq_ref[0].astype(jnp.float32), dims,
-                            preferred_element_type=jnp.float32)
-    kx = jax.lax.dot_general(xn, wk_ref[0].astype(jnp.float32), dims,
-                             preferred_element_type=jnp.float32)
-    vx = jax.lax.dot_general(xn, wv_ref[0].astype(jnp.float32), dims,
-                             preferred_element_type=jnp.float32)
-    if has_bias:
-        q = q + bq_ref[0].astype(jnp.float32)
-        kx = kx + bk_ref[0].astype(jnp.float32)
-        vx = vx + bv_ref[0].astype(jnp.float32)
-    qm = q.reshape(rep, dh)
+
+    kx = kn_ref[0].astype(jnp.float32)                      # [KH, Dh]
+    vx = vn_ref[0].astype(jnp.float32)
+    qs = [q_ref[0, r].astype(jnp.float32) for r in range(rep)]
     if use_rope:
-        c = cos_ref[...].astype(jnp.float32)                # [1, dh]
-        s = sin_ref[...].astype(jnp.float32)
+        c = cos_ref[0].astype(jnp.float32)                  # [1, Dh]
+        s = sin_ref[0].astype(jnp.float32)
         rot = rot_ref[...]
-        qm = qm * c + jax.lax.dot_general(qm, rot, dims,
-                                          preferred_element_type=jnp.float32) * s
-        kx = kx * c + jax.lax.dot_general(kx, rot, dims,
-                                          preferred_element_type=jnp.float32) * s
-    qm = qm * scale
+
+        def rope(t):
+            return t * c + jax.lax.dot_general(
+                t, rot, dims, preferred_element_type=jnp.float32) * s
+        kx = rope(kx)
+        qs = [rope(q) for q in qs]
+    qs = [(q * scale)[None] for q in qs]                    # [1, KH, Dh]
 
     # ---- in-kernel KV append: DMA the fresh row into the slot slab at
     # this slot's position (clamped exactly like dynamic_update_slice —
     # a full slot overwrites its last row, matching the unfused path)
     posw = jnp.minimum(pos, S - 1)
-    knew_sc[...] = kx.astype(knew_sc.dtype)
-    vnew_sc[...] = vx.astype(vnew_sc.dtype)
-    kw_cp = pltpu.make_async_copy(knew_sc, ko_any.at[b, pl.ds(posw, 1), kh],
+    knew_sc[...] = kx[None].astype(knew_sc.dtype)
+    vnew_sc[...] = vx[None].astype(vnew_sc.dtype)
+    kw_cp = pltpu.make_async_copy(knew_sc, ko_any.at[b, pl.ds(posw, 1)],
                                   wsem.at[0])
-    vw_cp = pltpu.make_async_copy(vnew_sc, vo_any.at[b, pl.ds(posw, 1), kh],
+    vw_cp = pltpu.make_async_copy(vnew_sc, vo_any.at[b, pl.ds(posw, 1)],
                                   wsem.at[1])
     kw_cp.start()
     vw_cp.start()
@@ -364,18 +536,15 @@ def _attn_kernel(pos_ref, x_ref, nw_ref, nb_ref, wq_ref, wk_ref, wv_ref,
     # past the live prefix are never fetched (pos, not S, bounds the loop)
     lim = posw                                              # valid: kpos < lim
     nlive = jax.lax.div(lim + bk - 1, bk)
-    m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
-    l_sc[...] = jnp.zeros_like(l_sc)
-    acc_sc[...] = jnp.zeros_like(acc_sc)
 
     def k_cp(slot, ki):
         return pltpu.make_async_copy(
-            k_any.at[b, pl.ds(ki * bk, bk), kh], kbuf.at[slot],
+            k_any.at[b, pl.ds(ki * bk, bk)], kbuf.at[slot],
             rsem.at[0, slot])
 
     def v_cp(slot, ki):
         return pltpu.make_async_copy(
-            v_any.at[b, pl.ds(ki * bk, bk), kh], vbuf.at[slot],
+            v_any.at[b, pl.ds(ki * bk, bk)], vbuf.at[slot],
             rsem.at[1, slot])
 
     @pl.when(nlive > 0)
@@ -383,23 +552,20 @@ def _attn_kernel(pos_ref, x_ref, nw_ref, nb_ref, wq_ref, wk_ref, wv_ref,
         k_cp(0, 0).start()
         v_cp(0, 0).start()
 
-    def _update(s_blk, v_blk, kpos_valid):
-        """One online-softmax step (decode_attention's recurrence)."""
-        s_blk = jnp.where(kpos_valid, s_blk, _NEG_INF)
-        m_prev = m_sc[...]
-        l_prev = l_sc[...]
-        m_curr = jnp.max(s_blk, axis=1)[:, None]
-        m_next = jnp.maximum(m_prev, m_curr)
+    def _update(state, s_blk, v_blk):
+        """One online-softmax step (decode_attention's recurrence) for
+        one query head per kv head: s_blk [n, KH, 1], v_blk [n, KH, Dh];
+        the running max / sum / accumulator are [1, KH, 1|Dh]."""
+        m_prev, l_prev, acc = state
+        m_next = jnp.maximum(m_prev, jnp.max(s_blk, axis=0, keepdims=True))
         m_safe = jnp.where(m_next == _NEG_INF, 0.0, m_next)
-        p = jnp.exp(s_blk - m_safe[:, :1])
+        p = jnp.exp(s_blk - m_safe)
         alpha = jnp.exp(m_prev - m_safe)
-        l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
-        m_sc[...] = m_next
-        acc_sc[...] = acc_sc[...] * alpha[:, :1] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        return (m_next,
+                alpha * l_prev + jnp.sum(p, axis=0, keepdims=True),
+                acc * alpha + jnp.sum(p * v_blk, axis=0, keepdims=True))
 
-    def _body(ki, carry):
+    def _tile(ki, state):
         slot = jax.lax.rem(ki, 2)
 
         @pl.when(ki + 1 < nlive)
@@ -409,29 +575,136 @@ def _attn_kernel(pos_ref, x_ref, nw_ref, nb_ref, wq_ref, wk_ref, wv_ref,
 
         k_cp(slot, ki).wait()
         v_cp(slot, ki).wait()
-        kt = kbuf[slot].astype(jnp.float32)                 # [bk, dh]
-        vt = vbuf[slot].astype(jnp.float32)
-        s_blk = jax.lax.dot_general(qm, kt, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (rep, bk), 1)
-        _update(s_blk, vt, kpos < lim)
-        return carry
 
-    jax.lax.fori_loop(0, nlive, _body, 0)
+        def _chunk(ci, state):
+            # ck rows at a time: the f32 working copies of a whole
+            # [bk, KH, Dh] tile are not in the VMEM plan
+            r0 = pl.multiple_of(ci * ck, ck)
+            kt = kbuf[slot, pl.ds(r0, ck)].astype(jnp.float32)
+            vt = vbuf[slot, pl.ds(r0, ck)].astype(jnp.float32)
+            kpos = ki * bk + r0 + jax.lax.broadcasted_iota(
+                jnp.int32, (ck, 1, 1), 0)
+            out = []
+            for q, st in zip(qs, state):
+                s_blk = jnp.sum(kt * q, axis=-1, keepdims=True)
+                s_blk = jnp.where(kpos < lim, s_blk, _NEG_INF)
+                out.append(_update(st, s_blk, vt))
+            return tuple(out)
+
+        # chunks wholly past the live prefix are skipped too
+        live = jnp.minimum(lim - ki * bk, bk)
+        return jax.lax.fori_loop(0, jax.lax.div(live + ck - 1, ck),
+                                 _chunk, state)
+
+    kh, dh = kx.shape
+    init = tuple((jnp.full((1, kh, 1), _NEG_INF, jnp.float32),
+                  jnp.zeros((1, kh, 1), jnp.float32),
+                  jnp.zeros((1, kh, dh), jnp.float32)) for _ in qs)
+    state = jax.lax.fori_loop(0, nlive, _tile, init)
 
     # ---- the fresh token folds in last, always valid (it reads its own
     # STORED k/v so storage-dtype rounding matches the unfused path)
-    kq = knew_sc[...].astype(jnp.float32)                   # [1, dh]
+    kq = knew_sc[...].astype(jnp.float32)                   # [1, KH, Dh]
     vq = vnew_sc[...].astype(jnp.float32)
-    s_new = jax.lax.dot_general(qm, kq, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    _update(s_new, vq, jnp.full((rep, 1), True))
-
-    l = l_sc[...][:, :1]
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    attn_ref[0, 0] = (acc_sc[...] / l_safe).astype(attn_ref.dtype)
+    for r, (q, st) in enumerate(zip(qs, state)):
+        s_new = jnp.sum(kq * q, axis=-1, keepdims=True)     # [1, KH, 1]
+        _, l, acc = _update(st, s_new, vq)
+        attn_ref[0, r] = (acc / l)[0].astype(attn_ref.dtype)
     kw_cp.wait()
     vw_cp.wait()
+
+
+def slab_decode_attention(q, k_new, v_new, k_slab, v_slab, seq_pos, *,
+                          scale: Optional[float] = None,
+                          rope_cos=None, rope_sin=None,
+                          block_k: Optional[int] = None,
+                          interpret: Optional[bool] = None):
+    """Rotary -> in-kernel KV append -> streaming decode attention over
+    the slot slabs, for every kv head the slabs hold (all of them at
+    tp=1, this device's group under tensor parallelism).
+
+    q [B, KH, rep, Dh], k_new/v_new [B, KH, Dh] the fresh projections
+    (pre-rotary); k_slab/v_slab [B, S, KH, Dh] (updated IN PLACE via
+    kernel aliasing); seq_pos [B] int32 cache lengths BEFORE this
+    token; rope_cos/rope_sin [B, Dh] full-width tables (halves
+    duplicated) or None.  Returns ``(attn [B, KH, rep, Dh] in the slab
+    dtype, k_slab', v_slab')``."""
+    b, kh, rep, dh = q.shape
+    s_max = k_slab.shape[1]
+    assert k_slab.shape[2:] == (kh, dh), (k_slab.shape, q.shape)
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    scale = scale if scale is not None else 1.0 / (dh ** 0.5)
+    # scalar seq_pos (single-request decode_step caches) broadcasts to
+    # the per-slot vector the kernel grid indexes by
+    pos1 = jnp.asarray(seq_pos, jnp.int32)
+    if pos1.ndim == 0:
+        pos1 = jnp.broadcast_to(pos1, (b,))
+    bk = min(block_k or min(1024, s_max), s_max)
+    while s_max % bk:
+        bk //= 2
+    ck = min(bk, ATTN_CHUNK)
+    while bk % ck:
+        ck //= 2
+    use_rope = rope_cos is not None
+    if use_rope:
+        cosf = rope_cos.reshape(b, 1, dh)
+        sinf = rope_sin.reshape(b, 1, dh)
+        rot = _rotate_half_matrix(dh)
+    else:
+        cosf = jnp.ones((b, 1, dh), jnp.float32)
+        sinf = jnp.zeros((b, 1, dh), jnp.float32)
+        rot = jnp.zeros((dh, dh), jnp.float32)
+    # query heads r-major so the kernel picks "query head r of every kv
+    # head" by a leading index: [B, rep, KH, Dh]
+    qr = jnp.swapaxes(q, 1, 2)
+
+    kernel = functools.partial(_slab_attn_kernel, S=s_max, rep=rep, bk=bk,
+                               ck=ck, scale=scale, use_rope=use_rope)
+    # seq_pos rides as a scalar-prefetch operand: the whole [B] vector
+    # lands in SMEM before the grid starts and each program reads its
+    # slot's entry (a (1,) SMEM block per program is not lowerable)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, rep, kh, dh), lambda bi, pos: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, kh, dh), lambda bi, pos: (bi, 0, 0)),
+            pl.BlockSpec((1, kh, dh), lambda bi, pos: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, dh), lambda bi, pos: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, dh), lambda bi, pos: (bi, 0, 0)),
+            pl.BlockSpec((dh, dh), lambda bi, pos: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, rep, kh, dh), lambda bi, pos: (bi, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((1, kh, dh), k_slab.dtype),
+            pltpu.VMEM((1, kh, dh), v_slab.dtype),
+            pltpu.VMEM((2, bk, kh, dh), k_slab.dtype),
+            pltpu.VMEM((2, bk, kh, dh), v_slab.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    attn, k2, v2 = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, rep, kh, dh), k_slab.dtype),
+            jax.ShapeDtypeStruct(k_slab.shape, k_slab.dtype),
+            jax.ShapeDtypeStruct(v_slab.shape, v_slab.dtype),
+        ],
+        # operand indices count the scalar-prefetch argument
+        input_output_aliases={7: 1, 8: 2},
+        compiler_params=_grid_params(interpret),
+        interpret=interpret,
+    )(pos1, qr, k_new, v_new, cosf, sinf, rot, k_slab, v_slab)
+    return jnp.swapaxes(attn, 1, 2), k2, v2
 
 
 def decode_block_attn(x, k_slab, v_slab, seq_pos, norm_w, norm_b,
@@ -440,6 +713,7 @@ def decode_block_attn(x, k_slab, v_slab, seq_pos, norm_w, norm_b,
                       eps: float = 1e-5, scale: Optional[float] = None,
                       rope_cos=None, rope_sin=None,
                       block_k: Optional[int] = None,
+                      block_n: Optional[int] = None,
                       interpret: Optional[bool] = None):
     """Fused norm -> QKV -> in-kernel KV append -> streaming decode
     attention over the slot slabs.
@@ -455,106 +729,28 @@ def decode_block_attn(x, k_slab, v_slab, seq_pos, norm_w, norm_b,
     if sq != 1:
         raise ValueError(f"decode_block_attn is a decode kernel (sq=1), "
                          f"got sq={sq}")
-    s_max, kh_, dh = k_slab.shape[1], k_slab.shape[2], k_slab.shape[3]
+    kh_, dh = k_slab.shape[2], k_slab.shape[3]
     assert kh_ == kv_heads and dh == head_dim
     heads = wq.shape[1] // head_dim
     rep = heads // kv_heads
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    scale = scale if scale is not None else 1.0 / (head_dim ** 0.5)
-    # scalar seq_pos (single-request decode_step caches) broadcasts to
-    # the per-slot vector the kernel grid indexes by
-    pos1 = jnp.asarray(seq_pos, jnp.int32)
-    if pos1.ndim == 0:
-        pos1 = jnp.broadcast_to(pos1, (b,))
-    bk = block_k or min(1024, s_max)
-    bk = min(bk, s_max)
-    while s_max % bk:
-        bk //= 2
-    has_bias = bq is not None or bkv is not None or bv is not None
-    use_rope = rope_cos is not None
-
-    # head-blocked weight views: [KH, D, rep*Dh] / [KH, D, Dh] so every
-    # block's trailing dims equal the array dims (Mosaic-legal at any
-    # head_dim, incl. the flagship's 64).  Trace-time transposes — the
-    # engine's decode program sees them as constants and folds them.
-    wq3 = wq.reshape(d, kv_heads, rep * dh).transpose(1, 0, 2)
-    wk3 = wk.reshape(d, kv_heads, dh).transpose(1, 0, 2)
-    wv3 = wv.reshape(d, kv_heads, dh).transpose(1, 0, 2)
+    x2 = x[:, 0]
+    nw = norm_w.reshape(1, d)
+    nb = norm_b.reshape(1, d) if norm == "layer" else jnp.zeros_like(nw)
     # each bias is independently optional (the reference applies them
-    # independently too); absent ones ride as zeros
-    zq = jnp.zeros((kv_heads, rep * dh), x.dtype)
-    zk = jnp.zeros((kv_heads, dh), x.dtype)
-    bq2 = bq.reshape(kv_heads, rep * dh) if bq is not None else zq
-    bk2 = bkv.reshape(kv_heads, dh) if bkv is not None else zk
-    bv2 = bv.reshape(kv_heads, dh) if bv is not None else zk
-    if use_rope:
-        cosf, sinf = rope_cos, rope_sin
-        rot = _rotate_half_matrix(dh)
-    else:
-        cosf = jnp.ones((b, dh), jnp.float32)
-        sinf = jnp.zeros((b, dh), jnp.float32)
-        rot = jnp.zeros((dh, dh), jnp.float32)
-    if norm == "layer":
-        nb = norm_b
-    else:
-        nb = jnp.zeros_like(norm_w)
-
-    kernel = functools.partial(
-        _attn_kernel, S=s_max, rep=rep, dh=dh, bk=bk, eps=float(eps),
-        scale=scale, norm=norm, has_bias=has_bias, use_rope=use_rope)
-    compiler_params = None if interpret else pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary"))
-    grid = (kv_heads, b)
-    attn4, k2, v2 = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda kh, bi: (bi,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, d), lambda kh, bi: (bi, 0, 0)),
-            pl.BlockSpec((d,), lambda kh, bi: (0,)),
-            pl.BlockSpec((d,), lambda kh, bi: (0,)),
-            pl.BlockSpec((1, d, rep * dh), lambda kh, bi: (kh, 0, 0)),
-            pl.BlockSpec((1, d, dh), lambda kh, bi: (kh, 0, 0)),
-            pl.BlockSpec((1, d, dh), lambda kh, bi: (kh, 0, 0)),
-            pl.BlockSpec((1, rep * dh), lambda kh, bi: (kh, 0)),
-            pl.BlockSpec((1, dh), lambda kh, bi: (kh, 0)),
-            pl.BlockSpec((1, dh), lambda kh, bi: (kh, 0)),
-            pl.BlockSpec((1, dh), lambda kh, bi: (bi, 0)),
-            pl.BlockSpec((1, dh), lambda kh, bi: (bi, 0)),
-            pl.BlockSpec((dh, dh), lambda kh, bi: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, rep, dh), lambda kh, bi: (bi, kh, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kv_heads, rep, dh), x.dtype),
-            jax.ShapeDtypeStruct(k_slab.shape, k_slab.dtype),
-            jax.ShapeDtypeStruct(v_slab.shape, v_slab.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((rep, 128), jnp.float32),
-            pltpu.VMEM((rep, 128), jnp.float32),
-            pltpu.VMEM((rep, dh), jnp.float32),
-            pltpu.VMEM((1, dh), k_slab.dtype),
-            pltpu.VMEM((1, dh), v_slab.dtype),
-            pltpu.VMEM((2, bk, dh), k_slab.dtype),
-            pltpu.VMEM((2, bk, dh), v_slab.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        input_output_aliases={13: 1, 14: 2},
-        compiler_params=compiler_params,
-        interpret=interpret,
-    )(pos1, x, norm_w, nb, wq3, wk3, wv3,
-      bq2, bk2, bv2, cosf, sinf, rot, k_slab, v_slab)
-    attn = attn4.reshape(b, 1, heads * dh)
-    return attn, k2, v2
+    # independently too); the three projections share one kernel — the
+    # norm is recomputed per call, a [B, D] VPU pass
+    proj = functools.partial(_norm_proj, x2, nw, nb, norm=norm, eps=eps,
+                             block_n=block_n, interpret=interpret)
+    q = proj(wq, bq).reshape(b, kv_heads, rep, dh)
+    kx = proj(wk, bkv).reshape(b, kv_heads, dh)
+    vx = proj(wv, bv).reshape(b, kv_heads, dh)
+    attn, k2, v2 = slab_decode_attention(
+        q, kx, vx, k_slab, v_slab, seq_pos, scale=scale,
+        rope_cos=rope_cos, rope_sin=rope_sin, block_k=block_k,
+        interpret=interpret)
+    return attn.reshape(b, 1, heads * dh).astype(x.dtype), k2, v2
 
 
 # ============================================================= proj+MLP block
@@ -562,54 +758,61 @@ def decode_block_attn(x, k_slab, v_slab, seq_pos, norm_w, norm_b,
 def _mlp_kernel(x_ref, attn_ref, wo_ref, bo_ref, n2w_ref, n2b_ref,
                 w1_ref, b1_ref, wg_ref, w2_ref, b2_ref, o_ref,
                 xmid_sc, h_sc, acc_sc, *,
-                nf, eps, norm, act, has_bias, gated):
-    f = pl.program_id(0)
-    dims = (((1,), (0,)), ((), ()))
+                no, nf, eps, norm, act, has_bias, gated):
+    """Grid ``(no + nf,)``: the first ``no`` steps accumulate the
+    out-projection over contraction-row tiles of ``wo`` (which streams
+    like every other weight — a resident copy would be double-buffered
+    by the grid pipeline), the remaining ``nf`` steps stream the MLP
+    tiles.  Dots run in the weight dtype with f32 accumulation."""
+    i = pl.program_id(0)
 
-    @pl.when(f == 0)
+    @pl.when(i == 0)
+    def _init():
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    @pl.when(i < no)
     def _proj():
-        x = x_ref[:, 0].astype(jnp.float32)                 # [B, D]
-        a = attn_ref[:, 0].astype(jnp.float32)              # [B, H*Dh]
-        xm = x + jax.lax.dot_general(a, wo_ref[...].astype(jnp.float32),
-                                     dims,
-                                     preferred_element_type=jnp.float32)
+        acc_sc[...] = acc_sc[...] + _mxu_dot(attn_ref[...], wo_ref[...])
+
+    @pl.when(i == no - 1)
+    def _mid():
+        xm = x_ref[...].astype(jnp.float32) + acc_sc[...]
         if has_bias:
             xm = xm + bo_ref[...].astype(jnp.float32)
         xmid_sc[...] = xm
         n2b = n2b_ref[...].astype(jnp.float32) if norm == "layer" else None
         h_sc[...] = _norm_f32(xm, n2w_ref[...].astype(jnp.float32), n2b,
-                              norm, eps)
+                              norm, eps).astype(h_sc.dtype)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    h = h_sc[...]
-    t = jax.lax.dot_general(h, w1_ref[...].astype(jnp.float32), dims,
-                            preferred_element_type=jnp.float32)
-    if has_bias:
-        t = t + b1_ref[...].astype(jnp.float32)
-    if gated:
-        g = jax.lax.dot_general(h, wg_ref[...].astype(jnp.float32), dims,
-                                preferred_element_type=jnp.float32)
-        a = jax.nn.silu(g) * t
-    elif act == "gelu_tanh":
-        a = jax.nn.gelu(t, approximate=True)
-    else:
-        a = jax.nn.gelu(t, approximate=False)
-    acc_sc[...] = acc_sc[...] + jax.lax.dot_general(
-        a, w2_ref[...].astype(jnp.float32), dims,
-        preferred_element_type=jnp.float32)
+    @pl.when(i >= no)
+    def _mlp():
+        h = h_sc[...]
+        t = _mxu_dot(h, w1_ref[...])
+        if has_bias:
+            t = t + b1_ref[...].astype(jnp.float32)
+        if gated:
+            a = jax.nn.silu(_mxu_dot(h, wg_ref[...])) * t
+        elif act == "gelu_tanh":
+            a = jax.nn.gelu(t, approximate=True)
+        else:
+            a = jax.nn.gelu(t, approximate=False)
+        acc_sc[...] = acc_sc[...] + _mxu_dot(a.astype(w2_ref.dtype),
+                                             w2_ref[...])
 
-    @pl.when(f == nf - 1)
+    @pl.when(i == no + nf - 1)
     def _emit():
         y = xmid_sc[...] + acc_sc[...]
         if has_bias:
             y = y + b2_ref[...].astype(jnp.float32)
-        o_ref[:, 0] = y.astype(o_ref.dtype)
+        o_ref[...] = y.astype(o_ref.dtype)
 
 
 def decode_block_mlp(x, attn, wo, bo, norm_w, norm_b, w1, b1, w2, b2,
                      w_gate=None, *, norm: str = "layer",
                      eps: float = 1e-5, act: str = "gelu_tanh",
                      block_f: Optional[int] = None,
+                     block_o: Optional[int] = None,
                      interpret: Optional[bool] = None):
     """Fused out-projection (+residual) -> norm2 -> MLP (+residual).
 
@@ -617,7 +820,7 @@ def decode_block_mlp(x, attn, wo, bo, norm_w, norm_b, w1, b1, w2, b2,
     :func:`decode_block_attn`'s output.  ``w_gate`` switches the MLP to
     SwiGLU (``down(silu(gate)*up)`` with w1=up, w2=down).  The [B, D]
     activation stays in VMEM scratch from the out-projection to the
-    final residual; MLP weights stream tile-by-tile."""
+    final residual; every weight streams tile-by-tile."""
     b, sq, d = x.shape
     hd = attn.shape[-1]
     ffn = w1.shape[1]
@@ -625,71 +828,64 @@ def decode_block_mlp(x, attn, wo, bo, norm_w, norm_b, w1, b1, w2, b2,
         interpret = jax.default_backend() == "cpu"
     gated = w_gate is not None
     has_bias = bo is not None or b1 is not None or b2 is not None
-    bf = min(block_f or ffn, ffn)
-    if ffn % bf:
-        # never escalate toward full residency (that is the exact
-        # failure plan_decode_block's budget exists to prevent): shrink
-        # to the largest dividing tile <= the request, preferring
-        # 128-multiples (Mosaic lane rule), else any divisor
-        cand = (bf // 128) * 128
-        while cand >= 128 and ffn % cand:
-            cand -= 128
-        if cand < 128:
-            cand = bf
-            while ffn % cand:
-                cand -= 1
-        bf = cand
-    nf = ffn // bf
-    zd = jnp.zeros((d,), x.dtype)
+    bf = _dividing_tile(ffn, block_f)
+    bo_t = _dividing_tile(hd, block_o)
+    nf, no = ffn // bf, hd // bo_t
     # each bias independently optional, matching the reference's
-    # per-bias application; absent ones ride as zeros
-    bo2 = bo if bo is not None else zd
-    b12 = b1 if b1 is not None else jnp.zeros((ffn,), x.dtype)
-    b22 = b2 if b2 is not None else zd
-    n2b = norm_b if norm == "layer" else jnp.zeros_like(norm_w)
+    # per-bias application; absent ones ride as zeros.  Row operands
+    # ride as [1, n] (Mosaic refuses rank-1 blocks)
+    row = lambda v, n: (v if v is not None
+                        else jnp.zeros((n,), x.dtype)).reshape(1, n)
+    n2w = norm_w.reshape(1, d)
+    n2b = norm_b.reshape(1, d) if norm == "layer" else jnp.zeros_like(n2w)
+    # index maps clamp each operand to its own phase: the pipeline
+    # re-fetches a block only when its index changes, so wo tiles are
+    # not re-read during the MLP phase nor MLP tiles during the
+    # out-projection
+    o_idx = lambda i: jnp.minimum(i, no - 1)
+    f_idx = lambda i: jnp.maximum(i - no, 0)
     if gated:
         wg = w_gate
-        wg_spec = pl.BlockSpec((d, bf), lambda f: (0, f))
+        wg_spec = pl.BlockSpec((d, bf), lambda i: (0, f_idx(i)))
     else:
         # the kernel body never reads wg when not gated, but the grid
         # pipeline DMAs every spec'd block regardless — a one-tile
         # placeholder with a CONSTANT index map keeps the dead operand
         # from re-streaming the full [D, ffn] up-projection each step
         wg = jnp.zeros((d, bf), x.dtype)
-        wg_spec = pl.BlockSpec((d, bf), lambda f: (0, 0))
+        wg_spec = pl.BlockSpec((d, bf), lambda i: (0, 0))
 
     kernel = functools.partial(
-        _mlp_kernel, nf=nf, eps=float(eps), norm=norm, act=act,
+        _mlp_kernel, no=no, nf=nf, eps=float(eps), norm=norm, act=act,
         has_bias=has_bias, gated=gated)
-    compiler_params = None if interpret else pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",))
     out = pl.pallas_call(
         kernel,
-        grid=(nf,),
+        grid=(no + nf,),
         in_specs=[
-            pl.BlockSpec((b, 1, d), lambda f: (0, 0, 0)),
-            pl.BlockSpec((b, 1, hd), lambda f: (0, 0, 0)),
-            pl.BlockSpec((hd, d), lambda f: (0, 0)),
-            pl.BlockSpec((d,), lambda f: (0,)),
-            pl.BlockSpec((d,), lambda f: (0,)),
-            pl.BlockSpec((d,), lambda f: (0,)),
-            pl.BlockSpec((d, bf), lambda f: (0, f)),
-            pl.BlockSpec((bf,), lambda f: (f,)),
+            pl.BlockSpec((b, d), lambda i: (0, 0)),
+            pl.BlockSpec((b, bo_t), lambda i: (0, o_idx(i))),
+            pl.BlockSpec((bo_t, d), lambda i: (o_idx(i), 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
+            pl.BlockSpec((d, bf), lambda i: (0, f_idx(i))),
+            pl.BlockSpec((1, bf), lambda i: (0, f_idx(i))),
             wg_spec,
-            pl.BlockSpec((bf, d), lambda f: (f, 0)),
-            pl.BlockSpec((d,), lambda f: (0,)),
+            pl.BlockSpec((bf, d), lambda i: (f_idx(i), 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((b, 1, d), lambda f: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1, d), x.dtype),
+        out_specs=pl.BlockSpec((b, d), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, d), x.dtype),
         scratch_shapes=[
             pltpu.VMEM((b, d), jnp.float32),
-            pltpu.VMEM((b, d), jnp.float32),
+            pltpu.VMEM((b, d), w1.dtype),
             pltpu.VMEM((b, d), jnp.float32),
         ],
-        compiler_params=compiler_params,
+        compiler_params=_grid_params(interpret),
         interpret=interpret,
-    )(x, attn, wo, bo2, norm_w, n2b, w1, b12, wg, w2, b22)
-    return out
+    )(x[:, 0], attn[:, 0].astype(wo.dtype), wo, row(bo, d), n2w, n2b,
+      w1, row(b1, ffn), wg, w2, row(b2, d))
+    return out[:, None]
 
 
 # ============================================================== layer wrapper
@@ -700,42 +896,38 @@ def decode_block_layer(x, k_slab, v_slab, seq_pos, *, kv_heads, head_dim,
                        w1, b1, w2, b2, w_gate=None, act="gelu_tanh",
                        rope_cos=None, rope_sin=None,
                        block_k=None, block_f=None, interpret=None):
-    """One full transformer layer decode step through the fused kernel
-    pair.  Returns ``(y [B, 1, D], k_slab', v_slab')`` with the slabs
-    updated in place (kernel aliasing) at each slot's ``seq_pos``.
+    """One full transformer layer decode step through the fused
+    kernels.  Returns ``(y [B, 1, D], k_slab', v_slab')`` with the
+    slabs updated in place (kernel aliasing) at each slot's ``seq_pos``.
 
-    When ``block_k``/``block_f`` are not given they come from
-    :func:`plan_decode_block` at THIS call's shapes — the budgeted
-    tiles, not the kernels' untiled defaults — so every caller of the
-    layer wrapper (models' ``fused_decode_step``, the engine's decode
-    program, bench) launches exactly the working set the legality
-    check approved.  Raises if no tiling fits: callers are contracted
+    Tiles come from :func:`plan_decode_block` at THIS call's shapes —
+    the budgeted tiles, not the kernels' untiled defaults — so every
+    caller of the layer wrapper (models' ``fused_decode_step``, the
+    engine's decode program, bench) launches exactly the working set
+    the legality check approved; ``block_k``/``block_f`` override the
+    plan's choice.  Raises if no tiling fits: callers are contracted
     to gate on :func:`fusion_legal` / ``fused_decode_supported``
     first, so reaching the raise means the gate was skipped."""
-    if block_k is None or block_f is None:
-        b = x.shape[0]
-        heads = wq.shape[1] // head_dim
-        plan, why = plan_decode_block(
-            max_seq=k_slab.shape[1], hidden=x.shape[-1], heads=heads,
-            kv_heads=kv_heads, head_dim=head_dim, ffn=w1.shape[1],
-            batch=b, itemsize=jnp.dtype(x.dtype).itemsize,
-            gated=w_gate is not None)
-        if plan is None:
-            raise ValueError(
-                f"decode_block_layer: no VMEM tiling fits this shape "
-                f"({why}) — gate on fusion_legal/fused_decode_supported "
-                f"before calling the fused path")
-        block_k = block_k if block_k is not None else plan["block_k"]
-        block_f = block_f if block_f is not None else plan["block_f"]
+    plan, why = plan_decode_block(
+        max_seq=k_slab.shape[1], hidden=x.shape[-1],
+        heads=wq.shape[1] // head_dim, kv_heads=kv_heads,
+        head_dim=head_dim, ffn=w1.shape[1], batch=x.shape[0],
+        itemsize=jnp.dtype(x.dtype).itemsize, gated=w_gate is not None)
+    if plan is None:
+        raise ValueError(
+            f"decode_block_layer: no VMEM tiling fits this shape "
+            f"({why}) — gate on fusion_legal/fused_decode_supported "
+            f"before calling the fused path")
     attn, k2, v2 = decode_block_attn(
         x, k_slab, v_slab, seq_pos, norm1_w, norm1_b, wq, wk, wv,
         bq, bkv, bv, kv_heads=kv_heads, head_dim=head_dim, norm=norm,
-        eps=eps1, rope_cos=rope_cos, rope_sin=rope_sin, block_k=block_k,
+        eps=eps1, rope_cos=rope_cos, rope_sin=rope_sin,
+        block_k=block_k or plan["block_k"], block_n=plan["block_n"],
         interpret=interpret)
     y = decode_block_mlp(
         x, attn, wo, bo, norm2_w, norm2_b, w1, b1, w2, b2, w_gate,
-        norm=norm, eps=eps2, act=act, block_f=block_f,
-        interpret=interpret)
+        norm=norm, eps=eps2, act=act, block_f=block_f or plan["block_f"],
+        block_o=plan["block_o"], interpret=interpret)
     return y, k2, v2
 
 
@@ -745,21 +937,27 @@ def decode_block_reference(x, k_slab, v_slab, seq_pos, *, kv_heads,
                            norm2_b, w1, b1, w2, b2, w_gate=None,
                            act="gelu_tanh", rope_cos=None, rope_sin=None):
     """Composed-op XLA form with EXACTLY the kernel's masking semantics
-    and f32 rounding — the parity oracle for tests, mirroring how the
-    models' unfused layer path composes append_kv +
-    decode_attention_auto (same math, op by op)."""
+    and rounding — f32 norm / softmax / accumulation, every dot operand
+    in the weight dtype (the normed rows, the attention output and the
+    MLP activation round to it first, as the models' unfused layer does)
+    — the parity oracle for tests, mirroring how the unfused path
+    composes append_kv + decode_attention_auto (same math, op by op)."""
     from ..models.kv_cache import append_kv
     from .decode_attention import decode_attention_reference
     b, sq, d = x.shape
     heads = wq.shape[1] // head_dim
     dt = jnp.float32
+
+    def mm(a, w):
+        return a.astype(w.dtype).astype(dt) @ w.astype(dt)
+
     xr = x.astype(dt)
     xn = _norm_f32(xr, norm1_w.astype(dt),
                    norm1_b.astype(dt) if norm == "layer" else None,
                    norm, eps1)
-    q = (xn @ wq.astype(dt)).reshape(b, 1, heads, head_dim)
-    kx = (xn @ wk.astype(dt)).reshape(b, 1, kv_heads, head_dim)
-    vx = (xn @ wv.astype(dt)).reshape(b, 1, kv_heads, head_dim)
+    q = mm(xn, wq).reshape(b, 1, heads, head_dim)
+    kx = mm(xn, wk).reshape(b, 1, kv_heads, head_dim)
+    vx = mm(xn, wv).reshape(b, 1, kv_heads, head_dim)
     if bq is not None:
         q = q + bq.astype(dt).reshape(heads, head_dim)
     if bkv is not None:
@@ -776,22 +974,22 @@ def decode_block_reference(x, k_slab, v_slab, seq_pos, *, kv_heads,
     k2, v2 = append_kv(k_slab, v_slab, kx.astype(k_slab.dtype),
                        vx.astype(v_slab.dtype), pos)
     lens = pos + 1
-    out = decode_attention_reference(q.astype(x.dtype), k2, v2, lens)
-    attn = out.reshape(b, 1, heads * head_dim)
-    xm = xr + attn.astype(dt) @ wo.astype(dt)
+    out = decode_attention_reference(q, k2, v2, lens)
+    attn = out.reshape(b, 1, heads * head_dim).astype(k_slab.dtype)
+    xm = xr + mm(attn, wo)
     if bo is not None:
         xm = xm + bo.astype(dt)
     h = _norm_f32(xm, norm2_w.astype(dt),
                   norm2_b.astype(dt) if norm == "layer" else None,
                   norm, eps2)
-    t = h @ w1.astype(dt)
+    t = mm(h, w1)
     if b1 is not None:
         t = t + b1.astype(dt)
     if w_gate is not None:
-        a = jax.nn.silu(h @ w_gate.astype(dt)) * t
+        a = jax.nn.silu(mm(h, w_gate)) * t
     else:
         a = jax.nn.gelu(t, approximate=act == "gelu_tanh")
-    y = xm + a @ w2.astype(dt)
+    y = xm + mm(a, w2)
     if b2 is not None:
         y = y + b2.astype(dt)
     return y.astype(x.dtype), k2, v2

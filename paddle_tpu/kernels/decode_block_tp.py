@@ -19,12 +19,11 @@ instead of exclude each other (ROADMAP direction 2's sharded variant):
     ``allgather_matmul``, shared via ``collective_matmul.ring_schedule``
     so the XLA and in-kernel rings cannot drift).
   * **attention** — :func:`decode_block_attn_tp` is the per-shard
-    attention block: grid ``(KH/tp, B)`` over the LOCAL kv-head group,
-    matrix-form rotary, the fresh K/V row DMA'd **in-kernel** into the
-    LOCAL kv-head slab shard at the slot's ``seq_pos`` (the
-    ``serving/kv_pool`` slabs partition on the kv-head axis, so each
-    device appends exactly its own head rows — byte-identical lifecycle
-    semantics to ``decode_block.decode_block_attn``), then the same
+    attention block: ``decode_block.slab_decode_attention`` over the
+    LOCAL kv-head group — matrix-form rotary, the fresh K/V row DMA'd
+    **in-kernel** into the LOCAL kv-head slab shard at the slot's
+    ``seq_pos`` (the ``serving/kv_pool`` slabs partition on the kv-head
+    axis, so each device appends exactly its own head rows), then the
     double-buffered online-softmax streaming over the live slab tiles.
   * **exit** — :func:`ring_exit_matmul` lowers the reduce-scatter ring:
     each hop's partial (out-proj / MLP-down) accumulates tile-by-tile
@@ -65,8 +64,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .collective_matmul import ring_schedule
-from .decode_block import VMEM_BUDGET, _NEG_INF, _norm_f32, \
-    _rotate_half_matrix
+from .decode_block import VMEM_BUDGET, _dividing_tile, _fit_tile, \
+    _grid_params, _mxu_dot, _norm_f32, _plan_slab_attention, \
+    slab_decode_attention
 
 __all__ = ["plan_decode_block_tp", "ring_entry_matmul",
            "ring_exit_matmul", "decode_block_attn_tp",
@@ -94,23 +94,6 @@ __remote_dma_seams__ = {
 
 # ======================================================== planning / legality
 
-def _fit_tile(dim: int, per_unit: int, fixed: int, budget: int):
-    """Largest tile dividing ``dim`` whose streamed working set
-    ``fixed + per_unit * tile`` fits ``budget``; 128-multiples
-    preferred (the Mosaic lane rule), any divisor as the shrink
-    fallback — the same never-escalate posture as
-    ``decode_block_mlp``'s tile fixup.  None when no divisor fits."""
-    lane = [t for t in range(128, dim + 1, 128) if dim % t == 0]
-    for t in sorted(lane, reverse=True):
-        if fixed + per_unit * t <= budget:
-            return t
-    for t in sorted((t for t in range(1, dim + 1) if dim % t == 0),
-                    reverse=True):
-        if fixed + per_unit * t <= budget:
-            return t
-    return None
-
-
 def plan_decode_block_tp(*, max_seq: int, hidden: int, heads: int,
                          kv_heads: int, head_dim: int, ffn: int,
                          batch: int, itemsize: int, tp: int,
@@ -132,22 +115,13 @@ def plan_decode_block_tp(*, max_seq: int, hidden: int, heads: int,
     qkv_l = (h_l + 2 * kh_l) * dh
     up_l = f_l * (2 if gated else 1)
 
-    # ---- per-shard attention kernel (grid (KH/tp, B)): no weight
-    # residents — the projections rode the entry ring — just the fresh
-    # qkv row, rope tables and the double-buffered kv window
-    attn_fixed = ((rep + 2) * dh * itemsize          # fresh q group + k + v
-                  + 2 * rep * 128 * 4                # m + l scratch rows
-                  + rep * dh * 4 + 2 * dh * 4        # acc + stored k/v
-                  + 2 * dh * dh * 4)                 # rope tables + R
-    bk = min(1024, max_seq)
-    while max_seq % bk:
-        bk //= 2
-    while bk > 8 and attn_fixed + 4 * bk * dh * itemsize > vmem_budget:
-        bk //= 2
-    if attn_fixed + 4 * bk * dh * itemsize > vmem_budget:
-        return None, (f"vmem: tp attention residents "
-                      f"{attn_fixed + 4 * bk * dh * itemsize} bytes "
-                      f"exceed budget {vmem_budget} even at block_k={bk}")
+    # ---- per-shard slab attention kernel (grid (B,)) over the LOCAL
+    # kv-head group: the same kernel and accounting as tp=1
+    bk, vmem_attn = _plan_slab_attention(max_seq, kh_l, rep, dh, itemsize,
+                                         vmem_budget)
+    if bk is None:
+        return None, (f"vmem: tp attention residents {vmem_attn} bytes "
+                      f"exceed budget {vmem_budget} even at block_k=8")
 
     # ---- entry ring hop kernels: the [B/tp, D] travelling shard stays
     # resident while weight/bias/output tiles stream double-buffered
@@ -182,7 +156,7 @@ def plan_decode_block_tp(*, max_seq: int, hidden: int, heads: int,
                       f"per-device MLP-down rows {f_l}")
     return {"block_k": bk, "block_qkv": block_qkv, "block_up": block_up,
             "block_o": block_o, "block_down": block_down,
-            "vmem_attn": attn_fixed + 4 * bk * dh * itemsize,
+            "vmem_attn": vmem_attn,
             "vmem_entry": entry_fixed
             + entry_unit * max(block_qkv, block_up),
             "vmem_exit": exit_fixed
@@ -194,12 +168,9 @@ def plan_decode_block_tp(*, max_seq: int, hidden: int, heads: int,
 def _entry_kernel(x_ref, w_ref, b_ref, o_ref):
     """One output tile of a ring hop's dot: the resident travelling
     shard against one streamed weight column tile (+ its bias tile),
-    f32 contraction."""
-    dims = (((1,), (0,)), ((), ()))
-    o_ref[...] = (jax.lax.dot_general(
-        x_ref[...].astype(jnp.float32), w_ref[...].astype(jnp.float32),
-        dims, preferred_element_type=jnp.float32)
-        + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+    operands in the storage dtype, f32 accumulation."""
+    o_ref[...] = (_mxu_dot(x_ref[...], w_ref[...])
+                  + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
 def ring_entry_matmul(h, w_l, bias_l, axis_name: str, tp: int, *,
@@ -221,23 +192,21 @@ def ring_entry_matmul(h, w_l, bias_l, axis_name: str, tp: int, *,
         interpret = jax.default_backend() == "cpu"
     b_loc, k = h.shape
     n_l = w_l.shape[1]
-    bias = bias_l if bias_l is not None else jnp.zeros((n_l,), h.dtype)
-    bn = min(block_n or n_l, n_l)
-    while n_l % bn:
-        bn -= 1
-    compiler_params = None if interpret else pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",))
+    # the bias rides as a [1, N_l] row (Mosaic refuses rank-1 blocks)
+    bias = (bias_l if bias_l is not None
+            else jnp.zeros((n_l,), h.dtype)).reshape(1, n_l)
+    bn = _dividing_tile(n_l, block_n)
     hop_call = pl.pallas_call(
         _entry_kernel,
         grid=(n_l // bn,),
         in_specs=[
             pl.BlockSpec((b_loc, k), lambda i: (0, 0)),
             pl.BlockSpec((k, bn), lambda i: (0, i)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((b_loc, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((b_loc, n_l), h.dtype),
-        compiler_params=compiler_params,
+        compiler_params=_grid_params(interpret),
         interpret=interpret,
     )
     if tp == 1:
@@ -265,7 +234,6 @@ def _exit_kernel(g_ref, y_ref, w_ref, o_ref, acc_sc, *, nk, act):
     into the tile read (``act(up)`` never round-trips HBM), f32 scratch
     accumulation, emit on the last tile."""
     i = pl.program_id(0)
-    dims = (((1,), (0,)), ((), ()))
 
     @pl.when(i == 0)
     def _init():
@@ -278,9 +246,7 @@ def _exit_kernel(g_ref, y_ref, w_ref, o_ref, acc_sc, *, nk, act):
         t = jax.nn.gelu(t, approximate=True)
     elif act == "gelu":
         t = jax.nn.gelu(t, approximate=False)
-    acc_sc[...] = acc_sc[...] + jax.lax.dot_general(
-        t, w_ref[...].astype(jnp.float32), dims,
-        preferred_element_type=jnp.float32)
+    acc_sc[...] = acc_sc[...] + _mxu_dot(t.astype(w_ref.dtype), w_ref[...])
 
     @pl.when(i == nk - 1)
     def _emit():
@@ -310,9 +276,7 @@ def ring_exit_matmul(y, w_l, axis_name: str, tp: int, *,
     k_l = y.shape[1] // (2 if gated else 1)
     n = w_l.shape[1]
     b_l = b // tp
-    bf = min(block_f or k_l, k_l)
-    while k_l % bf:
-        bf -= 1
+    bf = _dividing_tile(k_l, block_f)
     nk = k_l // bf
     if gated:
         g_spec = pl.BlockSpec((b_l, bf), lambda i: (0, i))
@@ -325,8 +289,6 @@ def ring_exit_matmul(y, w_l, axis_name: str, tp: int, *,
         g_spec = pl.BlockSpec((b_l, bf), lambda i: (0, 0))
         y_spec = pl.BlockSpec((b_l, bf), lambda i: (0, i))
     kernel = functools.partial(_exit_kernel, nk=nk, act=act)
-    compiler_params = None if interpret else pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",))
     hop_call = pl.pallas_call(
         kernel,
         grid=(nk,),
@@ -338,7 +300,7 @@ def ring_exit_matmul(y, w_l, axis_name: str, tp: int, *,
         out_specs=pl.BlockSpec((b_l, n), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((b_l, n), y.dtype),
         scratch_shapes=[pltpu.VMEM((b_l, n), jnp.float32)],
-        compiler_params=compiler_params,
+        compiler_params=_grid_params(interpret),
         interpret=interpret,
     )
 
@@ -365,120 +327,6 @@ def ring_exit_matmul(y, w_l, axis_name: str, tp: int, *,
 
 # ==================================================== per-shard attention
 
-def _attn_tp_kernel(pos_ref, q_ref, k_ref, v_ref, cos_ref, sin_ref,
-                    rot_ref, k_any, v_any,
-                    attn_ref, ko_any, vo_any,
-                    m_sc, l_sc, acc_sc, knew_sc, vnew_sc, kbuf, vbuf,
-                    rsem, wsem, *, S, rep, dh, bk, scale, use_rope):
-    """``decode_block._attn_kernel`` minus the norm/projection front end
-    (those rode the entry ring): rotary -> in-kernel append into the
-    LOCAL slab shard -> double-buffered online-softmax streaming, with
-    byte-identical masking/lifecycle semantics."""
-    kh = pl.program_id(0)
-    b = pl.program_id(1)
-    pos = pos_ref[0]
-    dims = (((1,), (0,)), ((), ()))
-
-    qm = q_ref[0, 0].reshape(rep, dh).astype(jnp.float32)
-    kx = k_ref[0, 0].reshape(1, dh).astype(jnp.float32)
-    vx = v_ref[0, 0].reshape(1, dh).astype(jnp.float32)
-    if use_rope:
-        c = cos_ref[...].astype(jnp.float32)                # [1, dh]
-        s = sin_ref[...].astype(jnp.float32)
-        rot = rot_ref[...]
-        qm = qm * c + jax.lax.dot_general(
-            qm, rot, dims, preferred_element_type=jnp.float32) * s
-        kx = kx * c + jax.lax.dot_general(
-            kx, rot, dims, preferred_element_type=jnp.float32) * s
-    qm = qm * scale
-
-    # ---- in-kernel KV append into the LOCAL kv-head slab shard
-    # (dynamic_update_slice's clamp: a full slot overwrites its last
-    # row, matching the unfused path)
-    posw = jnp.minimum(pos, S - 1)
-    knew_sc[...] = kx.astype(knew_sc.dtype)
-    vnew_sc[...] = vx.astype(vnew_sc.dtype)
-    kw_cp = pltpu.make_async_copy(knew_sc, ko_any.at[b, pl.ds(posw, 1), kh],
-                                  wsem.at[0])
-    vw_cp = pltpu.make_async_copy(vnew_sc, vo_any.at[b, pl.ds(posw, 1), kh],
-                                  wsem.at[1])
-    kw_cp.start()
-    vw_cp.start()
-
-    # ---- stream the live tiles once, double-buffered (pos bounds the
-    # loop, so dead tiles are never even DMA'd)
-    lim = posw
-    nlive = jax.lax.div(lim + bk - 1, bk)
-    m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
-    l_sc[...] = jnp.zeros_like(l_sc)
-    acc_sc[...] = jnp.zeros_like(acc_sc)
-
-    def k_cp(slot, ki):
-        return pltpu.make_async_copy(
-            k_any.at[b, pl.ds(ki * bk, bk), kh], kbuf.at[slot],
-            rsem.at[0, slot])
-
-    def v_cp(slot, ki):
-        return pltpu.make_async_copy(
-            v_any.at[b, pl.ds(ki * bk, bk), kh], vbuf.at[slot],
-            rsem.at[1, slot])
-
-    @pl.when(nlive > 0)
-    def _prefetch():
-        k_cp(0, 0).start()
-        v_cp(0, 0).start()
-
-    def _update(s_blk, v_blk, kpos_valid):
-        """One online-softmax step (decode_attention's recurrence)."""
-        s_blk = jnp.where(kpos_valid, s_blk, _NEG_INF)
-        m_prev = m_sc[...]
-        l_prev = l_sc[...]
-        m_curr = jnp.max(s_blk, axis=1)[:, None]
-        m_next = jnp.maximum(m_prev, m_curr)
-        m_safe = jnp.where(m_next == _NEG_INF, 0.0, m_next)
-        p = jnp.exp(s_blk - m_safe[:, :1])
-        alpha = jnp.exp(m_prev - m_safe)
-        l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
-        m_sc[...] = m_next
-        acc_sc[...] = acc_sc[...] * alpha[:, :1] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    def _body(ki, carry):
-        slot = jax.lax.rem(ki, 2)
-
-        @pl.when(ki + 1 < nlive)
-        def _next():
-            k_cp(1 - slot, ki + 1).start()
-            v_cp(1 - slot, ki + 1).start()
-
-        k_cp(slot, ki).wait()
-        v_cp(slot, ki).wait()
-        kt = kbuf[slot].astype(jnp.float32)                 # [bk, dh]
-        vt = vbuf[slot].astype(jnp.float32)
-        s_blk = jax.lax.dot_general(qm, kt, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (rep, bk), 1)
-        _update(s_blk, vt, kpos < lim)
-        return carry
-
-    jax.lax.fori_loop(0, nlive, _body, 0)
-
-    # ---- the fresh token folds in last, always valid (it reads its own
-    # STORED k/v so storage-dtype rounding matches the unfused path)
-    kq = knew_sc[...].astype(jnp.float32)                   # [1, dh]
-    vq = vnew_sc[...].astype(jnp.float32)
-    s_new = jax.lax.dot_general(qm, kq, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    _update(s_new, vq, jnp.full((rep, 1), True))
-
-    l = l_sc[...][:, :1]
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    attn_ref[0, 0] = (acc_sc[...] / l_safe).astype(attn_ref.dtype)
-    kw_cp.wait()
-    vw_cp.wait()
-
-
 def decode_block_attn_tp(q, k, v, k_slab, v_slab, seq_pos, *,
                          kv_heads: int, head_dim: int,
                          scale: Optional[float] = None,
@@ -487,7 +335,9 @@ def decode_block_attn_tp(q, k, v, k_slab, v_slab, seq_pos, *,
                          interpret: Optional[bool] = None):
     """Per-shard attention block: rotary -> in-kernel KV append into
     the LOCAL slab shard -> streaming decode attention over the local
-    kv-head group.
+    kv-head group — ``decode_block.slab_decode_attention`` on this
+    device's slab shard (the ``serving/kv_pool`` slabs partition on the
+    kv-head axis, so each device appends exactly its own head rows).
 
     ``q [B, H_l*Dh]`` / ``k``/``v [B, KH_l*Dh]`` are THIS device's head
     group's fresh projections (the entry ring's output, kv-head-grouped
@@ -496,76 +346,14 @@ def decode_block_attn_tp(q, k, v, k_slab, v_slab, seq_pos, *,
     cache lengths BEFORE this token.  ``kv_heads`` is the LOCAL count.
     Returns ``(attn [B, H_l*Dh], k_slab', v_slab')``."""
     b = q.shape[0]
-    s_max, kh_l, dh = k_slab.shape[1], k_slab.shape[2], k_slab.shape[3]
+    kh_l, dh = k_slab.shape[2], k_slab.shape[3]
     assert kh_l == kv_heads and dh == head_dim
-    rep = q.shape[1] // (kv_heads * dh)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    scale = scale if scale is not None else 1.0 / (head_dim ** 0.5)
-    pos1 = jnp.asarray(seq_pos, jnp.int32)
-    if pos1.ndim == 0:
-        pos1 = jnp.broadcast_to(pos1, (b,))
-    bk = min(block_k or min(1024, s_max), s_max)
-    while s_max % bk:
-        bk //= 2
-    use_rope = rope_cos is not None
-    q3 = q.reshape(b, kv_heads, rep * dh)
-    k3 = k.reshape(b, kv_heads, dh)
-    v3 = v.reshape(b, kv_heads, dh)
-    if use_rope:
-        cosf, sinf = rope_cos, rope_sin
-        rot = _rotate_half_matrix(dh)
-    else:
-        cosf = jnp.ones((b, dh), jnp.float32)
-        sinf = jnp.zeros((b, dh), jnp.float32)
-        rot = jnp.zeros((dh, dh), jnp.float32)
-
-    kernel = functools.partial(
-        _attn_tp_kernel, S=s_max, rep=rep, dh=dh, bk=bk, scale=scale,
-        use_rope=use_rope)
-    compiler_params = None if interpret else pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary"))
-    attn4, k2, v2 = pl.pallas_call(
-        kernel,
-        grid=(kv_heads, b),
-        in_specs=[
-            pl.BlockSpec((1,), lambda kh, bi: (bi,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, rep * dh), lambda kh, bi: (bi, kh, 0)),
-            pl.BlockSpec((1, 1, dh), lambda kh, bi: (bi, kh, 0)),
-            pl.BlockSpec((1, 1, dh), lambda kh, bi: (bi, kh, 0)),
-            pl.BlockSpec((1, dh), lambda kh, bi: (bi, 0)),
-            pl.BlockSpec((1, dh), lambda kh, bi: (bi, 0)),
-            pl.BlockSpec((dh, dh), lambda kh, bi: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, rep, dh), lambda kh, bi: (bi, kh, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kv_heads, rep, dh), q.dtype),
-            jax.ShapeDtypeStruct(k_slab.shape, k_slab.dtype),
-            jax.ShapeDtypeStruct(v_slab.shape, v_slab.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((rep, 128), jnp.float32),
-            pltpu.VMEM((rep, 128), jnp.float32),
-            pltpu.VMEM((rep, dh), jnp.float32),
-            pltpu.VMEM((1, dh), k_slab.dtype),
-            pltpu.VMEM((1, dh), v_slab.dtype),
-            pltpu.VMEM((2, bk, dh), k_slab.dtype),
-            pltpu.VMEM((2, bk, dh), v_slab.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        input_output_aliases={7: 1, 8: 2},
-        compiler_params=compiler_params,
-        interpret=interpret,
-    )(pos1, q3, k3, v3, cosf, sinf, rot, k_slab, v_slab)
-    return attn4.reshape(b, kv_heads * rep * dh), k2, v2
+    attn, k2, v2 = slab_decode_attention(
+        q.reshape(b, kv_heads, -1, dh), k.reshape(b, kv_heads, dh),
+        v.reshape(b, kv_heads, dh), k_slab, v_slab, seq_pos, scale=scale,
+        rope_cos=rope_cos, rope_sin=rope_sin, block_k=block_k,
+        interpret=interpret)
+    return attn.reshape(b, -1).astype(q.dtype), k2, v2
 
 
 # ============================================================== layer wrapper

@@ -115,11 +115,11 @@ def _flatten_and_pick_block(x):
     rows = x2.shape[0]
     if rows == 0:
         return x2, 0
-    # cap block x h x 4B (the f32 working copy) at 4 MiB: the r4 on-chip
-    # sweep showed Mosaic scoped-vmem failures for blocks past that (e.g.
-    # any legal block at h=8192 with the old flat 256 cap), which forced
-    # a compile-error fallback instead of a working kernel
-    cap = max(8, min(256, (4 * 1024 * 1024) // (4 * h)))
+    # cap block x h x 4B (the f32 working copy) at 2 MiB: the backward
+    # holds the x, g and dx blocks plus their f32 temporaries at once —
+    # at a 4 MiB copy (256 rows of h=4096) Mosaic's scoped allocation is
+    # 22 MiB against the 16 MiB limit and the kernel does not compile
+    cap = max(8, min(256, (2 * 1024 * 1024) // (4 * h)))
     if rows <= cap:
         return x2, rows          # one block == full array: always legal
     # sublane tile is 16 for 2-byte dtypes, 8 for f32

@@ -7,8 +7,7 @@ sampling with temperature, top-k, top-p, eos early-stop).
 TPU-native design: the whole token-by-token loop is a single
 ``lax.scan`` over the functional KV-cache ``decode_step`` — one compiled
 program for the entire generation instead of one dispatch per token
-(per-dispatch latency dominates small decode steps on a remote-attached
-chip; the same lesson as scripts/tpu_microbench).  The prompt is
+(per-dispatch host latency dominates small decode steps).  The prompt is
 prefilled in one chunked ``decode_step`` call (causal within the chunk),
 then the scan carries ``(caches, last_token, position, rng, finished)``;
 shapes are static throughout (``max_new_tokens`` is a trace-time int).

@@ -375,8 +375,12 @@ class GPTHybridTrainer:
     def train_step(self, state_tuple, ids, labels):
         pnb, pblk, onb, oblk = state_tuple
         lr = jnp.asarray(self.opt.get_lr(), jnp.float32)
-        pnb, pblk, onb, oblk, loss = self.jit_step()(
-            pnb, pblk, onb, oblk, ids, labels, lr)
+        # traced with the mesh in scope: the model's sharding
+        # constraints bind to it, and kernel routes can see that this
+        # program is partitioned by XLA (kernels/routing.py)
+        with jax.set_mesh(self.mesh):
+            pnb, pblk, onb, oblk, loss = self.jit_step()(
+                pnb, pblk, onb, oblk, ids, labels, lr)
         return (pnb, pblk, onb, oblk), loss
 
 

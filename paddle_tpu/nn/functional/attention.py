@@ -41,11 +41,6 @@ def _warn_traced_fallback():
                "path; pass assume_aligned=True if the packs match")
 
 
-def _warn_kernel_fallback(e: Exception):
-    _warn_once("varlen_kernel", f"flash_attn_unpadded: Pallas varlen route "
-               f"failed ({type(e).__name__}: {e}); using the dense path")
-
-
 def _causal_mask(sq, sk, dtype):
     i = jnp.arange(sq)[:, None]
     j = jnp.arange(sk)[None, :]
@@ -107,11 +102,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         and jax.default_backend() not in ("cpu",)
     )
     if use_pallas:
-        try:
-            from ...kernels.flash_attention import flash_attention as _pallas_fa
-            return _pallas_fa(query, key, value, causal=is_causal)
-        except Exception:
-            pass  # fall back to XLA path (e.g. unsupported shape/platform)
+        # a kernel the route selected either runs or raises: a failure
+        # that quietly went dense would read as a slow chip
+        from ...kernels.flash_attention import flash_attention as _pallas_fa
+        return _pallas_fa(query, key, value, causal=is_causal)
     return sdpa_reference(query, key, value, attn_mask, dropout_p, is_causal,
                           training=training)
 
@@ -173,24 +167,18 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
         and jax.default_backend() not in ("cpu",)   # dense XLA wins on CPU
         and _aligned())
     if kernel_ok:
-        try:
-            from ...kernels.flash_attention import flash_attention_varlen
-            pad_q = (-t) % 128
-            pad_k = (-tk) % 128
-            qp = jnp.pad(query, [(0, pad_q), (0, 0), (0, 0)])
-            kp = jnp.pad(key, [(0, pad_k), (0, 0), (0, 0)])
-            vp = jnp.pad(value, [(0, pad_k), (0, 0), (0, 0)])
-            # padding rows: ids that match nothing real (nor each other)
-            sq = jnp.pad(seg_q, (0, pad_q), constant_values=-1)[None]
-            sk_ = jnp.pad(seg_k, (0, pad_k), constant_values=-2)[None]
-            out = flash_attention_varlen(qp[None], kp[None], vp[None], sq,
-                                         sk_, causal=causal, scale=scale)[0]
-            return out[:t], None
-        except Exception as e:
-            # fall back to the dense path for robustness, but never
-            # silently: a broken kernel masquerading as a perf regression
-            # is undiagnosable
-            _warn_kernel_fallback(e)
+        from ...kernels.flash_attention import flash_attention_varlen
+        pad_q = (-t) % 128
+        pad_k = (-tk) % 128
+        qp = jnp.pad(query, [(0, pad_q), (0, 0), (0, 0)])
+        kp = jnp.pad(key, [(0, pad_k), (0, 0), (0, 0)])
+        vp = jnp.pad(value, [(0, pad_k), (0, 0), (0, 0)])
+        # padding rows: ids that match nothing real (nor each other)
+        sq = jnp.pad(seg_q, (0, pad_q), constant_values=-1)[None]
+        sk_ = jnp.pad(seg_k, (0, pad_k), constant_values=-2)[None]
+        out = flash_attention_varlen(qp[None], kp[None], vp[None], sq,
+                                     sk_, causal=causal, scale=scale)[0]
+        return out[:t], None
     logits = jnp.einsum("qhd,khd->hqk", query, key,
                         preferred_element_type=jnp.float32) * scale
     mask = seg_q[:, None] == seg_k[None, :]

@@ -43,12 +43,9 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon: float = 1e-
         from ...kernels.routing import use_pallas as _route
         if (_flags.use_pallas_norm and _on_tpu()
                 and _route("layer_norm", rows=rows, h=h_last)):
-            try:
-                import paddle_tpu.kernels as _k
-                return _k.fused_layer_norm_pallas(x, weight, bias,
-                                                  epsilon, interpret=False)
-            except Exception:
-                pass   # fall back to the XLA form (same pattern as sdpa)
+            import paddle_tpu.kernels as _k
+            return _k.fused_layer_norm_pallas(x, weight, bias, epsilon,
+                                              interpret=False)
     x32 = x.astype(jnp.float32) if x.dtype in (jnp.float16, jnp.bfloat16) else x
     mean = jnp.mean(x32, axis=axes, keepdims=True)
     var = jnp.mean(jnp.square(x32 - mean), axis=axes, keepdims=True)

@@ -473,16 +473,20 @@ def _on_mesh(core, x):
 
 def _staging_example(core):
     """Example prefill staging rows, built through the SAME compiled
-    zero-staging program shape ``_begin_prefill`` uses — identical
-    shapes, dtypes and (under tp) shardings to the runtime operands."""
-    model, max_seq = core.model, core.pool.max_seq
-
-    def fresh_staging():
-        caches = model.init_cache(1, max_seq)
-        return [c[0] for c in caches], [c[1] for c in caches]
-
+    zero-staging program ``_begin_prefill`` uses — identical shapes,
+    dtypes and (under tp) shardings to the runtime operands."""
     with core._mesh_scope():
-        return jax.jit(fresh_staging)()
+        return core._build_staging_init_fn()()
+
+
+def _baked(program, donate: Tuple[int, ...]) -> Callable:
+    """The engine's ``program`` with its bound weight operand captured
+    instead of passed: a stored artifact is self-contained (weights
+    compiled in as constants — its point, and its size limit), and
+    keeps the ``(ks, vs, ...)`` operand list the load path dispatches."""
+    # graftlint: disable-next=recompile-hazard -- one-shot export: each wrapper is traced exactly once by jax.export and then dropped
+    return jax.jit(lambda *operands: program(*operands),
+                   donate_argnums=donate)
 
 
 def _export_programs(core, writer: AOTStoreWriter) -> None:
@@ -492,7 +496,7 @@ def _export_programs(core, writer: AOTStoreWriter) -> None:
     the engine's real device state (plus replicated host scalars), so
     exported shardings match what the runtime will pass."""
     ks, vs = _staging_example(core)
-    prefill = core._build_prefill_fn()
+    prefill = _baked(core._build_prefill_fn(), (0, 1))
     pos = _on_mesh(core, jnp.asarray(0, jnp.int32))
     for w in writer.widths:
         t0 = time.perf_counter()
@@ -503,7 +507,7 @@ def _export_programs(core, writer: AOTStoreWriter) -> None:
                    build_s=time.perf_counter() - t0)
 
     t0 = time.perf_counter()
-    decode = core._build_decode_fn()
+    decode = _baked(core._build_decode_fn(), (0, 1))
     n = core.num_slots
     vocab = int(core.model.cfg.vocab_size)
     sampling = (_on_mesh(core, jnp.tile(jax.random.PRNGKey(0)[None],
@@ -526,7 +530,7 @@ def _export_programs(core, writer: AOTStoreWriter) -> None:
         # fixed-shape draft window (the engine keys the leg on the
         # decode path, exactly like decode itself)
         t0 = time.perf_counter()
-        verify = core._build_verify_fn()
+        verify = _baked(core._build_verify_fn(), (0, 1))
         vargs = args + (
             _on_mesh(core, jnp.zeros((n, core.spec_k), jnp.int32)),
             _on_mesh(core, jnp.zeros((n,), jnp.int32)))

@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.generation import _filter_top_p
+from ..nn.functional_call import bind_state, state
 from .aot import AOTStoreError, engine_aot_context, aot_fingerprint
 from .errors import EngineStalledError, RequestRejected
 from .health import (DegradationLadder, EngineHealth,
@@ -636,6 +637,14 @@ class EngineCore:
         self._last_tok = jnp.zeros((num_slots,), jnp.int32)
         key0 = jax.random.PRNGKey(0)
         self._keys = jnp.tile(key0[None], (num_slots,) + (1,) * key0.ndim)
+        if self.mesh is not None:
+            # born on the mesh: an array's type carries its mesh, so
+            # single-device row state would make the decode program's
+            # second call (fed its own mesh-resident outputs) a new
+            # signature — a second trace and compile of the ONE decode
+            from . import tp as _tp
+            self._last_tok = _tp.replicated(self._last_tok, self.mesh)
+            self._keys = _tp.replicated(self._keys, self.mesh)
         # per-slot sampling params: host numpy mirrors, re-uploaded to a
         # cached device copy only when admission/eviction dirties them
         # (values are traced row data — changing them never recompiles)
@@ -674,20 +683,34 @@ class EngineCore:
         return self.metrics.request_lane(req.request_id)
 
     # ----------------------------------------------------------- prefill
+    def _model_weights(self):
+        """``(params, buffers)`` of the model, the weight operand of
+        every composed program.  The programs take the parameters as
+        OPERAND 0, bound with ``functools.partial`` so dispatch sites
+        keep the ``(ks, vs, ...)`` signature: an array a jitted function
+        closes over is compiled into the program as a constant — one
+        private copy of the whole model per program, in host memory
+        while it lowers and in HBM once loaded.  Harmless at test
+        sizes; at published widths it is gigabytes per prefill width."""
+        return state(self.model)
+
     def _build_prefill_fn(self) -> Callable:
         model = self.model
+        params, buffers = self._model_weights()
 
-        def prefill(ks, vs, ids, pos, valid):
+        def prefill(params, ks, vs, ids, pos, valid):
             self.trace_counts["prefill"] += 1  # trace-time side effect
             caches = [(k, v, pos) for k, v in zip(ks, vs)]
-            logits, caches = model.decode_step(ids, caches, pos)
+            with bind_state(model, params, buffers):
+                logits, caches = model.decode_step(ids, caches, pos)
             last = jnp.take_along_axis(
                 logits, (valid - 1)[None, None, None], axis=1)[0, 0]
             return (last.astype(jnp.float32),
                     [c[0] for c in caches], [c[1] for c in caches])
 
         # donating the staging rows threads them chunk to chunk in place
-        return jax.jit(prefill, donate_argnums=(0, 1))
+        return functools.partial(
+            jax.jit(prefill, donate_argnums=(1, 2)), params)
 
     def _prefill_cost(self, req: Request) -> int:
         """Tokens of prefill work admitting ``req`` costs THIS step: the
@@ -742,7 +765,7 @@ class EngineCore:
         (a no-op scope on single-chip engines) — the same push
         ``_step_impl`` performs for the step programs."""
         if self.mesh is not None:
-            return self.mesh
+            return jax.set_mesh(self.mesh)
         return contextlib.nullcontext()
 
     def export_gather(self, match: MatchResult):
@@ -838,14 +861,7 @@ class EngineCore:
                 # ONE compiled zero-staging builder instead of 2*num_layers
                 # eager jnp.zeros dispatches per miss admission
                 if self._staging_init_fn is None:
-                    model, max_seq = self.model, self.pool.max_seq
-
-                    def fresh_staging():
-                        caches = model.init_cache(1, max_seq)
-                        return ([c[0] for c in caches],
-                                [c[1] for c in caches])
-
-                    self._staging_init_fn = jax.jit(fresh_staging)
+                    self._staging_init_fn = self._build_staging_init_fn()
                 ks, vs = self._staging_init_fn()
             t_gather1 = time.perf_counter()
             plan = self.scheduler.chunk_plan(matched, req.prompt_len,
@@ -878,6 +894,25 @@ class EngineCore:
                 self.prefix_cache.release(match)
             self.pool.free(slot)
             raise
+
+    def _build_staging_init_fn(self) -> Callable:
+        """The compiled builder of one request's zeroed staging rows
+        (per-layer ``[1, max_seq, kv_heads, head_dim]`` K and V lists).
+        Under a mesh the rows are born kv-head-sharded, the layout (and
+        so the type) every later chunk's staging output has — one
+        prefill trace per width."""
+        model, max_seq = self.model, self.pool.max_seq
+
+        def fresh_staging():
+            caches = model.init_cache(1, max_seq)
+            return [c[0] for c in caches], [c[1] for c in caches]
+
+        sharding = None
+        if self.mesh is not None:
+            from . import tp as _tp
+            sharding = jax.sharding.NamedSharding(self.mesh,
+                                                  _tp.KV_SLAB_SPEC)
+        return jax.jit(fresh_staging, out_shardings=sharding)
 
     def _run_chunk(self, st: _Prefill) -> None:
         """Dispatch one prefill chunk of ``st`` (async — no readback)."""
@@ -1143,13 +1178,17 @@ class EngineCore:
         if self.decode_path in ("tp_fused", "tp_fused_block"):
             return self._build_tp_decode_fn()
 
-        def decode(ks, vs, seq_pos, last_tok, keys, do_sample,
+        params, buffers = self._model_weights()
+
+        def decode(params, ks, vs, seq_pos, last_tok, keys, do_sample,
                    temperature, top_k, top_p, mask):
             self.trace_counts["decode"] += 1  # trace-time side effect
             caches = [(k, v, seq_pos) for k, v in zip(ks, vs)]
             step_fn = model.fused_decode_step if fused else \
                 model.decode_step
-            logits, caches = step_fn(last_tok[:, None], caches, seq_pos)
+            with bind_state(model, params, buffers):
+                logits, caches = step_fn(last_tok[:, None], caches,
+                                         seq_pos)
             split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
             nxt = sample_rows(split[:, 1], logits[:, 0], do_sample,
                               temperature, top_k, top_p, mask=mask)
@@ -1164,7 +1203,8 @@ class EngineCore:
 
         # donating the KV slabs aliases them in place — pool memory stays
         # a single allocation across the whole serving run
-        return jax.jit(decode, donate_argnums=(0, 1))
+        return functools.partial(
+            jax.jit(decode, donate_argnums=(1, 2)), params)
 
     def _build_tp_decode_fn(self) -> Callable:
         """The tensor-parallel fused compute-collective decode: ONE
@@ -1189,13 +1229,13 @@ class EngineCore:
                 pallas_block=self.decode_path == "tp_fused_block",
                 batch=self.num_slots, max_seq=self.pool.max_seq)
             self._tp_program_path = self.decode_path
-        program = self._tp_program
+        program, weights = self._tp_program
 
-        def decode(ks, vs, seq_pos, last_tok, keys, do_sample,
+        def decode(weights, ks, vs, seq_pos, last_tok, keys, do_sample,
                    temperature, top_k, top_p, mask):
             self.trace_counts["decode"] += 1  # trace-time side effect
             logits, new_ks, new_vs, new_pos = program(
-                ks, vs, seq_pos, last_tok)
+                weights, ks, vs, seq_pos, last_tok)
             split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
             lg = logits[:, 0]
             nxt = sample_rows(split[:, 1], lg, do_sample,
@@ -1204,7 +1244,8 @@ class EngineCore:
             return (new_ks, new_vs, new_pos, nxt.astype(jnp.int32),
                     split[:, 0])
 
-        return jax.jit(decode, donate_argnums=(0, 1))
+        return functools.partial(
+            jax.jit(decode, donate_argnums=(1, 2)), weights)
 
     def _decode_dispatch(self) -> jax.Array:
         """ONE fixed-shape decode step over every slot; returns the
@@ -1259,12 +1300,15 @@ class EngineCore:
         if self.decode_path in ("tp_fused", "tp_fused_block"):
             return self._build_tp_verify_fn()
 
-        def verify(ks, vs, seq_pos, last_tok, keys, do_sample,
+        params, buffers = self._model_weights()
+
+        def verify(params, ks, vs, seq_pos, last_tok, keys, do_sample,
                    temperature, top_k, top_p, mask, drafts, draft_len):
             self.trace_counts["verify"] += 1  # trace-time side effect
             caches = [(k, v, seq_pos) for k, v in zip(ks, vs)]
             ids = jnp.concatenate([last_tok[:, None], drafts], axis=1)
-            logits, caches = model.decode_step(ids, caches, seq_pos)
+            with bind_state(model, params, buffers):
+                logits, caches = model.decode_step(ids, caches, seq_pos)
             committed, accepted, new_keys = _verify_tail(
                 logits, drafts, draft_len, keys, do_sample, temperature,
                 top_k, top_p, mask, self.spec_k)
@@ -1280,7 +1324,8 @@ class EngineCore:
             return (new_ks, new_vs, new_pos,
                     new_last.astype(jnp.int32), packed, new_keys)
 
-        return jax.jit(verify, donate_argnums=(0, 1))
+        return functools.partial(
+            jax.jit(verify, donate_argnums=(1, 2)), params)
 
     def _build_tp_verify_fn(self) -> Callable:
         """Tensor-parallel fused verify: the width-``spec_k+1`` member
@@ -1298,13 +1343,14 @@ class EngineCore:
                 self.model, self.mesh, self.tensor_parallel,
                 width=self.spec_k + 1)
             self._tp_verify_program_path = self.decode_path
-        program = self._tp_verify_program
+        program, weights = self._tp_verify_program
 
-        def verify(ks, vs, seq_pos, last_tok, keys, do_sample,
+        def verify(weights, ks, vs, seq_pos, last_tok, keys, do_sample,
                    temperature, top_k, top_p, mask, drafts, draft_len):
             self.trace_counts["verify"] += 1  # trace-time side effect
             ids = jnp.concatenate([last_tok[:, None], drafts], axis=1)
-            logits, new_ks, new_vs, _ = program(ks, vs, seq_pos, ids)
+            logits, new_ks, new_vs, _ = program(weights, ks, vs, seq_pos,
+                                                ids)
             committed, accepted, new_keys = _verify_tail(
                 logits, drafts, draft_len, keys, do_sample, temperature,
                 top_k, top_p, mask, self.spec_k)
@@ -1316,7 +1362,8 @@ class EngineCore:
             return (new_ks, new_vs, new_pos,
                     new_last.astype(jnp.int32), packed, new_keys)
 
-        return jax.jit(verify, donate_argnums=(0, 1))
+        return functools.partial(
+            jax.jit(verify, donate_argnums=(1, 2)), weights)
 
     def _verify_dispatch(self, drafts: np.ndarray,
                          draft_len: np.ndarray) -> jax.Array:
@@ -1413,7 +1460,7 @@ class EngineCore:
         step dispatches.  Single-chip engines skip the push entirely."""
         if self.mesh is None:
             return self._step_body()
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return self._step_body()
 
     def _step_body(self) -> int:

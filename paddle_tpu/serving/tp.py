@@ -373,12 +373,12 @@ def build_tp_decode_program(model, mesh: Mesh, tp: int, *,
                             pallas_block: bool = False,
                             batch: Optional[int] = None,
                             max_seq: Optional[int] = None):
-    """Build the engine's fused compute-collective decode program:
-    ``fn(ks, vs, seq_pos, last_tok) -> (logits, new_ks, new_vs,
-    new_pos)`` with ``logits [num_slots, 1, vocab]`` vocab-sharded over
-    the mesh.  NOT jitted — the engine wraps it together with its
-    sampling tail in the single compiled decode step, so the program-set
-    pin (ONE decode) is unchanged.
+    """Build the engine's fused compute-collective decode program.
+    Returns ``(fn, weights)``: ``fn(weights, ks, vs, seq_pos, last_tok)
+    -> (logits, new_ks, new_vs, new_pos)`` with ``logits [num_slots, 1,
+    vocab]`` vocab-sharded over the mesh.  NOT jitted — the engine wraps
+    it together with its sampling tail in the single compiled decode
+    step, so the program-set pin (ONE decode) is unchanged.
 
     ``pallas_block=True`` builds the ``tp_fused_block`` variant: the
     layer bodies run the sharded Pallas decode-block kernels
@@ -390,8 +390,10 @@ def build_tp_decode_program(model, mesh: Mesh, tp: int, *,
     ``decode_block.resolve_fused_decode(tp=...)`` first.
 
     The weight bundle is laid out here once (device_put per
-    ``_BUNDLE_SPECS``); the returned closure captures it, exactly like
-    the composed path captures the model's own parameters."""
+    ``_BUNDLE_SPECS``) and RETURNED, never captured: the jitted caller
+    passes it as an operand, because an array a jitted function closes
+    over is compiled into the program as a constant — gathered to the
+    host and copied whole into every program, which un-shards it."""
     from ..distributed._jax_compat import shard_map
     from ..distributed.sharding_utils import put_global
     arch, weights = model.tp_decode_weights(tp)
@@ -423,14 +425,14 @@ def build_tp_decode_program(model, mesh: Mesh, tp: int, *,
                              pallas_plan=pallas_plan)
     slab = [KV_SLAB_SPEC] * num_layers
 
-    def program(ks, vs, seq_pos, last_tok):
+    def program(weights, ks, vs, seq_pos, last_tok):
         return shard_map(
             body, mesh=mesh,
             in_specs=(specs, slab, slab, P(), P()),
             out_specs=(P(None, None, "mp"), slab, slab, P()),
             check_vma=False)(weights, ks, vs, seq_pos, last_tok)
 
-    return program
+    return program, weights
 
 
 def _tp_verify_body(weights, ks, vs, seq_pos, ids, *, arch, tp, axis,
@@ -482,9 +484,10 @@ def _tp_verify_body(weights, ks, vs, seq_pos, ids, *, arch, tp, axis,
 
 def build_tp_verify_program(model, mesh: Mesh, tp: int, *, width: int,
                             overlap: bool = True):
-    """Build the fused verify program of the speculative-decoding path:
-    ``fn(ks, vs, seq_pos, ids) -> (logits, new_ks, new_vs, new_pos)``
-    with ``ids [num_slots, width]`` (each slot's last committed token
+    """Build the fused verify program of the speculative-decoding path.
+    Returns ``(fn, weights)`` like ``build_tp_decode_program``:
+    ``fn(weights, ks, vs, seq_pos, ids) -> (logits, new_ks, new_vs,
+    new_pos)`` with ``ids [num_slots, width]`` (each slot's last committed token
     followed by its zero-padded draft window) and ``logits [num_slots,
     width, vocab]`` vocab-sharded over the mesh.  NOT jitted — the
     engine wraps it with its matched-sampling acceptance tail in the
@@ -514,11 +517,11 @@ def build_tp_verify_program(model, mesh: Mesh, tp: int, *, width: int,
                              axis=TP_AXIS, overlap=overlap, width=width)
     slab = [KV_SLAB_SPEC] * num_layers
 
-    def program(ks, vs, seq_pos, ids):
+    def program(weights, ks, vs, seq_pos, ids):
         return shard_map(
             body, mesh=mesh,
             in_specs=(specs, slab, slab, P(), P()),
             out_specs=(P(None, None, "mp"), slab, slab, P()),
             check_vma=False)(weights, ks, vs, seq_pos, ids)
 
-    return program
+    return program, weights

@@ -871,7 +871,9 @@ def _attach_holders(project: Project, unit: CompileUnit,
                         memo = True
                 elif isinstance(t, ast.Name):
                     local_name = t.id
-            elif isinstance(n, ast.Return) and n.value is unit.call:
+            elif isinstance(n, ast.Return) and (
+                    n.value is unit.call
+                    or _binds_operands_of(n.value, unit.call)):
                 returned = True
         if local_name is not None:
             holders.add(local_name)
@@ -922,6 +924,15 @@ def _attach_holders(project: Project, unit: CompileUnit,
             frontier = nxt
     unit.holders = tuple(sorted(holders))
     unit.memoized = memo or unit.owner is None
+
+
+def _binds_operands_of(node: ast.AST, call: ast.AST) -> bool:
+    """``functools.partial(<the jit call>, weights...)``: the compiled
+    program returned with its leading operands bound (how the engine
+    hands its programs their weights) is still that program."""
+    return (isinstance(node, ast.Call)
+            and dotted_name(node.func) in PARTIAL_NAMES
+            and bool(node.args) and node.args[0] is call)
 
 
 def _nested_def_containing(scope: ast.AST,

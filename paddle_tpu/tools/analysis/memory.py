@@ -237,51 +237,8 @@ def _fold_int(node: ast.AST) -> Optional[int]:
 # mirror output to live plan output over every reference tiling, so a
 # drifted mirror fails loudly rather than silently mis-budgeting.
 
-def mirror_plan_decode_block(*, max_seq: int, hidden: int, heads: int,
-                             kv_heads: int, head_dim: int, ffn: int,
-                             batch: int, itemsize: int,
-                             gated: bool = False,
-                             vmem_budget: int = DEFAULT_VMEM_BUDGET):
-    """Mirror of ``kernels.decode_block.plan_decode_block`` (tp=1)."""
-    rep = heads // kv_heads
-    dh = head_dim
-    attn_fixed = (hidden * (rep + 2) * dh * itemsize
-                  + hidden * itemsize
-                  + 2 * hidden * 4
-                  + 2 * rep * 128 * 4
-                  + rep * dh * 4 + 2 * dh * 4
-                  + 2 * dh * dh * 4)
-    bk = min(1024, max_seq)
-    while max_seq % bk:
-        bk //= 2
-    while bk > 8 and attn_fixed + 2 * 2 * bk * dh * itemsize > vmem_budget:
-        bk //= 2
-    if attn_fixed + 2 * 2 * bk * dh * itemsize > vmem_budget:
-        return None, (f"vmem: attention residents "
-                      f"{attn_fixed + 4 * bk * dh * itemsize} bytes exceed "
-                      f"budget {vmem_budget} even at block_k={bk}")
-    mlp_fixed = (heads * dh * hidden * itemsize
-                 + batch * (hidden + heads * dh) * itemsize
-                 + 3 * batch * hidden * 4
-                 + 4 * hidden * 4)
-    n_mats = 3 if gated else 2
-    cands = [f for f in range(128, ffn + 1, 128) if ffn % f == 0]
-    if not cands:
-        cands = [ffn]
-    bf = None
-    for c in sorted(cands, reverse=True):
-        if mlp_fixed + n_mats * 2 * hidden * c * itemsize <= vmem_budget:
-            bf = c
-            break
-    if bf is None:
-        need = mlp_fixed + n_mats * 2 * hidden * min(cands) * itemsize
-        return None, (f"vmem: proj+MLP residents {need} bytes exceed "
-                      f"budget {vmem_budget} even at block_f={min(cands)} "
-                      f"(out-projection [{heads * dh}, {hidden}] must stay "
-                      f"resident)")
-    return {"block_k": bk, "block_f": bf,
-            "vmem_attn": attn_fixed + 4 * bk * dh * itemsize,
-            "vmem_mlp": mlp_fixed + n_mats * 2 * hidden * bf * itemsize}, None
+# mirror of kernels/decode_block.py ATTN_CHUNK
+_ATTN_CHUNK = 16
 
 
 def _mirror_fit_tile(dim: int, per_unit: int, fixed: int, budget: int):
@@ -294,6 +251,74 @@ def _mirror_fit_tile(dim: int, per_unit: int, fixed: int, budget: int):
         if fixed + per_unit * t <= budget:
             return t
     return None
+
+
+def _mirror_slab_row_bytes(kv_heads: int, head_dim: int, itemsize: int):
+    lanes = -(-head_dim // 128) * 128
+    sub = 8 * (4 // itemsize)
+    return (-(-kv_heads // sub) * sub * lanes * itemsize,
+            -(-kv_heads // 8) * 8 * lanes * 4)
+
+
+def _mirror_plan_slab_attention(max_seq: int, kv_heads: int, rep: int,
+                                head_dim: int, itemsize: int,
+                                vmem_budget: int):
+    row, row32 = _mirror_slab_row_bytes(kv_heads, head_dim, itemsize)
+    fixed = (4 * min(_ATTN_CHUNK, max_seq) * row32
+             + (2 * rep + 4) * row32 + 2 * row
+             + 3 * head_dim * max(head_dim, 128) * 4)
+    bk = min(1024, max_seq)
+    while max_seq % bk:
+        bk //= 2
+    while bk > 8 and fixed + 4 * bk * row > vmem_budget:
+        bk //= 2
+    need = fixed + 4 * bk * row
+    return (bk if need <= vmem_budget else None), need
+
+
+def mirror_plan_decode_block(*, max_seq: int, hidden: int, heads: int,
+                             kv_heads: int, head_dim: int, ffn: int,
+                             batch: int, itemsize: int,
+                             gated: bool = False,
+                             vmem_budget: int = DEFAULT_VMEM_BUDGET):
+    """Mirror of ``kernels.decode_block.plan_decode_block`` (tp=1)."""
+    rep = heads // kv_heads
+    dh = head_dim
+    bk, vmem_attn = _mirror_plan_slab_attention(
+        max_seq, kv_heads, rep, dh, itemsize, vmem_budget)
+    if bk is None:
+        return None, (f"vmem: attention residents {vmem_attn} bytes exceed "
+                      f"budget {vmem_budget} even at block_k=8")
+    proj_fixed = batch * hidden * 2 * itemsize + 2 * hidden * 4
+    proj_unit = 2 * (hidden * itemsize + itemsize + batch * 4)
+    bn = _mirror_fit_tile(kv_heads * dh, proj_unit, proj_fixed,
+                          vmem_budget)
+    if bn is None:
+        return None, (f"vmem: projection residents {proj_fixed} bytes + "
+                      f"weight tiles exceed budget {vmem_budget} at any "
+                      f"tile of the K/V width {kv_heads * dh}")
+    mlp_fixed = (batch * hidden * 2 * itemsize
+                 + batch * hidden * (8 + itemsize)
+                 + 4 * hidden * 4)
+    o_unit = 2 * (hidden + batch) * itemsize
+    n_mats = 3 if gated else 2
+    f_unit = 2 * (n_mats * hidden + 1) * itemsize
+    bo = bf = None
+    cands = [f for f in range(128, ffn + 1, 128) if ffn % f == 0] or [ffn]
+    for c in sorted(cands, reverse=True):
+        bo = _mirror_fit_tile(heads * dh, o_unit, mlp_fixed + f_unit * c,
+                              vmem_budget)
+        if bo is not None:
+            bf = c
+            break
+    if bf is None:
+        need = mlp_fixed + f_unit * min(cands) + o_unit
+        return None, (f"vmem: proj+MLP residents {need} bytes exceed "
+                      f"budget {vmem_budget} even at block_f={min(cands)}")
+    return {"block_k": bk, "block_n": bn, "block_o": bo, "block_f": bf,
+            "vmem_attn": vmem_attn,
+            "vmem_proj": proj_fixed + proj_unit * bn,
+            "vmem_mlp": mlp_fixed + o_unit * bo + f_unit * bf}, None
 
 
 def mirror_plan_decode_block_tp(*, max_seq: int, hidden: int, heads: int,
@@ -310,19 +335,11 @@ def mirror_plan_decode_block_tp(*, max_seq: int, hidden: int, heads: int,
     b_l = batch // tp
     qkv_l = (h_l + 2 * kh_l) * dh
     up_l = f_l * (2 if gated else 1)
-    attn_fixed = ((rep + 2) * dh * itemsize
-                  + 2 * rep * 128 * 4
-                  + rep * dh * 4 + 2 * dh * 4
-                  + 2 * dh * dh * 4)
-    bk = min(1024, max_seq)
-    while max_seq % bk:
-        bk //= 2
-    while bk > 8 and attn_fixed + 4 * bk * dh * itemsize > vmem_budget:
-        bk //= 2
-    if attn_fixed + 4 * bk * dh * itemsize > vmem_budget:
-        return None, (f"vmem: tp attention residents "
-                      f"{attn_fixed + 4 * bk * dh * itemsize} bytes "
-                      f"exceed budget {vmem_budget} even at block_k={bk}")
+    bk, vmem_attn = _mirror_plan_slab_attention(
+        max_seq, kh_l, rep, dh, itemsize, vmem_budget)
+    if bk is None:
+        return None, (f"vmem: tp attention residents {vmem_attn} bytes "
+                      f"exceed budget {vmem_budget} even at block_k=8")
     entry_fixed = b_l * hidden * (itemsize + 4)
     entry_unit = 2 * (hidden + b_l + 1) * itemsize
     block_qkv = _mirror_fit_tile(qkv_l, entry_unit, entry_fixed,
@@ -354,7 +371,7 @@ def mirror_plan_decode_block_tp(*, max_seq: int, hidden: int, heads: int,
                       f"per-device MLP-down rows {f_l}")
     return {"block_k": bk, "block_qkv": block_qkv, "block_up": block_up,
             "block_o": block_o, "block_down": block_down,
-            "vmem_attn": attn_fixed + 4 * bk * dh * itemsize,
+            "vmem_attn": vmem_attn,
             "vmem_entry": entry_fixed
             + entry_unit * max(block_qkv, block_up),
             "vmem_exit": exit_fixed

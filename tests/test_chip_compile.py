@@ -1,0 +1,217 @@
+"""Every ``pallas_call`` under paddle_tpu/kernels/ is compiled by Mosaic.
+
+The suite runs on the CPU, where the kernels default to ``interpret=True``
+and no TPU compiler ever sees them: a kernel Mosaic refuses passes every
+other test (the fused decode block did, from PR 7 to PR 20).  libtpu ships
+a compile-only client, so this file AOT-compiles each kernel with
+``interpret=False`` against a detached v5e topology, at the widths
+``chip_smoke.py`` runs on the chip (GPT-3 6.7B: h4096, 32x128 heads, ffn
+16384, s2048, bf16; 8 slots).  Compiling is all it can do: numerics are
+the interpret-mode tests' and the chip run's.
+
+A missing topology is a failure, not a skip: without it this gate is
+silently off.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+B, S, H, DH = 8, 2048, 32, 128          # slots, max_seq, heads, head_dim
+D, FFN = H * DH, 4 * H * DH
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+
+
+def _compile(fn, *specs, **jit_kw):
+    """Trace, lower for TPU and run XLA:TPU + Mosaic; returns the
+    executable's memory analysis."""
+    compiled = jax.jit(fn, **jit_kw).trace(*specs).lower(
+        lowering_platforms=("tpu",)).compile()
+    return compiled.memory_analysis()
+
+
+def _one(topo):
+    sharding = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype=BF16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding)
+
+
+def test_topology_is_v5e(topo):
+    assert len(topo.devices) == 4
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+
+
+@pytest.mark.parametrize("varlen", [False, True])
+def test_flash_attention_fwd_bwd(topo, varlen):
+    """Forward, dQ and dK/dV kernels at the training shape (the
+    segment-masked variants behind flash_attn_unpadded too)."""
+    from paddle_tpu.kernels.flash_attention import (flash_attention,
+                                                    flash_attention_varlen)
+    s = _one(topo)
+    qkv = [s((1, S, H, DH))] * 3
+
+    if varlen:
+        def loss(q, k, v, seg):
+            return flash_attention_varlen(
+                q, k, v, seg, seg, causal=True,
+                interpret=False).astype(jnp.float32).sum()
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv,
+                 s((1, S), jnp.int32))
+    else:
+        def loss(q, k, v):
+            return flash_attention(
+                q, k, v, causal=True,
+                interpret=False).astype(jnp.float32).sum()
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+
+
+@pytest.mark.parametrize("sq", [1, 64])
+def test_decode_attention(topo, sq):
+    """One decode token, and a 64-token prefill chunk, against the slot
+    slabs."""
+    from paddle_tpu.kernels.decode_attention import decode_attention
+    s = _one(topo)
+    b = B if sq == 1 else 1
+    _compile(functools.partial(decode_attention, interpret=False),
+             s((b, sq, H, DH)), s((b, S, H, DH)), s((b, S, H, DH)),
+             s((b,), jnp.int32))
+
+
+@pytest.mark.parametrize("kind", ["rms", "layer"])
+def test_fused_norm_fwd_bwd(topo, kind):
+    from paddle_tpu.kernels.fused_norm import (fused_layer_norm_pallas,
+                                               fused_rms_norm_pallas)
+    s = _one(topo)
+    if kind == "rms":
+        def loss(x, w):
+            return fused_rms_norm_pallas(
+                x, w, interpret=False).astype(jnp.float32).sum()
+        _compile(jax.grad(loss, argnums=(0, 1)), s((8192, D)), s((D,)))
+    else:
+        def loss(x, w, b):
+            return fused_layer_norm_pallas(
+                x, w, b, interpret=False).astype(jnp.float32).sum()
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), s((8192, D)),
+                 s((D,)), s((D,)))
+
+
+def test_fused_adamw(topo):
+    """One out-projection's worth of parameters, standalone (the
+    tighter scoped-VMEM context, SKILL.md r4)."""
+    from paddle_tpu.kernels.fused_adamw import fused_adamw_update
+    s = _one(topo)
+    f32 = jnp.float32
+    _compile(functools.partial(fused_adamw_update, interpret=False),
+             s((D, D)), s((D, D)), s((D, D), f32), s((D, D), f32),
+             s((), jnp.int32), s((), f32))
+
+
+def _layer_specs(s, heads, kv_heads, gated):
+    rows = dict(norm1_w=(D,), norm1_b=(D,), wq=(D, heads * DH),
+                wk=(D, kv_heads * DH), wv=(D, kv_heads * DH),
+                bq=(heads * DH,), bkv=(kv_heads * DH,),
+                bv=(kv_heads * DH,), wo=(heads * DH, D), bo=(D,),
+                norm2_w=(D,), norm2_b=(D,), w1=(D, FFN), b1=(FFN,),
+                w2=(FFN, D), b2=(D,))
+    if gated:
+        rows["w_gate"] = (D, FFN)
+    return {k: s(v) for k, v in rows.items()}
+
+
+@pytest.mark.parametrize("wiring", ["gpt", "gqa_rope_swiglu"])
+def test_decode_block_layer(topo, wiring):
+    """The fused decode layer (norm+projection, slab attention,
+    out-projection+MLP kernels) at the plan's own tiles, slabs donated:
+    the in-kernel append must alias them in place (no temp copy)."""
+    from paddle_tpu.kernels.decode_block import decode_block_layer
+    s = _one(topo)
+    gqa = wiring != "gpt"
+    kv_heads = 8 if gqa else H
+    weights = _layer_specs(s, H, kv_heads, gated=gqa)
+    rope = {"rope_cos": s((B, DH), jnp.float32),
+            "rope_sin": s((B, DH), jnp.float32)} if gqa else {}
+
+    def layer(x, k, v, pos, weights, rope):
+        return decode_block_layer(
+            x, k, v, pos, kv_heads=kv_heads, head_dim=DH,
+            norm="rms" if gqa else "layer", eps1=1e-5, eps2=1e-5,
+            act="gelu_tanh", interpret=False, **weights, **rope)
+
+    slab = s((B, S, kv_heads, DH))
+    mem = _compile(layer, s((B, 1, D)), slab, slab, s((B,), jnp.int32),
+                   weights, rope, donate_argnums=(1, 2))
+    slab_bytes = B * S * kv_heads * DH * 2
+    assert mem.temp_size_in_bytes < slab_bytes, (
+        f"temp {mem.temp_size_in_bytes} B holds a slab copy "
+        f"({slab_bytes} B): the KV append no longer aliases in place")
+
+
+def test_decode_block_tp_layer(topo):
+    """The sharded fused layer over the four-chip topology: entry and
+    exit ring kernels, the per-shard slab attention, and the ppermute
+    hops between them, in ONE shard_map program."""
+    from paddle_tpu.kernels.decode_block import plan_decode_block
+    from paddle_tpu.kernels.decode_block_tp import tp_fused_block_layer
+    tp = 4
+    mesh = Mesh(np.array(topo.devices[:tp]), ("mp",))
+
+    def ns(shape, spec, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    plan, why = plan_decode_block(
+        max_seq=S, hidden=D, heads=H, kv_heads=H, head_dim=DH, ffn=FFN,
+        batch=B, itemsize=2, tp=tp)
+    assert plan is not None, why
+    arch = {"norm": "layer", "eps": 1e-5, "act": "gelu_tanh", "heads": H,
+            "kv_heads": H, "head_dim": DH}
+    shapes = dict(n1w=(D,), n1b=(D,), wqkv=(D, 3 * D), bqkv=(3 * D,),
+                  wo=(D, D), bo=(D,), n2w=(D,), n2b=(D,), wup=(D, FFN),
+                  bup=(FFN,), wdown=(FFN, D), bdown=(D,))
+    specs = {k: P() for k in shapes}
+    specs.update(wqkv=P(None, "mp"), bqkv=P("mp"), wo=P("mp", None),
+                 wup=P(None, "mp"), bup=P("mp"), wdown=P("mp", None))
+    slab = P(None, None, "mp", None)
+
+    def body(x_s, pk, pv, pos, blk):
+        return tp_fused_block_layer(x_s, pk, pv, pos, blk, arch, None,
+                                    "mp", tp, plan, interpret=False)
+
+    fn = jax.shard_map(
+        body, mesh=mesh, in_specs=(P("mp", None), slab, slab, P(), specs),
+        out_specs=(P("mp", None), slab, slab), check_vma=False)
+    _compile(fn, ns((B, D), P("mp", None)), ns((B, S, H, DH), slab),
+             ns((B, S, H, DH), slab), ns((B,), P(), jnp.int32),
+             {k: ns(shapes[k], specs[k]) for k in shapes},
+             donate_argnums=(1, 2))
+
+
+def test_every_kernel_module_is_covered():
+    """A new ``pallas_call`` site must join this file: the modules that
+    hold one are exactly the ones compiled above."""
+    import os
+    import re
+    import paddle_tpu.kernels as kernels
+    root = os.path.dirname(kernels.__file__)
+    holders = set()
+    for name in sorted(os.listdir(root)):
+        if name.endswith(".py"):
+            with open(os.path.join(root, name)) as fh:
+                if re.search(r"\bpl\.pallas_call\(", fh.read()):
+                    holders.add(name)
+    assert holders == {"flash_attention.py", "decode_attention.py",
+                       "fused_norm.py", "fused_adamw.py",
+                       "decode_block.py", "decode_block_tp.py"}, holders

@@ -8,7 +8,6 @@ matched arithmetic where possible)."""
 import numpy as np
 import pytest
 
-from conftest import requires_modern_jax
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +29,6 @@ def _build(shard):
     return model
 
 
-@requires_modern_jax
 def test_generate_on_mp_sharded_model_matches_dense():
     ids = jnp.asarray(np.random.RandomState(0).randint(0, 256, (4, 6)))
     dense = _build(False)
@@ -52,7 +50,6 @@ def test_generate_on_mp_sharded_model_matches_dense():
             assert best - chosen < 1e-3, (bi, t, best - chosen)
 
 
-@requires_modern_jax
 def test_beam_search_on_mp_sharded_model():
     ids = jnp.asarray(np.random.RandomState(1).randint(0, 256, (2, 5)))
     dense = _build(False)

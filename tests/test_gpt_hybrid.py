@@ -7,7 +7,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import requires_modern_jax
 
 import paddle_tpu
 import paddle_tpu.distributed as dist
@@ -83,7 +82,6 @@ def test_remat_actually_applied_and_policy_parity():
             remat_wrap(lambda x: x, bad)(jnp.ones(()))
 
 
-@requires_modern_jax
 def test_pipeline_loss_matches_serial():
     """Same init (fixed seed) run dp1/mp1/pp1 vs dp2/mp2/pp2: losses equal."""
     tr1 = _mk_trainer({"dp_degree": 1, "mp_degree": 1, "pp_degree": 1},
@@ -267,7 +265,6 @@ def test_vpp_trainer_matches_serial():
     np.testing.assert_allclose(float(l1b), float(l2b), rtol=2e-3)
 
 
-@requires_modern_jax
 def test_vpp_trainer_with_mp_matches_serial():
     """VPP composed with tensor parallel: pp2 x vpp2 x mp2 == serial
     (settles that partial-manual shard_map keeps mp shardings intact on
@@ -309,7 +306,6 @@ def test_vpp_trainer_with_mp_matches_serial():
     np.testing.assert_allclose(float(l1b), float(l2b), rtol=2e-3)
 
 
-@requires_modern_jax
 def test_vpp_with_zero3_trains_and_shards():
     """VPP interleaving composed with ZeRO-3 param sharding: trains, and
     the two-level stacked block leaves are actually sharded."""
@@ -339,7 +335,6 @@ def test_vpp_with_zero3_trains_and_shards():
     assert float(loss) < l0
 
 
-@requires_modern_jax
 def test_vocab_table_not_replicated_across_pp():
     """Stage assignment of embedding + tied head, SPMD-style (reference
     SharedLayerDesc, SURVEY §2.3 PP row): with pp>1 the wte table's rows are
@@ -459,7 +454,6 @@ def test_bf16_hybrid_state_layout():
             assert v.dtype == jnp.float32
 
 
-@requires_modern_jax
 def test_bf16_hybrid_pipeline_compiles_and_learns():
     """bf16 + pp>1 regression (round 5): shardy's HLO round-trip emits
     copy-rooted BF16 psum combiners that CHECK-crash XLA ("Invalid

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import requires_modern_jax
 
 import paddle_tpu
 import paddle_tpu.nn as nn
@@ -156,7 +155,6 @@ def test_static_save_load_inference_model(tmp_path):
                                rtol=1e-6, atol=1e-6)
 
 
-@requires_modern_jax
 def test_save_load_multi_device_program(tmp_path):
     """AOT export of the FULL hybrid-parallel train step (dp2 x mp2 x pp2
     over 8 devices): serialize, reload, execute — bit-equal loss.  The

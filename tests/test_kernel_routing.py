@@ -117,13 +117,47 @@ def test_fused_adamw_large_tensor_block_cap():
 
 
 def test_norm_block_picker_vmem_cap():
-    """h=8192 must pick a block with block*h*4B <= 4MiB (the r4 sweep's
-    scoped-vmem failure mode) instead of an illegal large block."""
+    """The block's f32 working copy stays within 2 MiB: at 4 MiB (256
+    rows of h=4096) the BACKWARD kernel's scoped VMEM is 22 MiB against
+    Mosaic's 16 MiB limit and does not compile
+    (tests/test_chip_compile.py compiles both at this shape)."""
     from paddle_tpu.kernels.fused_norm import _flatten_and_pick_block
     x = jnp.zeros((4096, 8192), jnp.bfloat16)
     _, block = _flatten_and_pick_block(x)
     assert block > 0
-    assert block * 8192 * 4 <= 4 * 1024 * 1024
+    assert block * 8192 * 4 <= 2 * 1024 * 1024
     x2 = jnp.zeros((8192, 4096), jnp.bfloat16)
     _, block2 = _flatten_and_pick_block(x2)
-    assert block2 == 256          # unchanged for the standard shape
+    assert block2 == 128
+
+
+def test_route_refuses_where_xla_would_partition_the_kernel():
+    """XLA cannot partition a Mosaic custom call: under a mesh axis
+    larger than one that is not manual, every route answers no — with
+    the compiler's words — and inside a shard_map over that axis it
+    answers from the table again."""
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+    from paddle_tpu.kernels.routing import partition_refusal, use_pallas
+    assert partition_refusal() is None
+    assert use_pallas("flash_attention", seq_q=4096, seq_k=4096)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("mp",))
+    seen = {}
+
+    def body(x):
+        seen["manual"] = (partition_refusal(),
+                          use_pallas("flash_attention", seq_q=4096,
+                                     seq_k=4096))
+        return x
+
+    with jax.set_mesh(mesh):
+        why = partition_refusal()
+        assert "['mp']" in why and "cannot be automatically partitioned" in why
+        assert not use_pallas("flash_attention", seq_q=4096, seq_k=4096)
+        assert not use_pallas("decode_attention", kv_len=2048)
+        jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("mp"),
+                              out_specs=P("mp")))(jnp.zeros(4))
+    assert seen["manual"] == (None, True)
+    # a one-device mesh partitions nothing
+    with jax.set_mesh(Mesh(np.array(jax.devices()[:1]), ("mp",))):
+        assert partition_refusal() is None

@@ -4,7 +4,6 @@ the references, same pattern as the reference's test_*_op.py suites)."""
 import numpy as np
 import pytest
 
-from conftest import requires_modern_jax
 import torch
 
 import jax
@@ -352,7 +351,6 @@ def test_hsigmoid_layer_forward():
     assert np.isfinite(np.asarray(out)).all()
 
 
-@requires_modern_jax
 def test_beam_search_decoder_beats_greedy():
     """beam_size=1 == greedy argmax decode; larger beams score >= greedy."""
     P.seed(0)

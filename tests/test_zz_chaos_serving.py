@@ -328,10 +328,11 @@ def test_tp_quarantine_rebuilds_sharded_plane():
     core = eng.core
     # the REBUILT plane is still tensor-parallel: slabs sharded on the
     # kv-head axis over the serving mesh, block slab included
-    assert tuple(core.pool.ks[0].sharding.spec) == \
-        (None, None, "mp", None)
-    assert tuple(core.block_pool.bks[0].sharding.spec) == \
-        (None, None, "mp", None)
+    # (a spec may drop trailing unsharded dims; compare the sharded ones)
+    assert tuple(core.pool.ks[0].sharding.spec)[:3] == \
+        (None, None, "mp")
+    assert tuple(core.block_pool.bks[0].sharding.spec)[:3] == \
+        (None, None, "mp")
     assert core.trace_counts["decode"] == 2   # ONE per device plane
     assert eng.decode_path == "tp_fused"
 
@@ -387,8 +388,8 @@ def test_tp_fused_block_quarantine_rebuild():
     assert eng.health.state == "healthy"
     core = eng.core
     assert eng.decode_path == "tp_fused_block"
-    assert tuple(core.pool.ks[0].sharding.spec) == \
-        (None, None, "mp", None)
+    assert tuple(core.pool.ks[0].sharding.spec)[:3] == \
+        (None, None, "mp")
     assert core.trace_counts["decode"] == 2   # ONE per device plane
 
 
