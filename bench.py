@@ -854,7 +854,7 @@ def _serving_tp_bench(smoke=False):
     degree the visible devices allow, driven by the mixed-arrival
     workload (warmup run first, measured run on the warmed programs).
     Per degree: decode tok/s, scaling efficiency (tok/s vs tp=1,
-    normalized per chip), TTFT p50/p99, the serving.collective_s p50,
+    normalized per chip), TTFT p50/p99, the decode phases' p50 (dispatch + readback),
     and TOKEN PARITY against the tp=1 engine — the correctness bar the
     scaling story stands on.  A primitive-level overlapped-vs-serialized
     compare rides along: same shard_map, ring-fused vs
@@ -923,7 +923,9 @@ def _serving_tp_bench(smoke=False):
         else:
             eff = round(tps / (base_tps * tp), 3) \
                 if (tps and base_tps) else None
-        coll = eng.registry.snapshot().get("serving.collective_s", {})
+        snap = eng.registry.snapshot()
+        phase_p50 = [snap.get(f"serving.phase.{p}_s", {}).get("p50")
+                     for p in ("decode_dispatch", "readback")]
         rows.append({
             "tp": tp,
             "decode_path": eng.decode_path,
@@ -931,8 +933,8 @@ def _serving_tp_bench(smoke=False):
             "scaling_efficiency": eff,
             "ttft_p50_ms": md["ttft_p50_ms"],
             "ttft_p99_ms": md["ttft_p99_ms"],
-            "collective_p50_ms": (round(coll["p50"] * 1e3, 3)
-                                  if coll.get("p50") else None),
+            "decode_phases_p50_ms": (round(sum(phase_p50) * 1e3, 3)
+                                     if all(phase_p50) else None),
             "comm_note": _comm_seam_note(tp),
             "parity_vs_tp1": parity})
     out = {

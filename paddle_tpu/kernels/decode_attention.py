@@ -150,6 +150,7 @@ def decode_attention(q, k_cache, v_cache, seq_lens,
             pltpu.VMEM((sq, d), jnp.float32),
         ],
         compiler_params=compiler_params,
+        name="decode_attention",
         interpret=interpret,
     )(lens3, to3(q), to3(k_cache), to3(v_cache))
     return jnp.moveaxis(out3.reshape(b, h, sq, d), 1, 2)

@@ -461,6 +461,7 @@ def _norm_proj(x2, nw, nb, w, bias, *, norm, eps, block_n, interpret):
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((b, d), w.dtype)],
         compiler_params=_grid_params(interpret),
+        name="decode_block_norm_proj",
         interpret=interpret,
     )(x2, nw, nb, w, bias2)
 
@@ -702,6 +703,7 @@ def slab_decode_attention(q, k_new, v_new, k_slab, v_slab, seq_pos, *,
         # operand indices count the scalar-prefetch argument
         input_output_aliases={7: 1, 8: 2},
         compiler_params=_grid_params(interpret),
+        name="slab_decode_attention",
         interpret=interpret,
     )(pos1, qr, k_new, v_new, cosf, sinf, rot, k_slab, v_slab)
     return jnp.swapaxes(attn, 1, 2), k2, v2
@@ -882,6 +884,7 @@ def decode_block_mlp(x, attn, wo, bo, norm_w, norm_b, w1, b1, w2, b2,
             pltpu.VMEM((b, d), jnp.float32),
         ],
         compiler_params=_grid_params(interpret),
+        name="decode_block_mlp",
         interpret=interpret,
     )(x[:, 0], attn[:, 0].astype(wo.dtype), wo, row(bo, d), n2w, n2b,
       w1, row(b1, ffn), wg, w2, row(b2, d))

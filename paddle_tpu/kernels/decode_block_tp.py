@@ -207,6 +207,7 @@ def ring_entry_matmul(h, w_l, bias_l, axis_name: str, tp: int, *,
         out_specs=pl.BlockSpec((b_loc, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((b_loc, n_l), h.dtype),
         compiler_params=_grid_params(interpret),
+        name="ring_entry_matmul",
         interpret=interpret,
     )
     if tp == 1:
@@ -301,6 +302,7 @@ def ring_exit_matmul(y, w_l, axis_name: str, tp: int, *,
         out_shape=jax.ShapeDtypeStruct((b_l, n), y.dtype),
         scratch_shapes=[pltpu.VMEM((b_l, n), jnp.float32)],
         compiler_params=_grid_params(interpret),
+        name="ring_exit_matmul",
         interpret=interpret,
     )
 

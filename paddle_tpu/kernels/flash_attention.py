@@ -187,6 +187,7 @@ def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         compiler_params=_dimension_semantics(3, interpret),
+        name="flash_attention_fwd",
         interpret=interpret,
     )(*args)
     return out, lse[..., 0]
@@ -352,6 +353,7 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_dimension_semantics(3, interpret),
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(*dq_args)
 
@@ -389,6 +391,7 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         compiler_params=_dimension_semantics(3, interpret),
+        name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(*dkv_args)
     return dq, dk, dv

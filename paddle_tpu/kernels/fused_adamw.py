@@ -123,6 +123,7 @@ def fused_adamw_update(p, g, m, v, step, lr, beta1=0.9, beta2=0.999,
             jax.ShapeDtypeStruct((rows, lane), jnp.float32),
         ],
         input_output_aliases=aliases,
+        name="fused_adamw_update",
         interpret=interpret,
     )(scalars, p2, g2, m2, v2)
 

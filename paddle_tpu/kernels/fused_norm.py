@@ -63,6 +63,7 @@ def _run_fwd(x2, w, eps, block_rows, interpret):
                    pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows, h), x2.dtype),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
+        name="fused_rms_norm_fwd",
         interpret=interpret,
     )(x2, w)
 
@@ -93,6 +94,7 @@ def _rms_core_bwd(eps, block_rows, interpret, res, g):
                    pl.BlockSpec((1, h), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows, h), x2.dtype),
                    jax.ShapeDtypeStruct((1, h), jnp.float32)],
+        name="fused_rms_norm_bwd",
         interpret=interpret,
     )(x2, w, rstd, g)
     return dx, dw[0].astype(w.dtype)
@@ -220,6 +222,7 @@ def _ln_run_fwd(x2, w, b, eps, block_rows, interpret):
         out_shape=[jax.ShapeDtypeStruct((rows, h), x2.dtype),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
+        name="fused_layer_norm_fwd",
         interpret=interpret,
     )(x2, w, b)
 
@@ -253,6 +256,7 @@ def _ln_core_bwd(eps, block_rows, interpret, res, g):
         out_shape=[jax.ShapeDtypeStruct((rows, h), x2.dtype),
                    jax.ShapeDtypeStruct((1, h), jnp.float32),
                    jax.ShapeDtypeStruct((1, h), jnp.float32)],
+        name="fused_layer_norm_bwd",
         interpret=interpret,
     )(x2, w, mu, rstd, g)
     return dx, dw[0].astype(w.dtype), db[0].astype(b.dtype)

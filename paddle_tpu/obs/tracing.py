@@ -25,6 +25,14 @@ into ``profiler.export_chrome_tracing`` traces with request lanes
 rendered alongside host ``RecordEvent`` phases and device activity
 (register via :meth:`install_profiler_source`).
 
+A tracer built with ``annotate`` (a factory ``name -> context manager``;
+the serving engine passes ``jax.profiler.TraceAnnotation``) ALSO enters
+that context in ``begin_span`` and leaves it in ``end_span``: one call
+writes the ring's span and a host span of the same name in the
+profiler's own trace, on the device planes' clock.  Live spans must
+therefore nest (a child ends before its parent).  ``add_span`` records
+are never annotated — their interval is already over.
+
 Memory is bounded: spans and events live in fixed-size rings (oldest
 evicted first) and lane labels in a capped map — a month-long serving
 run holds the same telemetry footprint as a ten-second one.  Pure host
@@ -52,7 +60,7 @@ _LANE_BLOCK = 1 << 20
 class Span:
     """One named interval on a lane; ``attrs`` is small, JSON-able."""
 
-    __slots__ = ("name", "lane", "start", "end", "attrs")
+    __slots__ = ("name", "lane", "start", "end", "attrs", "_annotation")
 
     def __init__(self, name: str, lane: int, start: float,
                  end: float = 0.0, attrs: Optional[dict] = None):
@@ -61,6 +69,7 @@ class Span:
         self.start = start
         self.end = end
         self.attrs = attrs if attrs is not None else {}
+        self._annotation = None     # the entered ``annotate`` context
 
     @property
     def duration(self) -> float:
@@ -79,8 +88,12 @@ class Tracer:
     LANE_BLOCK = _LANE_BLOCK
 
     def __init__(self, max_spans: int = 4096, max_events: int = 1024,
-                 enabled: bool = True):
+                 enabled: bool = True, annotate=None):
         self.enabled = enabled
+        # name -> context manager entered/left around every LIVE span
+        # (None: the ring alone).  The obs layer never imports jax: the
+        # owner hands in ``jax.profiler.TraceAnnotation`` or a recorder.
+        self.annotate = annotate
         self._spans: deque = deque(maxlen=max_spans)
         self._events: deque = deque(maxlen=max_events)
         self._lane_names: "OrderedDict[int, str]" = OrderedDict()
@@ -108,18 +121,31 @@ class Tracer:
 
     # ------------------------------------------------------------- spans
     def begin_span(self, name: str, lane: int = 0,
-                   **attrs) -> Optional[Span]:
+                   t: Optional[float] = None, **attrs) -> Optional[Span]:
         """Open a live span; returns None while disabled (``end_span``
-        accepts None, so callers need no enabled-guard of their own)."""
+        accepts None, so callers need no enabled-guard of their own).
+        ``t`` is a ``perf_counter`` reading the caller just took (one
+        reading can close a span and open the next, so the two tile);
+        None reads the clock here."""
         if not self.enabled:
             return None
-        return Span(name, lane, time.perf_counter(), 0.0, attrs or None)
+        span = Span(name, lane, 0.0, 0.0, attrs or None)
+        if self.annotate is not None:
+            span._annotation = self.annotate(name)
+            span._annotation.__enter__()
+        span.start = time.perf_counter() if t is None else t
+        return span
 
-    def end_span(self, span: Optional[Span]) -> None:
-        """Close + record a span from :meth:`begin_span` (None = no-op)."""
+    def end_span(self, span: Optional[Span],
+                 t: Optional[float] = None) -> None:
+        """Close + record a span from :meth:`begin_span` (None = no-op);
+        ``t`` as in :meth:`begin_span`."""
         if span is None:
             return
-        span.end = time.perf_counter()
+        span.end = time.perf_counter() if t is None else t
+        if span._annotation is not None:
+            span._annotation.__exit__(None, None, None)
+            span._annotation = None
         self._spans.append(span)
 
     def add_span(self, name: str, lane: int, start: float, end: float,

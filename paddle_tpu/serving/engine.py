@@ -880,13 +880,16 @@ class EngineCore:
             if tracer.enabled:
                 lane = self._lane(req)
                 tracer.set_lane_name(lane, f"request {req.request_id}")
+                rid = req.request_id
                 tracer.add_span("queued", lane, req.arrival_time, t_admit,
-                                prompt_len=req.prompt_len)
+                                prompt_len=req.prompt_len, request=rid,
+                                step=self._step_in_flight)
                 if self._cache_active:
                     tracer.add_span("prefix_match", lane, t_match0,
-                                    t_match1, hit_tokens=matched)
+                                    t_match1, hit_tokens=matched,
+                                    request=rid)
                 tracer.add_span("gather", lane, t_gather0, t_gather1,
-                                hit=bool(matched))
+                                hit=bool(matched), request=rid)
             self._prefills.append(_Prefill(req, slot, ks, vs, plan, match))
             self.progress_counter += 1          # admission = progress
         except BaseException:
@@ -939,9 +942,11 @@ class EngineCore:
         st.req.prefill_chunks += 1
         self.progress_counter += 1              # chunk ran = progress
         self.metrics.on_prefill_chunk(valid, seconds=t1 - t0)
+        self.metrics.step_count("prefill_tokens", valid)
         self.metrics.tracer.add_span(
             "prefill_chunk", self._lane(st.req), t0, t1,
-            chunk=st.next_chunk - 1, width=width, tokens=valid)
+            chunk=st.next_chunk - 1, width=width, tokens=valid,
+            request=st.req.request_id)
         if st.done:
             st.last_logits = last_logits
 
@@ -1054,6 +1059,9 @@ class EngineCore:
         the deferred prefix-cache insert, and the first-token emit."""
         if not staged:
             return 0
+        # a device wait, so a phase of its own: step.prefill is dispatch
+        self.metrics.phase("first_token_readback")
+        self.metrics.step_count("prefills_completed", len(staged))
         toks = np.asarray(jnp.concatenate([f for _, f in staged]))
         emitted = 0
         flush_exc = None
@@ -1464,14 +1472,17 @@ class EngineCore:
             return self._step_body()
 
     def _step_body(self) -> int:
-        """The raw step.  Telemetry rides the loop off the hot path: the
-        step's phase breakdown (admission / prefill / decode dispatch /
-        readback) lands as ``step.*`` spans on the engine lane +
-        per-phase histograms, and trace-counter deltas / head-of-line
+        """The raw step.  Every part of it runs inside a live
+        ``step.<phase>`` span (``metrics.STEP_PHASES``), a child of the
+        step's ``serving.step`` span on the engine lane; the children
+        tile the step, each feeds a ``serving.phase.<name>_s`` histogram,
+        and with ``record_events=True`` each is a profiler annotation
+        too.  The step span carries the step's counts
+        (``metrics.STEP_COUNTS``); trace-counter deltas / head-of-line
         skips / evictions become discrete events.  The per-slot token
         readback stays the step's ONLY device sync."""
         t0 = time.perf_counter()
-        tracer = self.metrics.tracer
+        metrics = self.metrics
         step_i = self._step_index
         self._step_index += 1
         self._step_in_flight = step_i
@@ -1481,18 +1492,12 @@ class EngineCore:
         if faults is not None:
             armed = faults.check("slow_step")
             if armed is not None:
-                self.metrics.on_fault(
+                metrics.on_fault(
                     "slow_step", f"injected {armed.seconds}s stall",
                     step=step_i)
                 time.sleep(armed.seconds)
-        ann = None
-        if self.metrics.record_events:
-            from ..profiler import RecordEvent
-            ann = RecordEvent("serving.step")
-            ann.begin()
-        sp = tracer.begin_span("serving.step",
-                               lane=self.metrics.engine_lane,
-                               step=step_i)
+        new_tokens = 0
+        spans = metrics.begin_step(step_i, "admission")
         try:
             if self._deadlines_possible:
                 self._expire_deadlines(time.perf_counter())
@@ -1511,21 +1516,30 @@ class EngineCore:
                     self.scheduler.requeue_front(
                         [r for r, _ in admitted[i:]])
                     raise
-            t_admit = time.perf_counter()
+            counts = spans.counts
+            counts["admitted"] = len(admitted)
+            counts["queue_depth"] = self.scheduler.queue_depth
+            # step.prefill is dispatch only: _flush_staged moves on to
+            # step.first_token_readback where a prefill completed
+            metrics.phase("prefill")
             new_tokens = self._advance_prefills()
-            t_prefill = time.perf_counter()
-            phases = [("admission", t0, t_admit),
-                      ("prefill", t_admit, t_prefill)]
             if self._slots:
-                if faults is not None:
-                    armed = faults.check("nan_logits")
-                    if armed is not None:
-                        self._poison_slot(min(self._slots), step_i)
+                counts["active_slots"] = len(self._slots)
+                counts["live_kv_rows"] = sum(
+                    st.pos for st in self._slots.values())
                 # speculative draft phase (pure host, spec_on only):
                 # None -> normal decode this step, else the batched
                 # fixed-shape verify program commits up to spec_k+1
                 # tokens per slot
-                spec = self._propose_drafts()
+                spec = None
+                if self.spec_on:
+                    metrics.phase("draft")
+                    spec = self._propose_drafts()
+                metrics.phase("decode_dispatch")
+                if faults is not None:
+                    armed = faults.check("nan_logits")
+                    if armed is not None:
+                        self._poison_slot(min(self._slots), step_i)
                 # decode faults cannot be pinned on one slot — the
                 # watchdog attributes them to the decode path (ladder
                 # candidate when fused or speculating, retry/quarantine
@@ -1548,9 +1562,9 @@ class EngineCore:
                     nxt = self._verify_dispatch(drafts, draft_len)
                 else:
                     nxt = self._decode_dispatch()
-                t_decode = time.perf_counter()
+                metrics.phase("readback")
                 toks = np.asarray(nxt)     # THE per-step device readback
-                t_readback = time.perf_counter()
+                metrics.phase("harvest")
                 self._fault_phase = None
                 # the readback already advanced EVERY slot's device
                 # state: a raise mid-loop (a user stream callback, an
@@ -1579,50 +1593,35 @@ class EngineCore:
                             new_tokens += self._harvest_window(
                                 slot, toks[slot, :a + 1])
                     except Exception as e:
-                        self.metrics.on_fault("harvest", repr(e),
-                                              step=step_i)
+                        metrics.on_fault("harvest", repr(e), step=step_i)
                         self._finalize(st.req, "failed",
                                        f"token emit failed: {e!r}")
                         if harvest_exc is None:
                             harvest_exc = e
                 if spec is not None:
-                    self.metrics.on_spec(int(drafted), accepted_total)
+                    metrics.on_spec(int(drafted), accepted_total)
                 if harvest_exc is not None and not self.fault_tolerant:
                     raise harvest_exc
-                # decode phases exist only on steps that decoded — a
-                # prefill-only step must not feed 0.0 into their
-                # histograms and fake slices into the timeline
-                phases += [("decode_dispatch", t_prefill, t_decode),
-                           ("readback", t_decode, t_readback)]
-                if self.decode_path in ("fused", "tp_fused_block"):
-                    # fused-path dispatch cost, separable from unfused
-                    # runs in the same registry (glossary:
-                    # kernel.decode_block_s, docs/observability.md)
-                    self.metrics.on_decode_block_step(t_decode - t_prefill)
-                if self.tensor_parallel > 1:
-                    # the TP decode's dispatch+readback carries its
-                    # fused entry/exit collectives — this histogram is
-                    # the trace evidence for the collective-fusion path
-                    # (glossary: serving.collective_s)
-                    self.metrics.on_collective(t_readback - t_prefill)
+            metrics.phase("bookkeeping")
             self._evict_finished()
             if self.journal is not None:
                 self._journal_progress()
         finally:
-            # a raised step must still close the span and the trace
-            # annotation, or every later event nests inside a phantom
-            # serving.step (resource-lifecycle rule: begin_span/end_span)
-            tracer.end_span(sp)
-            if ann is not None:
-                ann.end()
+            # a raised step must still close its open phase and then the
+            # step span (and their trace annotations, innermost first),
+            # or every later span nests inside a phantom serving.step
+            # (resource-lifecycle rule: begin_step/end_step)
+            spans.counts["new_tokens"] = new_tokens
+            metrics.end_step(spans)
+        # the telemetry's own accounting runs outside the step span, so
+        # that it does not time itself
         self._record_events(step_i, skips_before)
-        self.metrics.record_step(
+        metrics.record_step(
             active_slots=len(self._slots), num_slots=self.num_slots,
             queue_depth=self.scheduler.queue_depth,
             new_tokens=new_tokens,
             step_seconds=time.perf_counter() - t0,
-            step_index=step_i,
-            phases=phases)
+            phases=spans.phases)
         return self.scheduler.active + self.scheduler.queue_depth
 
     def _journal_progress(self) -> None:
@@ -1830,7 +1829,9 @@ class EngineCore:
                 tracer.add_span("prefill", lane,
                                 req.admit_time or req.arrival_time, now,
                                 chunks=req.prefill_chunks,
-                                hit_tokens=req.prefix_hit_tokens)
+                                hit_tokens=req.prefix_hit_tokens,
+                                request=req.request_id,
+                                step=self._step_in_flight)
                 tracer.event("first_token", lane=lane, t=now)
         elif req.last_token_time is not None:
             self.metrics.on_output_token(now - req.last_token_time)
@@ -1935,11 +1936,13 @@ class EngineCore:
         if not tracer.enabled:
             return
         lane = self._lane(req)
+        rid = req.request_id
         if req.first_token_time is not None:
             tracer.add_span("decode", lane, req.first_token_time, now,
-                            tokens=len(req.tokens))
+                            tokens=len(req.tokens), request=rid,
+                            step=self._step_in_flight)
         tracer.add_span("request", lane, req.arrival_time, now,
-                        tokens=len(req.tokens),
+                        tokens=len(req.tokens), request=rid,
                         finish_reason=req.finish_reason or req.status)
 
     def _release_slot(self, slot: int, now: float) -> Request:

@@ -9,10 +9,13 @@ p50/p99 TTFT and TPOT from the new log-bucketed histograms.
 
 Every update is a host-side op on values the engine already holds (no
 extra device syncs: the engine's single per-step token readback feeds
-everything — pinned by tests/test_observability.py).  With
-``record_events=True`` the engine additionally wraps each step in a
-``profiler.RecordEvent`` and the tracer's request lanes merge into
-``profiler.export_chrome_tracing`` output.
+everything — pinned by tests/test_observability.py).  Each step is a
+live ``serving.step`` span with one ``step.<phase>`` child open at a
+time (:meth:`ServingMetrics.begin_step` / ``phase`` / ``end_step``).
+With ``record_events=True`` the tracer also writes every live span as a
+``jax.profiler.TraceAnnotation`` — the same names on the device trace's
+clock — and its lanes merge into ``profiler.export_chrome_tracing``
+output.
 
 CLOCK BASE: all timestamps entering this class MUST be
 ``time.perf_counter()`` readings — ``Scheduler.submit`` stamps
@@ -31,7 +34,16 @@ from typing import Dict, Optional, Sequence, Tuple
 
 from ..obs import Histogram, MetricsRegistry, Tracer
 
-__all__ = ["ServingMetrics"]
+__all__ = ["ServingMetrics", "StepSpans", "STEP_PHASES", "STEP_COUNTS"]
+
+# the children of a ``serving.step`` span, in the order a step runs them
+# (docs/observability.md "Step timeline"); each is ``step.<name>`` on
+# the engine lane and a ``serving.phase.<name>_s`` histogram
+STEP_PHASES = ("admission", "prefill", "first_token_readback", "draft",
+               "decode_dispatch", "readback", "harvest", "bookkeeping")
+# host ints the engine already holds, set on the ``serving.step`` span
+STEP_COUNTS = ("admitted", "prefill_tokens", "prefills_completed",
+               "active_slots", "live_kv_rows", "new_tokens", "queue_depth")
 
 # admission-projection clamps: a degenerate measurement window (one
 # finish inside a denormal-small busy window, or a finish against an
@@ -44,15 +56,40 @@ MAX_RETRY_AFTER_S = 600.0
 MAX_PROJECTED_TTFT_S = 3600.0
 
 
+class StepSpans:
+    """One engine step's live spans: the ``serving.step`` span and the
+    ONE ``step.<phase>`` child open inside it.  Phases follow one
+    another and one clock reading closes a phase and opens the next, so
+    the children tile the step exactly.  ``counts`` lands on the step
+    span as attrs when it closes; ``phases`` is the ``(name, start,
+    end)`` list ``record_step`` feeds the phase histograms from (also
+    where the tracer is disabled and records no span)."""
+
+    __slots__ = ("index", "counts", "phases", "_root", "_open",
+                 "_open_name", "_open_start")
+
+    def __init__(self, index: int):
+        self.index = index
+        self.counts = dict.fromkeys(STEP_COUNTS, 0)
+        self.phases = []
+        self._root = self._open = self._open_name = None
+        self._open_start = 0.0
+
+
 class ServingMetrics:
     def __init__(self, record_events: bool = False,
                  registry: Optional[MetricsRegistry] = None,
                  tracer: Optional[Tracer] = None):
-        # record_events=True wraps each step in a profiler.RecordEvent
-        # AND merges the tracer's request lanes into chrome exports
+        # record_events=True writes every live span as a profiler
+        # TraceAnnotation too AND merges the tracer's lanes into chrome
+        # exports
         self.record_events = record_events
         self.registry = registry if registry is not None else MetricsRegistry()
         self.tracer = tracer if tracer is not None else Tracer()
+        if record_events and self.tracer.annotate is None:
+            import jax
+            self.tracer.annotate = jax.profiler.TraceAnnotation
+        self._step: Optional[StepSpans] = None
         # disjoint lane block per engine: the step timeline sits on
         # engine_lane, request r on engine_lane + 1 + r — two engines
         # sharing one tracer never collide on a lane
@@ -144,10 +181,6 @@ class ServingMetrics:
                                "submit -> admission", unit="s")
         self._h_gather = h("serving.gather_s",
                            "prefix block gather / staging init", unit="s")
-        self._h_decode_block = h("kernel.decode_block_s",
-                                 "fused decode-block step dispatch wall "
-                                 "time (engine fused_decode path)",
-                                 unit="s")
         self._g_queue_depth = g("serving.queue_depth",
                                 "waiting requests at the last step")
         self._g_occupancy = g("serving.slot_occupancy",
@@ -180,13 +213,10 @@ class ServingMetrics:
                                 "degradation ladder")
         # tensor-parallel serving surface (docs/serving.md
         # "Tensor-parallel serving"): the mesh degree this engine
-        # shards over, and the wall time of the collective-bearing
-        # decode dispatch+readback — on a TP mesh every decode step's
-        # latency includes its fused entry/exit collectives, so this
-        # histogram IS the trace evidence the collectives ride the
-        # step (compare its p50 against a tp=1 engine's
-        # serving.phase.decode_dispatch_s)
-        # the tp gauge binds OUTSIDE self._own: the degree is an
+        # shards over.  On a TP mesh every decode step's
+        # serving.phase.decode_dispatch_s + readback_s carries its
+        # fused entry/exit collectives — compare against a tp=1 engine's.
+        # The tp gauge binds OUTSIDE self._own: the degree is an
         # engine-lifetime constant published once at construction, and
         # the warmup->reset()->measure flow must not zero it into a
         # lying 0 on every later scrape (health_state survives reset by
@@ -194,10 +224,6 @@ class ServingMetrics:
         self._g_tp = reg.gauge("serving.tp_degree",
                                "tensor-parallel mesh degree "
                                "(1 = single chip)")
-        self._h_collective = h("serving.collective_s",
-                               "collective-bearing decode "
-                               "dispatch+readback wall time (recorded "
-                               "only on tp > 1 engines)", unit="s")
         # zero-cold-start surface (docs/serving.md "Zero cold start"):
         # warm-load accounting for the AOT program store.  The event
         # counters window-reset with the rest; the two gauges are
@@ -346,20 +372,8 @@ class ServingMetrics:
         self.tracer.event("aot_fallback", lane=self.engine_lane,
                           program=program, reason=reason)
 
-    def on_decode_block_step(self, seconds: float) -> None:
-        """One fused-path decode dispatch's wall time (the engine calls
-        this only on steps whose decode ran the fused kernel pair, so
-        the ``kernel.decode_block_s`` histogram is separable from the
-        unfused ``serving.phase.decode_dispatch_s`` in one registry)."""
-        self._h_decode_block.observe(seconds)
-
     def set_tp_degree(self, tp: int) -> None:
         self._g_tp.set(tp)
-
-    def on_collective(self, seconds: float) -> None:
-        """One TP decode step's collective-bearing dispatch+readback
-        time (the engine calls this only when ``tp > 1``)."""
-        self._h_collective.observe(seconds)
 
     def on_compile(self, program: str, n: int = 1) -> None:
         self._c_compiles.inc(n)
@@ -517,16 +531,65 @@ class ServingMetrics:
         base = self._h_ttft.quantile(0.50) or 0.0
         return min(queue_depth / rate + base, MAX_PROJECTED_TTFT_S)
 
+    # ---------------------------------------------------- the step's spans
+    def begin_step(self, index: int, phase: str) -> StepSpans:
+        """Open step ``index``'s ``serving.step`` span on the engine
+        lane and its first phase.  ``begin_step``/``end_step`` is a
+        registered graftlint ``ResourcePair``: the engine ends it in a
+        ``finally``, which closes the open phase first, so a step that
+        raises mid-phase leaves no span (or trace annotation) open and
+        the nesting holds."""
+        st = StepSpans(index)
+        now = time.perf_counter()
+        st._root = self.tracer.begin_span(
+            "serving.step", lane=self.engine_lane, t=now, step=index)
+        st._open = self.tracer.begin_span(
+            "step." + phase, lane=self.engine_lane, t=now, step=index)
+        st._open_name, st._open_start = phase, now
+        self._step = st
+        return st
+
+    def phase(self, name: str) -> None:
+        """The step in flight moves on to ``step.<name>``: the open
+        phase closes and the next opens, where the work happens (one
+        clock reading for both; a few of these run every step, so no
+        helper calls)."""
+        st = self._step
+        tracer = self.tracer
+        now = time.perf_counter()
+        tracer.end_span(st._open, t=now)
+        st.phases.append((st._open_name, st._open_start, now))
+        st._open = tracer.begin_span(
+            "step." + name, lane=self.engine_lane, t=now, step=st.index)
+        st._open_name, st._open_start = name, now
+
+    def step_count(self, key: str, n: int) -> None:
+        """Add ``n`` to one of :data:`STEP_COUNTS` of the step in flight
+        (a host int the caller already holds — never a device value)."""
+        self._step.counts[key] += n
+
+    def end_step(self, st: StepSpans) -> None:
+        """Close the open phase, put the counts on the ``serving.step``
+        span and close it."""
+        now = time.perf_counter()
+        self.tracer.end_span(st._open, t=now)
+        st.phases.append((st._open_name, st._open_start, now))
+        if st._root is not None:
+            st._root.attrs.update(st.counts)
+        self.tracer.end_span(st._root, t=now)
+        self._step = None
+
     def record_step(self, active_slots: int, num_slots: int,
                     queue_depth: int, new_tokens: int,
-                    step_seconds: float, step_index: int = 0,
+                    step_seconds: float,
                     phases: Optional[Sequence[Tuple[str, float, float]]]
                     = None) -> None:
         """One engine step's accounting (called after the token harvest —
         never between device dispatches).  ``phases`` is the step's
         timeline breakdown as ``(name, start, end)`` perf_counter
-        triples; each lands in a ``serving.phase.<name>_s`` histogram
-        and as a ``step.<name>`` span on the engine lane."""
+        triples (:attr:`StepSpans.phases`: the ``step.<name>`` spans
+        were written live); each lands in a ``serving.phase.<name>_s``
+        histogram."""
         occupancy = active_slots / max(num_slots, 1)
         self._c_steps.inc()
         self._c_tokens.inc(new_tokens)
@@ -547,8 +610,6 @@ class ServingMetrics:
                         f"step phase: {name}", unit="s")
                     self._phase_h[name] = hp
                 hp.observe(end - start)
-                self.tracer.add_span(f"step.{name}", self.engine_lane,
-                                     start, end, step=step_index)
 
     # --------------------------------------------------------- counters
     # lifetime counts read as plain ints (the pre-registry attribute API)

@@ -153,6 +153,11 @@ DEFAULT_PAIRS: Tuple[ResourcePair, ...] = (
     # phantom (the engine's serving.step pattern — end_span in finally)
     ResourcePair("begin_span", "end_span", "trace span",
                  receiver_hint=("tracer", "obs")),
+    # serving/metrics.py ServingMetrics: a step's serving.step span and
+    # its open step.<phase> child (trace annotations too) close in the
+    # engine's finally — end_step closes the phase first, so they nest
+    ResourcePair("begin_step", "end_step", "step span set",
+                 receiver_hint=("metrics",)),
     # obs.Tracer capture sessions: an enable without a guaranteed
     # disable leaves a tracer recording (and its profiler source live)
     # after the workload raised

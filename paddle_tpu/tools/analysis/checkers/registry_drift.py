@@ -142,6 +142,7 @@ ALLOWLIST: Dict[str, str] = {
     **{n: _SERVING for n in (
         "ServingEngine", "EngineCore", "Request", "RequestOutput",
         "SamplingParams", "Scheduler", "KVPool", "ServingMetrics",
+        "StepSpans",
         "bucket_length", "sample_rows", "BlockPool", "PrefixCache",
         "MatchResult",
         # fault-tolerance surface (ISSUE 8): watchdog/ladder/injection

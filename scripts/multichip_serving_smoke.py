@@ -13,10 +13,12 @@ artifacts (the TP sibling of ``scripts/chaos_smoke.py``):
     ``tp_fused`` composed / ``tp_fused_block`` fused at tp > 1 — the
     fused-TP leg cannot silently fall back), token PARITY against the
     composed tp=1 engine ACROSS modes, tokens/sec, TTFT p50/p99,
-    ``serving.collective_s`` stats, and the sharded-plane check (slab
-    PartitionSpec on the kv-head axis);
+    the decode phases' ``serving.phase.decode_dispatch_s`` /
+    ``readback_s`` stats (at tp > 1 they carry the fused collectives),
+    and the sharded-plane check (slab PartitionSpec on the kv-head
+    axis);
   * ``metrics.prom``  — Prometheus text of the last degree's run, so the
-    ``serving_tp_degree`` gauge and ``serving_collective_s`` histogram
+    ``serving_tp_degree`` gauge and ``serving_phase_*_s`` histograms
     documented in docs/observability.md can be eyeballed as scraped.
 
 Usage:
@@ -109,7 +111,8 @@ def run_degree(model_seed, tp, prompts, slots, new_tokens,
         "tokens_per_sec": md["tokens_per_sec"],
         "ttft_p50_ms": md["ttft_p50_ms"],
         "ttft_p99_ms": md["ttft_p99_ms"],
-        "collective_s": snap["serving.collective_s"],
+        "decode_dispatch_s": snap["serving.phase.decode_dispatch_s"],
+        "readback_s": snap["serving.phase.readback_s"],
         "tp_degree_gauge": snap["serving.tp_degree"],
         "slab_spec": slab_spec,
     }, eng

@@ -5,8 +5,9 @@ telemetry artifacts production tooling scrapes:
   * ``metrics.prom``  — Prometheus text exposition of the engine's
     metrics registry (TTFT/TPOT/step-time histograms, counters, gauges);
   * ``trace.json``    — Chrome trace (chrome://tracing / Perfetto) with
-    per-request lifecycle lanes merged alongside the profiler's
-    ``RecordEvent`` host events.
+    the engine lane (``serving.step`` and its ``step.*`` phases) and
+    per-request lifecycle lanes merged alongside the profiler's own
+    host events.
 
 Usage:
     python scripts/obs_dump.py --out /tmp/obs [--requests 6] [--slots 2]
@@ -86,8 +87,8 @@ def main(argv=None) -> int:
         with open(prom_path, "w") as f:
             f.write(eng.registry.prometheus())
         trace_path = os.path.join(args.out, "trace.json")
-        # prof.export merges the host RecordEvents with the engine
-        # tracer's request lanes (record_events=True registered it)
+        # prof.export merges the profiler's host events with the engine
+        # tracer's lanes (record_events=True registered it)
         prof.export(trace_path)
     finally:
         tracer.disable()

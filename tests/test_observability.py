@@ -6,8 +6,9 @@ The load-bearing contracts (ISSUE 6):
     first-token -> decode -> finish) with monotonic timestamps;
   * p50/p99 TTFT and TPOT from the log-bucketed histograms track the
     exact per-request values;
-  * chrome-trace export is valid JSON with request lanes merged next to
-    the profiler's RecordEvent host events, nesting intact;
+  * chrome-trace export is valid JSON with the engine lane's step
+    phases and the request lanes merged into the profiler's export,
+    nesting intact;
   * HARD CONSTRAINTS: telemetry adds ZERO device syncs (the per-step
     token readback stays the only one) and costs <3% of step wall time;
     memory is bounded (ring-buffered spans, fixed histogram buckets);
@@ -376,9 +377,18 @@ def test_chrome_trace_schema_request_lanes_and_nesting(gpt, tmp_path):
                       if e["ph"] == "M" and e["name"] == "thread_name"}
         for o in outs:
             assert f"request {o.request_id}" in lane_names
-        # (c) host RecordEvents from the SAME export (merged timeline)
-        assert any(e.get("cat") == "host" and e["name"] == "serving.step"
-                   for e in evs)
+        # (c) the engine lane from the SAME export (merged timeline):
+        # every serving.step slice with its step.* children inside it
+        engine = [e for e in evs
+                  if e["ph"] == "X" and e.get("cat") == "serving"]
+        steps = {e["args"]["step"]: e for e in engine
+                 if e["name"] == "serving.step"}
+        kids = [e for e in engine if e["name"].startswith("step.")]
+        assert steps and kids
+        for e in kids:
+            parent = steps[e["args"]["step"]]
+            assert e["ts"] >= parent["ts"] - 1
+            assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 2
         # (d) nesting intact: each request lane's prefill/decode slices
         # sit inside its request slice
         by_lane = {}
@@ -398,28 +408,232 @@ def test_chrome_trace_schema_request_lanes_and_nesting(gpt, tmp_path):
         eng.tracer.remove_profiler_source()
 
 
-def test_record_event_closed_on_raise(gpt, monkeypatch):
-    """Regression: a raising step must still close its RecordEvent AND
-    its serving.step span — later events may not nest inside phantoms."""
-    from paddle_tpu.profiler import Profiler
+class _RecordingAnnotate:
+    """An ``annotate`` factory for ``Tracer``: logs every enter/exit and
+    refuses an exit that is not the innermost open annotation (what a
+    thread's line of a profiler trace needs)."""
+
+    def __init__(self):
+        self.log = []
+        self.open = []
+
+    def __call__(self, name):
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                rec.open.append(name)
+                rec.log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                assert rec.open and rec.open[-1] == name, (name, rec.open)
+                rec.open.pop()
+                rec.log.append(("exit", name))
+
+        return _Ann()
+
+
+_PHASES = ("admission", "prefill", "first_token_readback", "draft",
+           "decode_dispatch", "readback", "harvest", "bookkeeping")
+
+
+@pytest.mark.parametrize("spec_k", [0, 3])
+def test_step_annotations_nest_under_serving_step(gpt, spec_k):
+    """One tracer call writes the ring's span AND the annotation: a step
+    that admits, completes a prefill and decodes enters and leaves
+    exactly the documented phases, in order, inside ``serving.step``
+    (``step.draft`` only where speculation is on)."""
+    from paddle_tpu.serving.metrics import STEP_PHASES
+    assert STEP_PHASES == _PHASES
+    rec = _RecordingAnnotate()
+    eng = ServingEngine(gpt, num_slots=3, min_bucket=8, spec_k=spec_k,
+                        tracer=Tracer(annotate=rec))
+    eng.submit(np.tile([5, 6, 7, 8], 4), max_new_tokens=4)
+    eng.step()
+    want = [p for p in _PHASES if spec_k or p != "draft"]
+    log = [("enter", "serving.step")]
+    for p in want:
+        log += [("enter", f"step.{p}"), ("exit", f"step.{p}")]
+    log.append(("exit", "serving.step"))
+    assert rec.log == log and rec.open == []
+    # the same call wrote the ring: same names, same order, one step
+    lane0 = eng.tracer.spans(lane=0)
+    assert [s.name for s in lane0] == \
+        [f"step.{p}" for p in want] + ["serving.step"]
+    assert {s.attrs["step"] for s in lane0} == {0}
+    # request-lane records are add_span facts: never annotated
+    assert not any(n in ("queued", "prefill", "decode", "request")
+                   for _, n in rec.log)
+
+
+def _raise_in(monkeypatch, eng, phase):
+    """Arm ``eng`` so that its next step raises inside ``phase``."""
+    def boom(*a, **kw):
+        raise RuntimeError("boom")
+    core = eng.core
+    if phase == "admission":
+        monkeypatch.setattr(core.scheduler, "admit", boom)
+    elif phase == "prefill":
+        monkeypatch.setattr(core, "_run_chunk", boom)
+    elif phase == "first_token_readback":
+        monkeypatch.setattr(core.prefix_cache, "insert", boom)
+    elif phase == "decode_dispatch":
+        monkeypatch.setattr(core, "_decode_dispatch", boom)
+    elif phase == "harvest":
+        monkeypatch.setattr(core, "_harvest", boom)
+    elif phase == "bookkeeping":
+        monkeypatch.setattr(core, "_evict_finished", boom)
+    else:
+        raise AssertionError(phase)
+
+
+@pytest.mark.parametrize("phase", ["admission", "prefill",
+                                   "first_token_readback",
+                                   "decode_dispatch", "harvest",
+                                   "bookkeeping"])
+def test_record_event_closed_on_raise(gpt, monkeypatch, phase):
+    """Regression: a step that raises mid-phase must still close the
+    open phase and then its serving.step — span AND annotation,
+    innermost first — or later events nest inside phantoms."""
+    rec = _RecordingAnnotate()
     eng = ServingEngine(gpt, num_slots=2, min_bucket=8,
-                        record_events=True)
+                        tracer=Tracer(annotate=rec))
+    eng.submit(_prompts(9, (4,))[0], max_new_tokens=2)
+    _raise_in(monkeypatch, eng, phase)
+    with pytest.raises(RuntimeError, match="boom"):
+        eng.step()
+    assert rec.open == []                   # none left open
+    assert rec.log[0] == ("enter", "serving.step")
+    assert rec.log[-2:] == [("exit", f"step.{phase}"),
+                            ("exit", "serving.step")]
+    lane0 = eng.tracer.spans(lane=0)
+    assert [s.name for s in lane0][-2:] == [f"step.{phase}",
+                                            "serving.step"]
+    assert all(s.end >= s.start and s._annotation is None for s in lane0)
+    # the step's counts are on its span even though it raised
+    from paddle_tpu.serving.metrics import STEP_COUNTS
+    assert set(STEP_COUNTS) <= set(lane0[-1].attrs)
+
+
+def test_record_events_annotates_with_the_profilers_annotation(gpt):
+    """``record_events=True`` is the one knob: it hands the tracer
+    ``jax.profiler.TraceAnnotation`` (and leaves an injected factory
+    alone); without it the tracer writes the ring only."""
+    on = ServingEngine(gpt, num_slots=2, min_bucket=8, record_events=True)
     try:
-        eng.submit(_prompts(9, (4,))[0], max_new_tokens=2)
-        prof = Profiler(timer_only=True)
-        prof.start()
-        monkeypatch.setattr(eng.core.scheduler, "admit",
-                            lambda *a, **kw: (_ for _ in ()).throw(
-                                RuntimeError("boom")))
-        with pytest.raises(RuntimeError, match="boom"):
-            eng.step()
-        prof.stop()
-        closed = [e for e in prof.events() if e.name == "serving.step"]
-        assert closed and all(e.end_us >= e.start_us for e in closed)
-        spans = eng.tracer.spans(lane=0, name="serving.step")
-        assert spans and all(s.end >= s.start for s in spans)
+        assert on.tracer.annotate is jax.profiler.TraceAnnotation
     finally:
-        eng.tracer.remove_profiler_source()
+        on.close()
+    off = ServingEngine(gpt, num_slots=2, min_bucket=8)
+    assert off.tracer.annotate is None
+    rec = _RecordingAnnotate()
+    mine = ServingEngine(gpt, num_slots=2, min_bucket=8, record_events=True,
+                         tracer=Tracer(annotate=rec))
+    try:
+        assert mine.tracer.annotate is rec
+    finally:
+        mine.close()
+
+
+def _steps_with_children(tr):
+    kids = {}
+    for s in tr.spans(lane=0):
+        if s.name.startswith("step."):
+            kids.setdefault(s.attrs["step"], []).append(s)
+    return [(s, kids.get(s.attrs["step"], []))
+            for s in tr.spans(lane=0, name="serving.step")]
+
+
+def test_step_children_tile_the_step(gpt):
+    """The phases follow one another with nothing between them (one
+    clock reading closes a phase and opens the next): the children's
+    durations sum to the step's (within 5% or 50 us), each child lies
+    inside its step, and none overlaps the next."""
+    eng = ServingEngine(gpt, num_slots=2, min_bucket=8)
+    _mixed_run(eng, seed=21)
+    pairs = _steps_with_children(eng.tracer)
+    assert len(pairs) > 5
+    for step, kids in pairs:
+        assert kids, step
+        covered = sum(k.duration for k in kids)
+        assert abs(step.duration - covered) <= \
+            max(0.05 * step.duration, 50e-6), (step, covered)
+        assert step.start <= kids[0].start and kids[-1].end <= step.end
+        for a, b in zip(kids, kids[1:]):
+            assert a.end <= b.start
+    # a tracer that is off still feeds the phase histograms
+    quiet = ServingEngine(gpt, num_slots=2, min_bucket=8)
+    quiet.tracer.disable()
+    _mixed_run(quiet, seed=21)
+    assert quiet.tracer.spans() == []
+    for phase in ("admission", "prefill", "first_token_readback",
+                  "decode_dispatch", "readback", "harvest", "bookkeeping"):
+        h = quiet.registry.get(f"serving.phase.{phase}_s")
+        assert h is not None and h.count > 0, phase
+
+
+def test_step_counts_and_request_attrs_match_an_outside_count(gpt):
+    """The counts on each ``serving.step`` span equal what a client can
+    count from outside, and a request's spans carry its id and the index
+    of the step that caused them."""
+    eng = ServingEngine(gpt, num_slots=2, min_bucket=8)
+    m = eng.metrics
+    prompts = _prompts(22, (5, 11, 3, 9, 14))
+    new = (4, 1, 6, 3, 5)
+    seen = {}            # request id -> engine step index of each token
+    at = {"step": None}
+
+    def sink(req, tok):
+        seen.setdefault(req.request_id, []).append(at["step"])
+
+    before = (m.prefill_tokens, m.prefill_chunk_tokens, m.prefills,
+              m.tokens_generated)
+    ids = [eng.submit(p, max_new_tokens=n, stream=sink)
+           for p, n in zip(prompts[:3], new[:3])]
+    k = 0
+    while True:
+        at["step"] = eng.core._step_index
+        if k == 3:
+            ids += [eng.submit(p, max_new_tokens=n, stream=sink)
+                    for p, n in zip(prompts[3:], new[3:])]
+        k += 1
+        if not eng.step():
+            break
+        assert k < 500
+    steps = {s.attrs["step"]: s
+             for s in eng.tracer.spans(lane=0, name="serving.step")}
+    total = lambda key: sum(s.attrs[key] for s in steps.values())
+    assert total("prefill_tokens") == m.prefill_chunk_tokens - before[1] \
+        == m.prefill_tokens - before[0] == sum(len(p) for p in prompts)
+    assert total("prefills_completed") == m.prefills - before[2] == 5
+    assert total("admitted") == 5
+    assert total("new_tokens") == m.tokens_generated - before[3] \
+        == sum(new)
+    plen = dict(zip(ids, (len(p) for p in prompts)))
+    for idx, span in steps.items():
+        # in the slots at this step's decode dispatch: first token out in
+        # a step <= idx, not finished (so not evicted) in a step < idx
+        live = [(rid, sum(1 for t in toks if t < idx))
+                for rid, toks in seen.items()
+                if toks[0] <= idx and toks[-1] >= idx]
+        assert span.attrs["active_slots"] == len(live), idx
+        # a slot holds its prompt's rows and one more per token after
+        # the first (which the prefill itself produced)
+        assert span.attrs["live_kv_rows"] == sum(
+            plen[rid] + max(before_n - 1, 0) for rid, before_n in live), idx
+        assert span.attrs["new_tokens"] == sum(
+            toks.count(idx) for toks in seen.values()), idx
+        assert 0 <= span.attrs["queue_depth"] <= 5
+    for rid in ids:
+        lane = 1 + rid
+        spans = eng.tracer.spans(lane=lane)
+        assert spans and all(s.attrs["request"] == rid for s in spans)
+        by = {s.name: s for s in spans}
+        for name in ("queued", "prefill", "decode"):
+            owner = steps[by[name].attrs["step"]]
+            assert owner.start <= by[name].end <= owner.end, (rid, name)
+        assert by["prefill"].attrs["step"] == seen[rid][0]
+        assert by["decode"].attrs["step"] == seen[rid][-1]
 
 
 # ------------------------------------------------- the two hard constraints
@@ -473,9 +687,10 @@ def test_zero_added_device_syncs(gpt, monkeypatch):
 
 def test_telemetry_overhead_under_3pct_of_step(gpt):
     """Overhead-budget pin: the per-step telemetry work (counters,
-    histograms, spans, events — measured as a pure-host microbench of
-    MORE calls than a real step makes) costs <3% of the measured decode
-    step wall time on the CPU-smoke loop."""
+    histograms, the live step spans, events — measured as a pure-host
+    microbench of the calls a steady-state decode step makes, and one
+    more) costs <3% of the measured decode step wall time on the
+    CPU-smoke loop."""
     eng = ServingEngine(gpt, num_slots=2, min_bucket=8,
                         prefill_chunk=None)
     ids = [eng.submit(p, max_new_tokens=100)
@@ -490,23 +705,29 @@ def test_telemetry_overhead_under_3pct_of_step(gpt):
     step_wall = (time.perf_counter() - t0) / max(k, 1)
 
     m, tr = eng.metrics, eng.tracer
+    steady = [p for p in _PHASES
+              if p not in ("first_token_readback", "draft")]
+    assert [s.name for s in tr.spans(lane=0)][-7:] == \
+        [f"step.{p}" for p in steady] + ["serving.step"]
     reps = 2000
     t0 = time.perf_counter()
     for i in range(reps):
         # exactly the telemetry one steady-state 2-slot decode step
-        # performs: a TPOT sample per slot, the step span pair, the
-        # trace-counter scan, and record_step with the phase timeline
+        # performs: a TPOT sample per slot, the step span with the six
+        # live phase spans such a step has and its counts, the
+        # trace-counter scan, and record_step fed from the phase timeline
         m.on_output_token(1e-3)
         m.on_output_token(1e-3)
-        sp = tr.begin_span("serving.step", lane=0, step=i)
-        tr.end_span(sp)
+        spans = m.begin_step(i, steady[0])
+        for phase in steady[1:]:
+            m.phase(phase)
+        m.step_count("prefill_tokens", 7)
+        spans.counts["active_slots"] = 2
+        m.end_step(spans)
         eng.core._record_events(i, eng.core.scheduler.total_head_skips)
-        m.record_step(2, 2, 1, 2, 1e-3, step_index=i,
-                      phases=(("admission", 0.0, 1e-5),
-                              ("prefill", 0.0, 1e-4),
-                              ("decode_dispatch", 0.0, 1e-3),
-                              ("readback", 0.0, 1e-5)))
+        m.record_step(2, 2, 1, 2, 1e-3, phases=spans.phases)
     obs_per_step = (time.perf_counter() - t0) / reps
+    assert len(tr.spans(lane=0, name="step.harvest")) > 0
     assert obs_per_step < 0.03 * step_wall, (obs_per_step, step_wall)
 
 
@@ -558,7 +779,8 @@ def test_obs_dump_artifacts(tmp_path):
 
     data = json.load(open(os.path.join(out, "trace.json")))
     names = {e.get("name") for e in data["traceEvents"]}
-    assert "serving.step" in names                   # host RecordEvent
+    assert "serving.step" in names                   # the engine lane
+    assert "step.decode_dispatch" in names
     lanes = {e["args"]["name"] for e in data["traceEvents"]
              if e.get("ph") == "M" and e.get("name") == "thread_name"}
     assert any(n.startswith("request ") for n in lanes)

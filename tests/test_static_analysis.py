@@ -353,6 +353,8 @@ def test_obs_span_pairs_registered():
     pairs = {(p.acquire, p.release) for p in DEFAULT_PAIRS}
     assert ("begin_span", "end_span") in pairs
     assert ("enable", "disable") in pairs
+    # PR 26: the engine's step span set (serving.step + the open phase)
+    assert ("begin_step", "end_step") in pairs
     hints = {p.acquire: p.receiver_hint for p in DEFAULT_PAIRS}
     # hinted to tracer-ish receivers so `re.match`-style name collisions
     # (or any enable() on a non-tracer object) stay untracked
@@ -723,7 +725,7 @@ def test_dtype_flow_negative():
     assert res.findings == [], [f.format() for f in res.findings]
 
 
-def test_recompile_shape_through_decode_block_signature():
+def test_recompile_shape_through_signature_of_decode_block():
     """ISSUE 7: the decode_block signatures flow ``(y, k_slab', v_slab')``
     through call sites, so fixed-shape hazards on the fused kernel's
     OUTPUTS are provable — exactly 2 planted (bool-mask on the returned
@@ -745,7 +747,7 @@ def test_recompile_shape_decode_block_negative():
     assert res.findings == [], [f.format() for f in res.findings]
 
 
-def test_dtype_flow_through_decode_block_signature():
+def test_dtype_flow_through_signature_of_decode_block():
     """The decode_block summaries carry the activation dtype onto the
     outputs: exactly 2 planted bf16 accumulation bugs downstream of the
     fused layer (bf16 sum, bf16 @-contraction)."""

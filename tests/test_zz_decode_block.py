@@ -344,12 +344,14 @@ def test_obs_event_and_histogram_mark_fused_path(gpt):
     assert len(evs) == 1
     attrs = evs[0][3]
     assert attrs["active"] is True and attrs["reason"] == ""
-    assert eng.core.metrics._h_decode_block.count > 0
-    # unfused engine: event says inactive, histogram stays empty
+    # the fused dispatch is timed by the decode phase's histogram, the
+    # event says which path that was
+    assert eng.registry.get("serving.phase.decode_dispatch_s").count > 0
+    # unfused engine: event says inactive, the same phase is timed
     toks2, eng2 = _serve(gpt, False, False)
     evs2 = eng2.core.metrics.tracer.events("decode_block")
     assert len(evs2) == 1 and evs2[0][3]["active"] is False
-    assert eng2.core.metrics._h_decode_block.count == 0
+    assert eng2.registry.get("serving.phase.decode_dispatch_s").count > 0
 
 
 def test_bench_compare_row_smoke():

@@ -383,8 +383,9 @@ def test_compile_pin_tp_fused_block():
 
 
 def test_obs_event_carries_tp_dimension():
-    """The decode_block obs event gains the mesh degree, and fused TP
-    steps feed the kernel.decode_block_s histogram."""
+    """The decode_block obs event gains the mesh degree (it says which
+    path the decode phases' histograms timed), and fused TP steps feed
+    serving.phase.decode_dispatch_s like every other path."""
     m = _fresh(lambda: GPTForCausalLM(gpt_tiny()))
     eng = ServingEngine(m, num_slots=2, tensor_parallel=2,
                         fused_decode=True)
@@ -395,7 +396,7 @@ def test_obs_event_carries_tp_dimension():
     assert attrs["active"] is True
     assert attrs["tp"] == 2
     assert attrs["reason"] == ""
-    assert eng.core.metrics._h_decode_block.count > 0
+    assert eng.registry.get("serving.phase.decode_dispatch_s").count > 0
     # a composed tp engine still reports active=False at its degree
     m2 = _fresh(lambda: GPTForCausalLM(gpt_tiny()))
     e2 = ServingEngine(m2, num_slots=2, tensor_parallel=2)
@@ -404,7 +405,7 @@ def test_obs_event_carries_tp_dimension():
     attrs2 = evs2[0][3]
     assert attrs2["active"] is False
     assert attrs2["tp"] == 2
-    assert e2.core.metrics._h_decode_block.count == 0
+    assert e2.registry.get("serving.phase.decode_dispatch_s").count > 0
 
 
 # -------------------------------------------------------- bench smokes
