@@ -45,9 +45,14 @@ already holds.
 Per-slot sampling reuses ``generation._filter_top_p`` directly (its
 threshold broadcasts over rows) and generalises ``_filter_top_k`` to a
 per-row traced k via rank masking (``_filter_top_k_rows`` — the static-k
-form cannot vary k within one compiled step).  Each slot draws from its
-OWN PRNG key with the same split discipline as ``generate``, so a
-single-request engine run reproduces ``generate(seed=...)`` token for
+form cannot vary k within one compiled step).  Each stage of that tail
+sits under a ``lax.cond`` on the rows' own parameters (``sample_rows``):
+an all-greedy batch runs the argmax alone, the rank mask runs only where
+a sampling row has ``top_k > 0`` and the nucleus filter only where one
+has ``top_p < 1`` — bit-equal to running every stage, in the SAME one
+decode program.  Each slot draws from its OWN PRNG key with the same
+split discipline as ``generate`` (the split stays outside the conds), so
+a single-request engine run reproduces ``generate(seed=...)`` token for
 token, sampling included.
 """
 
@@ -140,6 +145,15 @@ def sample_rows(keys, logits, do_sample, temperature, top_k, top_p,
     of ``keys [rows, key_dim]``, so one request's randomness never
     depends on its slot neighbours.
 
+    Each stage sits under a ``lax.cond`` on what the rows ask for,
+    computed here from the operands themselves: no row samples -> the
+    argmax alone (no scale, sort, gather or draw); the rank mask only
+    where a sampling row has ``top_k > 0``, the nucleus filter only
+    where one has ``top_p < 1``.  A row that does not ask for a stage
+    gets its logits back unchanged from it anyway, so a skipped stage
+    changes no value: the result is bit-equal to the ungated pipeline
+    for every row, inside ONE program of the same signature.
+
     ``mask [rows, vocab] bool`` (constrained decoding) bans False
     columns BEFORE everything — greedy argmax and the filter pipeline
     both see ``-inf`` there, so a constrained row renormalizes over its
@@ -150,15 +164,40 @@ def sample_rows(keys, logits, do_sample, temperature, top_k, top_p,
     if mask is not None:
         logits = jnp.where(mask, logits, -jnp.inf)
     greedy_tok = jnp.argmax(logits, axis=-1)
-    temp = jnp.maximum(jnp.asarray(temperature, jnp.float32), 1e-6)
-    scaled = logits / temp[:, None]
-    filtered = _filter_top_k_rows(scaled, top_k)
-    p = jnp.asarray(top_p, jnp.float32)[:, None]
-    # rows with top_p == 1.0 skip the nucleus filter EXACTLY, matching
-    # generate()'s static skip; filtered rows take the nucleus lane
-    filtered = jnp.where(p >= 1.0, filtered, _filter_top_p(filtered, p))
-    sampled = jax.vmap(jax.random.categorical)(keys, filtered)
-    return jnp.where(jnp.asarray(do_sample, bool), sampled, greedy_tok)
+    do_sample = jnp.asarray(do_sample, bool)
+    k = jnp.asarray(top_k, jnp.int32)
+    p = jnp.asarray(top_p, jnp.float32)
+
+    def nucleus(x):
+        # rows with top_p == 1.0 skip the nucleus filter EXACTLY,
+        # matching generate()'s static skip; filtered rows take the
+        # nucleus lane
+        col = p[:, None]
+        return jnp.where(col >= 1.0, x, _filter_top_p(x, col))
+
+    def sample():
+        temp = jnp.maximum(jnp.asarray(temperature, jnp.float32), 1e-6)
+        x = logits / temp[:, None]
+        x = jax.lax.cond(jnp.any(do_sample & (k > 0)),
+                         lambda x: _filter_top_k_rows(x, k),
+                         lambda x: x, x)
+        x = jax.lax.cond(jnp.any(do_sample & (p < 1.0)),
+                         nucleus, lambda x: x, x)
+        sampled = jax.vmap(jax.random.categorical)(keys, x)
+        return jnp.where(do_sample, sampled, greedy_tok)
+
+    return jax.lax.cond(jnp.any(do_sample), sample, lambda: greedy_tok)
+
+
+@jax.jit
+def _first_token(key, logits, do_sample, temperature, top_k, top_p, mask):
+    """A completed prefill's first token (sentinel-encoded, shape
+    ``[1]``): the decode tail on the one row ``logits [vocab]``, as ONE
+    small program.  Jitted because an eager ``lax.cond`` traces its
+    branches anew, and so compiles anew, on every call."""
+    first = sample_rows(key[None], logits[None], do_sample, temperature,
+                        top_k, top_p, mask=mask[None])
+    return finite_or_sentinel(logits[None], first)
 
 
 def _verify_tail(logits, drafts, draft_len, keys, do_sample, temperature,
@@ -974,14 +1013,15 @@ class EngineCore:
             self._mask_host[slot, np.asarray(req.allowed_tokens,
                                              np.int64)] = True
         self._mask_dev = None
-        first = sample_rows(
-            sub[None], st.last_logits[None],
-            jnp.asarray([s.do_sample]),
-            jnp.asarray([s.temperature], jnp.float32),
-            jnp.asarray([s.top_k], jnp.int32),
-            jnp.asarray([s.top_p], jnp.float32),
-            mask=jnp.asarray(self._mask_host[slot][None]))
-        first = finite_or_sentinel(st.last_logits[None], first)
+        # host arrays go in as the call's operands: no eager transfer
+        # programs of their own ahead of it
+        first = _first_token(
+            sub, st.last_logits,
+            np.asarray([s.do_sample], bool),
+            np.asarray([s.temperature], np.float32),
+            np.asarray([s.top_k], np.int32),
+            np.asarray([s.top_p], np.float32),
+            self._mask_host[slot])
         draft = None
         if self.spec_on:
             from .spec import NGramDraftTable
@@ -1525,6 +1565,11 @@ class EngineCore:
             new_tokens = self._advance_prefills()
             if self._slots:
                 counts["active_slots"] = len(self._slots)
+                # free slots read False in the mirror, so this counts
+                # occupied slots only; > 0 exactly when the decode
+                # program's sampling branch runs this step
+                counts["sampling_slots"] = int(
+                    np.count_nonzero(self._do_sample))
                 counts["live_kv_rows"] = sum(
                     st.pos for st in self._slots.values())
                 # speculative draft phase (pure host, spec_on only):

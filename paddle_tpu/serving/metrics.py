@@ -43,7 +43,8 @@ STEP_PHASES = ("admission", "prefill", "first_token_readback", "draft",
                "decode_dispatch", "readback", "harvest", "bookkeeping")
 # host ints the engine already holds, set on the ``serving.step`` span
 STEP_COUNTS = ("admitted", "prefill_tokens", "prefills_completed",
-               "active_slots", "live_kv_rows", "new_tokens", "queue_depth")
+               "active_slots", "sampling_slots", "live_kv_rows",
+               "new_tokens", "queue_depth")
 
 # admission-projection clamps: a degenerate measurement window (one
 # finish inside a denormal-small busy window, or a finish against an

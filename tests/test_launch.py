@@ -84,7 +84,10 @@ def test_launch_worker_logs(tmp_path):
 
 
 def test_spawn_function():
-    from paddle_tpu.distributed.spawn import spawn
+    # through the package attribute: importing the submodule first would
+    # leave ``paddle_tpu.distributed.spawn`` the MODULE for every later
+    # test of this worker (test_generated_docs reads it as the function)
+    from paddle_tpu.distributed import spawn
     import multiprocessing as mp
 
     q = mp.get_context("spawn").Queue()

@@ -690,7 +690,9 @@ def test_telemetry_overhead_under_3pct_of_step(gpt):
     histograms, the live step spans, events — measured as a pure-host
     microbench of the calls a steady-state decode step makes, and one
     more) costs <3% of the measured decode step wall time on the
-    CPU-smoke loop."""
+    CPU-smoke loop.  The telemetry's cost is the fastest of several
+    loops: a loaded host only ever adds to a loop, and the pin is on
+    what the calls cost, not on what else ran meanwhile."""
     eng = ServingEngine(gpt, num_slots=2, min_bucket=8,
                         prefill_chunk=None)
     ids = [eng.submit(p, max_new_tokens=100)
@@ -709,24 +711,30 @@ def test_telemetry_overhead_under_3pct_of_step(gpt):
               if p not in ("first_token_readback", "draft")]
     assert [s.name for s in tr.spans(lane=0)][-7:] == \
         [f"step.{p}" for p in steady] + ["serving.step"]
-    reps = 2000
-    t0 = time.perf_counter()
-    for i in range(reps):
-        # exactly the telemetry one steady-state 2-slot decode step
-        # performs: a TPOT sample per slot, the step span with the six
-        # live phase spans such a step has and its counts, the
-        # trace-counter scan, and record_step fed from the phase timeline
-        m.on_output_token(1e-3)
-        m.on_output_token(1e-3)
-        spans = m.begin_step(i, steady[0])
-        for phase in steady[1:]:
-            m.phase(phase)
-        m.step_count("prefill_tokens", 7)
-        spans.counts["active_slots"] = 2
-        m.end_step(spans)
-        eng.core._record_events(i, eng.core.scheduler.total_head_skips)
-        m.record_step(2, 2, 1, 2, 1e-3, phases=spans.phases)
-    obs_per_step = (time.perf_counter() - t0) / reps
+    loops, reps = 8, 400
+    obs_per_step = float("inf")
+    for loop in range(loops):
+        t0 = time.perf_counter()
+        for i in range(loop * reps, (loop + 1) * reps):
+            # exactly the telemetry one steady-state 2-slot decode step
+            # performs: a TPOT sample per slot, the step span with the
+            # six live phase spans such a step has and its counts, the
+            # trace-counter scan, and record_step fed from the phase
+            # timeline
+            m.on_output_token(1e-3)
+            m.on_output_token(1e-3)
+            spans = m.begin_step(i, steady[0])
+            for phase in steady[1:]:
+                m.phase(phase)
+            m.step_count("prefill_tokens", 7)
+            spans.counts["active_slots"] = 2
+            spans.counts["sampling_slots"] = int(
+                np.count_nonzero(eng.core._do_sample))
+            m.end_step(spans)
+            eng.core._record_events(i, eng.core.scheduler.total_head_skips)
+            m.record_step(2, 2, 1, 2, 1e-3, phases=spans.phases)
+        obs_per_step = min(obs_per_step,
+                           (time.perf_counter() - t0) / reps)
     assert len(tr.spans(lane=0, name="step.harvest")) > 0
     assert obs_per_step < 0.03 * step_wall, (obs_per_step, step_wall)
 
