@@ -403,8 +403,13 @@ def resolve_fused_decode(model, *, batch: int, kv_len: int, tp: int = 1):
     place.  Returns ``(ok, reason)``; ``reason`` is None when the
     fused path may engage."""
     supported = getattr(model, "fused_decode_supported", None)
-    if supported is None or not hasattr(model, "fused_decode_step"):
+    if supported is None:
         return False, "model has no fused_decode_step"
+    if not hasattr(model, "fused_decode_step"):
+        # a model without the fused step may still say why (its layer is
+        # not one the block kernels compute: models/ouro.py)
+        return False, (supported(batch=batch, kv_len=kv_len, tp=tp)[1]
+                       or "model has no fused_decode_step")
     if tp > 1:
         if not hasattr(model, "tp_decode_weights") \
                 or not hasattr(model, "tp_decode_supported"):

@@ -132,6 +132,10 @@ def engine_aot_context(core) -> Dict[str, Any]:
         "model": model_ctx,
         "num_slots": core.num_slots,
         "max_seq": core.pool.max_seq,
+        # the RESOLVED plane count (kv_pool.cache_geometry): for a
+        # looped model a property of its config, not a field of it, and
+        # it shapes every program's slab operands
+        "kv_planes": core.pool.planes,
         "min_bucket": core.scheduler.min_bucket,
         "prefill_chunk": core.prefill_chunk,
         "block_len": bp.block_len if bp is not None else None,

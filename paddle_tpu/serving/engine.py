@@ -94,7 +94,10 @@ __compile_surface_roots__ = ("EngineCore",)
 # are the per-slot decode vectors ``_build_device_plane`` allocates
 # (last token i32, PRNG key pair u32x2, sampling params bool+f32+i32+f32,
 # logit mask bool[vocab]); ``staging`` is the single-slot prefill cache
-# (per-layer k+v at the model dtype).  Pure data, read by the AST
+# (per-slab k+v at the model dtype; ``num_layers`` and ``kv_heads`` are
+# the POOL's: its slabs and the heads one slab holds, whose product is
+# planes x the model's kv heads for every family, kv_pool.
+# cache_geometry).  Pure data, read by the AST
 # analysis and pinned against runtime measurement by
 # tests/test_zz_memory_surface.py; zero runtime effect.
 __memory_bytes__ = {
@@ -434,6 +437,10 @@ class EngineCore:
             # get back an untouched single-device model, not one whose
             # weights were already laid out over a mesh
             cfg = model.cfg
+            refusal = _tp.serving_refusal(model)
+            if refusal is not None:
+                raise ValueError(
+                    f"tensor_parallel {tensor_parallel}: {refusal}")
             kv_heads = getattr(cfg, "kv_heads", None) or cfg.num_heads
             if kv_heads % tensor_parallel:
                 raise ValueError(
@@ -446,6 +453,10 @@ class EngineCore:
             # all compile against the sharded weights
             _tp.shard_model_params(model, self.mesh)
         self.metrics.set_tp_degree(tensor_parallel)
+        # times a token passes the model's layer stack in one forward
+        # step (a looped model's cfg says; 1 otherwise): the
+        # ``loop_passes`` of the step and prefill spans
+        self.loop_passes = int(getattr(model.cfg, "loop_passes", 1))
         self._build_device_plane()
         self.scheduler = Scheduler(num_slots, self.pool.max_seq,
                                    min_bucket=min_bucket,
@@ -639,6 +650,7 @@ class EngineCore:
         self.pool = KVPool.create(model, num_slots, self._max_seq_arg,
                                   mesh=self.mesh)
         self.pool.faults = self.faults
+        self.metrics.set_kv_planes(self.pool.planes)
         self.prefix_cache: Optional[PrefixCache] = None
         self.block_pool: Optional[BlockPool] = None
         # once the degradation ladder bypassed the cache, a quarantine
@@ -1565,6 +1577,7 @@ class EngineCore:
             new_tokens = self._advance_prefills()
             if self._slots:
                 counts["active_slots"] = len(self._slots)
+                counts["loop_passes"] = self.loop_passes
                 # free slots read False in the mirror, so this counts
                 # occupied slots only; > 0 exactly when the decode
                 # program's sampling branch runs this step
@@ -1875,6 +1888,7 @@ class EngineCore:
                                 req.admit_time or req.arrival_time, now,
                                 chunks=req.prefill_chunks,
                                 hit_tokens=req.prefix_hit_tokens,
+                                loop_passes=self.loop_passes,
                                 request=req.request_id,
                                 step=self._step_in_flight)
                 tracer.event("first_token", lane=lane, t=now)

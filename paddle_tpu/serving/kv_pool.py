@@ -24,13 +24,33 @@ import jax.numpy as jnp
 
 from ..models.kv_cache import gather_block_rows, scatter_block_rows
 
-__all__ = ["KVPool", "BlockPool"]
+__all__ = ["KVPool", "BlockPool", "cache_geometry"]
 
 # graftmem marker (tools/analysis/memory.py): every slab extent in the
 # pool constructors below must flow from registered capacity fields —
 # the derived blocks-per-row ratio is declared here so the capacity
 # manifest can name it alongside the constructor parameters
 __memory_capacity_fields__ = ("blocks_per_row",)
+
+
+def cache_geometry(cfg) -> Tuple[int, int, int]:
+    """``(planes, slabs, kv heads a slab holds)`` of a causal-LM's cache.
+
+    A PLANE is one K and one V row per cached position: a model that
+    applies each layer once has a plane per layer; a looped model has a
+    plane per pass per layer and says so in ``cfg.num_cache_layers``.
+    A SLAB is one array of the pool's lists.  Ordinarily a slab is a
+    plane; a model whose program loops over its planes reaches them by
+    a traced index, so it keeps ``cfg.cache_planes_per_slab`` planes
+    side by side on one slab's head axis (models/ouro.py).  ``kv_heads``
+    falls back to ``num_heads`` for MHA models like GPT."""
+    planes = getattr(cfg, "num_cache_layers", None) or cfg.num_layers
+    per_slab = getattr(cfg, "cache_planes_per_slab", None) or 1
+    if planes % per_slab:
+        raise ValueError(f"cache_planes_per_slab {per_slab} must divide "
+                         f"the {planes} cache planes")
+    kv_heads = getattr(cfg, "kv_heads", None) or cfg.num_heads
+    return planes, planes // per_slab, per_slab * kv_heads
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -55,7 +75,7 @@ class KVPool:
 
     def __init__(self, num_slots: int, max_seq: int, num_layers: int,
                  kv_heads: int, head_dim: int, dtype=jnp.float32,
-                 mesh=None):
+                 mesh=None, planes: Optional[int] = None):
         if num_slots < 1:
             raise ValueError("num_slots must be >= 1")
         if mesh is not None and kv_heads % mesh.devices.size:
@@ -65,7 +85,11 @@ class KVPool:
                 f"slot slabs partition on the kv-head axis)")
         self.num_slots = num_slots
         self.max_seq = max_seq
+        # num_layers counts the SLABS of the lists below; planes the K/V
+        # rows a cached position spans (cache_geometry: they differ for
+        # a looped model only)
         self.num_layers = num_layers
+        self.planes = planes if planes is not None else num_layers
         self.mesh = mesh
         shape = (num_slots, max_seq, kv_heads, head_dim)
         if mesh is None:
@@ -100,14 +124,14 @@ class KVPool:
     @classmethod
     def create(cls, model, num_slots: int,
                max_seq: Optional[int] = None, mesh=None) -> "KVPool":
-        """Size the pool from a causal-LM's config (kv_heads falls back
-        to num_heads for MHA models like GPT).  With ``mesh`` the slabs
-        lay out kv-head-sharded over the tensor-parallel mesh."""
+        """Size the pool from a causal-LM's config
+        (:func:`cache_geometry`).  With ``mesh`` the slabs lay out
+        kv-head-sharded over the tensor-parallel mesh."""
         cfg = model.cfg
         max_seq = max_seq or cfg.max_seq_len
-        kv_heads = getattr(cfg, "kv_heads", None) or cfg.num_heads
-        return cls(num_slots, max_seq, cfg.num_layers, kv_heads,
-                   cfg.head_dim, dtype=jnp.dtype(cfg.dtype), mesh=mesh)
+        planes, slabs, slab_heads = cache_geometry(cfg)
+        return cls(num_slots, max_seq, slabs, slab_heads, cfg.head_dim,
+                   dtype=jnp.dtype(cfg.dtype), mesh=mesh, planes=planes)
 
     # ------------------------------------------------------------ slots
     @property
@@ -250,10 +274,9 @@ class BlockPool:
     def create(cls, model, num_blocks: int, block_len: int,
                max_seq: int, mesh=None) -> "BlockPool":
         cfg = model.cfg
-        kv_heads = getattr(cfg, "kv_heads", None) or cfg.num_heads
-        return cls(num_blocks, block_len, max_seq, cfg.num_layers,
-                   kv_heads, cfg.head_dim, dtype=jnp.dtype(cfg.dtype),
-                   mesh=mesh)
+        _, slabs, slab_heads = cache_geometry(cfg)
+        return cls(num_blocks, block_len, max_seq, slabs, slab_heads,
+                   cfg.head_dim, dtype=jnp.dtype(cfg.dtype), mesh=mesh)
 
     # ------------------------------------------------------------ blocks
     @property

@@ -44,7 +44,7 @@ STEP_PHASES = ("admission", "prefill", "first_token_readback", "draft",
 # host ints the engine already holds, set on the ``serving.step`` span
 STEP_COUNTS = ("admitted", "prefill_tokens", "prefills_completed",
                "active_slots", "sampling_slots", "live_kv_rows",
-               "new_tokens", "queue_depth")
+               "loop_passes", "new_tokens", "queue_depth")
 
 # admission-projection clamps: a degenerate measurement window (one
 # finish inside a denormal-small busy window, or a finish against an
@@ -225,6 +225,14 @@ class ServingMetrics:
         self._g_tp = reg.gauge("serving.tp_degree",
                                "tensor-parallel mesh degree "
                                "(1 = single chip)")
+        # KV planes a cached position spans (kv_pool.cache_geometry): a
+        # plane per layer, per pass per layer for a looped model; x
+        # 2 * kv_heads * head_dim * itemsize is a cached row's bytes.
+        # An engine-lifetime constant like the tp degree, bound outside
+        # self._own for the same reason
+        self._g_kv_planes = reg.gauge("serving.kv.planes",
+                                      "KV planes one cached position "
+                                      "spans (layers x passes)")
         # zero-cold-start surface (docs/serving.md "Zero cold start"):
         # warm-load accounting for the AOT program store.  The event
         # counters window-reset with the rest; the two gauges are
@@ -375,6 +383,9 @@ class ServingMetrics:
 
     def set_tp_degree(self, tp: int) -> None:
         self._g_tp.set(tp)
+
+    def set_kv_planes(self, planes: int) -> None:
+        self._g_kv_planes.set(planes)
 
     def on_compile(self, program: str, n: int = 1) -> None:
         self._c_compiles.inc(n)

@@ -92,6 +92,21 @@ def build_serving_mesh(tp: int, devices=None) -> Mesh:
     return Mesh(np.array(devices[:tp]), ("mp",))
 
 
+def serving_refusal(model) -> Optional[str]:
+    """Why ``model`` cannot be served over a tensor-parallel mesh at
+    all, or None.  The slot and block slabs partition on their head
+    axis (``KV_SLAB_SPEC``); a cache that keeps several planes on that
+    axis (``cfg.cache_planes_per_slab``, models/ouro.py) would be split
+    by plane, not by head, and its model has no Megatron layout here."""
+    per_slab = getattr(model.cfg, "cache_planes_per_slab", None) or 1
+    if per_slab > 1:
+        return (f"the model's cache slab holds {per_slab} KV planes on "
+                f"its head axis, which the kv-head sharding of the "
+                f"serving mesh would split by plane (no tensor-parallel "
+                f"layout for a looped cache)")
+    return None
+
+
 # --------------------------------------------------------------- layouts
 def serving_param_specs(model) -> Dict[str, P]:
     """Dotted-name -> PartitionSpec for the engine's GSPMD programs

@@ -184,6 +184,11 @@ ALLOWLIST: Dict[str, str] = {
         # control plane + sharding plumbing, not array ops; contract =
         # tests/test_zz_spec_serving.py
         "NGramDraftTable", "build_tp_verify_program",
+        # KV planes (ISSUE 28): how many planes and slabs a model's
+        # cache has, and why a looped cache cannot be kv-head sharded —
+        # pool sizing control plane, not array ops; contract =
+        # tests/test_ouro.py
+        "cache_geometry", "serving_refusal",
     )},
     # ---- paddle_tpu.obs public surface (the OBS registry surface:
     #      counters/gauges/histograms and the span tracer are telemetry

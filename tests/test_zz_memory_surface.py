@@ -138,6 +138,51 @@ def test_leg_tp2_pools_match_static_exactly(manifest):
     _check_pools_exact(manifest, eng, model, "tp2")
 
 
+def _family(name):
+    from paddle_tpu.models import (LlamaForCausalLM, OuroForCausalLM,
+                                   llama_tiny, ouro_tiny)
+    return {"gpt": lambda: GPTForCausalLM(gpt_tiny()),
+            "llama": lambda: LlamaForCausalLM(llama_tiny()),
+            "ouro": lambda: OuroForCausalLM(ouro_tiny())}[name]()
+
+
+@pytest.mark.parametrize("family, planes, model_kv_heads",
+                         [("gpt", 2, 4), ("llama", 2, 2), ("ouro", 9, 4)])
+def test_static_bytes_equal_the_live_pool_for_every_served_family(
+        manifest, family, planes, model_kv_heads):
+    """The manifest's formulas speak of the POOL's fields: its slabs
+    (``num_layers``) and the heads one slab holds (``kv_heads``).  For
+    a looped model they are 1 and planes x heads (kv_pool.
+    cache_geometry); their product is planes x the model's kv heads in
+    every family, so pool, block pool and staging stay exact."""
+    paddle_tpu.seed(0)
+    model = _family(family)
+    model.eval()
+    eng = ServingEngine(model, num_slots=NUM_SLOTS, max_seq=MAX_SEQ,
+                        min_bucket=8, block_len=BLOCK_LEN)
+    try:
+        pool, bp = eng.core.pool, eng.core.block_pool
+        assert pool.planes == planes
+        env = {**TINY_ENV, "num_layers": pool.num_layers,
+               "kv_heads": pool.ks[0].shape[2],
+               "head_dim": model.cfg.head_dim,
+               "vocab_size": model.cfg.vocab_size}
+        assert env["num_layers"] * env["kv_heads"] \
+            == planes * model_kv_heads
+        pools, plane = manifest["pools"], manifest["planes"][ENGINE_PLANE]
+        assert _measured_pool_bytes(pool) \
+            == _eval(pools[KV_POOL]["formula"], env)
+        assert _measured_block_bytes(bp) \
+            == _eval(pools[BLOCK_POOL]["formula"], env)
+        assert _measured_staging(model) \
+            == _eval(plane["staging"]["formula"], env)
+        # a cached row: 2 x planes x kv_heads x head_dim x itemsize
+        row = 2 * planes * model_kv_heads * model.cfg.head_dim * 4
+        assert _measured_staging(model) == MAX_SEQ * row
+    finally:
+        eng.close()
+
+
 def test_row_state_estimate_within_tolerance(manifest):
     """The declared row-state legs bound the measured persistent
     non-pool device state within the stated tolerance.  Static must be
