@@ -64,7 +64,7 @@ tile, which is how the grid pipeline allocates them — fits
 ``VMEM_LIMIT`` = 16 MiB, the rest is the compiler's own temporaries);
 if the irreducible residents cannot fit at ANY tile size the plan
 refuses and ``fusion_legal`` reports the reason, as it does for slab
-rows Mosaic cannot window (``_mosaic_slab_rule``) — the routed
+rows Mosaic cannot window (``decode_attention.mosaic_slab_rule``) — the routed
 fallback is the composed unfused path (see kernels/routing.py and
 docs/serving.md's fallback matrix).
 
@@ -85,23 +85,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .decode_attention import (ATTN_CHUNK, VMEM_LIMIT, mosaic_slab_rule,
+                               online_softmax_update, slab_tiles,
+                               stream_slab_attention)
+
 __all__ = ["decode_block_attn", "decode_block_mlp", "decode_block_layer",
            "decode_block_reference", "plan_decode_block", "fusion_legal",
            "decode_block_route", "resolve_fused_decode"]
 
-_NEG_INF = float("-inf")
 # default VMEM working-set budget: 16 MiB/core minus headroom for
 # Mosaic's own spills/temporaries (same posture as fused_norm's 4 MiB
 # per-block cap, scaled to a whole-layer working set)
 VMEM_BUDGET = 12 * 1024 * 1024
-# the scoped-VMEM limit every decode-block pallas_call hands Mosaic
-# explicitly: the planned working set (<= VMEM_BUDGET) plus the
-# compiler's own temporaries.  Passing it pins the limit to the same
-# value standalone and inside the engine's program (XLA's default
-# scoped limit differs between the two contexts)
-VMEM_LIMIT = 16 * 1024 * 1024
-# slab rows the attention kernel up-casts and reduces at a time
-ATTN_CHUNK = 16
+# VMEM_LIMIT (decode_attention's, shared): the scoped-VMEM limit every
+# decode-block pallas_call hands Mosaic explicitly: the planned working
+# set (<= VMEM_BUDGET) plus the compiler's own temporaries
 
 # graftmem marker (tools/analysis/memory.py): the memory-budget rule
 # re-derives this plan's per-grid-step working set through an integer
@@ -293,23 +291,6 @@ def plan_decode_block(*, max_seq: int, hidden: int, heads: int,
             "vmem_mlp": mlp_fixed + o_unit * bo + f_unit * bf}, None
 
 
-def _mosaic_slab_rule(kv_heads: int, head_dim: int):
-    """Why Mosaic cannot window this device's ``[.., KH, Dh]`` slab
-    rows, or None.  ``(KH, Dh)`` are the slab's tiled minor dims and
-    ``tpu.memref_slice`` takes whole tiles only; the compiler's words
-    are quoted so the refusal is static, never a failed dispatch (the
-    interpreted CPU kernels have no such limit)."""
-    if head_dim % 128:
-        return (f"mosaic: head_dim {head_dim} is not a multiple of the "
-                f"128-lane tile ('Slice shape along dimension 3 must be "
-                f"aligned to tiling (128), but is {head_dim}')")
-    if kv_heads % 8 and kv_heads not in (2, 4):
-        return (f"mosaic: {kv_heads} kv heads per device do not fill "
-                f"the slab's sublane tile ('Slice shape along dimension "
-                f"2 must be aligned to tiling (8), but is {kv_heads}')")
-    return None
-
-
 def fusion_legal(*, max_seq: int, hidden: int, heads: int, kv_heads: int,
                  head_dim: int, ffn: int, batch: int, dtype,
                  gated: bool = False, tp: int = 1,
@@ -336,7 +317,7 @@ def fusion_legal(*, max_seq: int, hidden: int, heads: int, kv_heads: int,
     if head_dim % 2:
         return False, f"head_dim {head_dim} must be even (rotary halves)"
     if kv_heads % tp == 0 and jax.default_backend() != "cpu":
-        why = _mosaic_slab_rule(kv_heads // tp, head_dim)
+        why = mosaic_slab_rule(kv_heads // tp, head_dim)
         if why is not None:
             return False, why
     if tp > 1:
@@ -538,75 +519,11 @@ def _slab_attn_kernel(pos_ref, q_ref, kn_ref, vn_ref, cos_ref, sin_ref,
     kw_cp.start()
     vw_cp.start()
 
-    # ---- stream the live tiles once, double-buffered; tiles wholly
-    # past the live prefix are never fetched (pos, not S, bounds the loop)
-    lim = posw                                              # valid: kpos < lim
-    nlive = jax.lax.div(lim + bk - 1, bk)
-
-    def k_cp(slot, ki):
-        return pltpu.make_async_copy(
-            k_any.at[b, pl.ds(ki * bk, bk)], kbuf.at[slot],
-            rsem.at[0, slot])
-
-    def v_cp(slot, ki):
-        return pltpu.make_async_copy(
-            v_any.at[b, pl.ds(ki * bk, bk)], vbuf.at[slot],
-            rsem.at[1, slot])
-
-    @pl.when(nlive > 0)
-    def _prefetch():
-        k_cp(0, 0).start()
-        v_cp(0, 0).start()
-
-    def _update(state, s_blk, v_blk):
-        """One online-softmax step (decode_attention's recurrence) for
-        one query head per kv head: s_blk [n, KH, 1], v_blk [n, KH, Dh];
-        the running max / sum / accumulator are [1, KH, 1|Dh]."""
-        m_prev, l_prev, acc = state
-        m_next = jnp.maximum(m_prev, jnp.max(s_blk, axis=0, keepdims=True))
-        m_safe = jnp.where(m_next == _NEG_INF, 0.0, m_next)
-        p = jnp.exp(s_blk - m_safe)
-        alpha = jnp.exp(m_prev - m_safe)
-        return (m_next,
-                alpha * l_prev + jnp.sum(p, axis=0, keepdims=True),
-                acc * alpha + jnp.sum(p * v_blk, axis=0, keepdims=True))
-
-    def _tile(ki, state):
-        slot = jax.lax.rem(ki, 2)
-
-        @pl.when(ki + 1 < nlive)
-        def _next():
-            k_cp(1 - slot, ki + 1).start()
-            v_cp(1 - slot, ki + 1).start()
-
-        k_cp(slot, ki).wait()
-        v_cp(slot, ki).wait()
-
-        def _chunk(ci, state):
-            # ck rows at a time: the f32 working copies of a whole
-            # [bk, KH, Dh] tile are not in the VMEM plan
-            r0 = pl.multiple_of(ci * ck, ck)
-            kt = kbuf[slot, pl.ds(r0, ck)].astype(jnp.float32)
-            vt = vbuf[slot, pl.ds(r0, ck)].astype(jnp.float32)
-            kpos = ki * bk + r0 + jax.lax.broadcasted_iota(
-                jnp.int32, (ck, 1, 1), 0)
-            out = []
-            for q, st in zip(qs, state):
-                s_blk = jnp.sum(kt * q, axis=-1, keepdims=True)
-                s_blk = jnp.where(kpos < lim, s_blk, _NEG_INF)
-                out.append(_update(st, s_blk, vt))
-            return tuple(out)
-
-        # chunks wholly past the live prefix are skipped too
-        live = jnp.minimum(lim - ki * bk, bk)
-        return jax.lax.fori_loop(0, jax.lax.div(live + ck - 1, ck),
-                                 _chunk, state)
-
-    kh, dh = kx.shape
-    init = tuple((jnp.full((1, kh, 1), _NEG_INF, jnp.float32),
-                  jnp.zeros((1, kh, 1), jnp.float32),
-                  jnp.zeros((1, kh, dh), jnp.float32)) for _ in qs)
-    state = jax.lax.fori_loop(0, nlive, _tile, init)
+    # ---- stream the live prefix once (decode_attention's core, shared
+    # with the unfused path's in-place kernel): valid is kpos < posw
+    state = stream_slab_attention(
+        k_any, v_any, kbuf, vbuf, rsem, b=b, heads=slice(None), qs=qs,
+        lims=[posw] * rep, n_rows=posw, bk=bk, ck=ck)
 
     # ---- the fresh token folds in last, always valid (it reads its own
     # STORED k/v so storage-dtype rounding matches the unfused path)
@@ -614,7 +531,7 @@ def _slab_attn_kernel(pos_ref, q_ref, kn_ref, vn_ref, cos_ref, sin_ref,
     vq = vnew_sc[...].astype(jnp.float32)
     for r, (q, st) in enumerate(zip(qs, state)):
         s_new = jnp.sum(kq * q, axis=-1, keepdims=True)     # [1, KH, 1]
-        _, l, acc = _update(st, s_new, vq)
+        _, l, acc = online_softmax_update(st, s_new, vq)
         attn_ref[0, r] = (acc / l)[0].astype(attn_ref.dtype)
     kw_cp.wait()
     vw_cp.wait()
@@ -646,12 +563,7 @@ def slab_decode_attention(q, k_new, v_new, k_slab, v_slab, seq_pos, *,
     pos1 = jnp.asarray(seq_pos, jnp.int32)
     if pos1.ndim == 0:
         pos1 = jnp.broadcast_to(pos1, (b,))
-    bk = min(block_k or min(1024, s_max), s_max)
-    while s_max % bk:
-        bk //= 2
-    ck = min(bk, ATTN_CHUNK)
-    while bk % ck:
-        ck //= 2
+    bk, ck = slab_tiles(s_max, block_k or 1024)
     use_rope = rope_cos is not None
     if use_rope:
         cosf = rope_cos.reshape(b, 1, dh)

@@ -16,6 +16,9 @@ table on the chip builders have now.  speedup = xla_ms / pallas_ms:
   flash_attn fwd/bwd  s1024: 0.97/0.94   s2048: 2.05/2.32
                       s4096: 2.30/2.35   s8192: 40x (dense OOM-adjacent)
   decode_attn (bk1024) kv4096: 1.06   kv8192: 0.99   kv16384: 1.00
+    (the COPYING kernel, head-major operands, every row streamed: since
+    PR 29 decode takes the in-place kernel, which reads live rows only,
+    so this row and the 6144 rule below are due a re-measure)
   fused_adamw (br8192) 8M: 1.00 (exact tie)
   layer_norm   2048x1024: 0.98  8192x4096: 0.90  32768x2048: 0.93
   rms_norm     2048x1024: 0.98  8192x4096: 0.88  32768x2048: 0.83

@@ -116,12 +116,6 @@ class LlamaAttention(Layer):
             from .kv_cache import append_kv
             k, v = append_kv(pk, pv, k, v, pos)
             new_cache = (k, v, pos + s)
-        # GQA: repeat kv heads up to q heads (XLA turns this into a
-        # broadcast inside the attention einsum — no real copy)
-        rep = cfg.num_heads // k.shape[2]
-        if rep > 1:
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
         if cache is not None:
             # routed decode attention (see gpt.py _attn): seq_lens =
             # pos + s with the causal tail IS the per-query chunked-
@@ -129,11 +123,19 @@ class LlamaAttention(Layer):
             # lens derive from the cache POSITION per row (a scalar pos
             # broadcasts; a [b] vector keeps each row's own context
             # length — ragged batches were silently wrong under the old
-            # jnp.full((b,), pos + s) which assumed uniform lengths)
+            # jnp.full((b,), pos + s) which assumed uniform lengths).
+            # GQA happens inside the kernels: the cache is never
+            # repeated up to the query heads
             from ..kernels.decode_attention import decode_attention_auto
             from .kv_cache import cache_lens
             out = decode_attention_auto(q, k, v, cache_lens(cache[2], s, b))
         else:
+            # GQA: repeat kv heads up to q heads (XLA turns this into a
+            # broadcast inside the attention einsum — no real copy)
+            rep = cfg.num_heads // k.shape[2]
+            if rep > 1:
+                k = jnp.repeat(k, rep, axis=2)
+                v = jnp.repeat(v, rep, axis=2)
             out = F.scaled_dot_product_attention(q, k, v, is_causal=True,
                                                  training=self.training)
         out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)

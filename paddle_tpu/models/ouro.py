@@ -26,7 +26,9 @@ attention calls).  A loop in the program can only reach a plane by a
 traced index, so the planes of one cache live in ONE slab, side by side
 on the head axis: ``[batch, max_len, planes * kv_heads, head_dim]``,
 plane ``p`` at heads ``[p * kv_heads, (p + 1) * kv_heads)``.  The slab
-rides the scans' carry and is appended to in place.  To the serving
+rides the scans' carry, is appended to in place, and is attended to in
+place: the decode-attention kernel windows plane ``p`` by its first
+head (``head0``, kernels/decode_attention.py).  To the serving
 engine it is an ordinary cache slab with many heads
 (``cfg.cache_planes_per_slab`` tells the pools how many planes one slab
 holds; serving/kv_pool.py).
@@ -179,12 +181,6 @@ def _append_plane(slab, new, pos, head0):
     return jax.lax.dynamic_update_slice(slab, new, (0, pos, head0, 0))
 
 
-def _read_plane(slab, head0, kv_heads: int):
-    b, max_len, _, d = slab.shape
-    return jax.lax.dynamic_slice(slab, (0, 0, head0, 0),
-                                 (b, max_len, kv_heads, d))
-
-
 class OuroModel(Layer):
     def __init__(self, cfg: OuroConfig):
         super().__init__()
@@ -256,9 +252,10 @@ class OuroModel(Layer):
                     head0 = plane_index(t, l, n) * kvh
                     slabs = (_append_plane(slabs[0], k, cpos, head0),
                              _append_plane(slabs[1], v, cpos, head0))
-                    a = decode_attention_auto(
-                        q, _read_plane(slabs[0], head0, kvh),
-                        _read_plane(slabs[1], head0, kvh), lens)
+                    # the plane is a window of the slab's head axis:
+                    # the kernel reads it where it lies
+                    a = decode_attention_auto(q, slabs[0], slabs[1], lens,
+                                              head0=head0, kv_heads=kvh)
                 return (self._finish(w, x, a), slabs), None
             return body
 

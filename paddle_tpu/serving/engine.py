@@ -203,6 +203,17 @@ def _first_token(key, logits, do_sample, temperature, top_k, top_p, mask):
     return finite_or_sentinel(logits[None], first)
 
 
+def _advance_live(seq_pos, new_pos):
+    """The positions a decode or verify program hands back: a slot
+    parked at 0 (free, or claimed and still prefilling: ``KVPool.free``
+    parks it, ``adopt`` sets a live length of at least 1) STAYS at 0.
+    Its ride-along token rewrites row 0 and attends to itself alone.
+    Left to advance, a free slot's phantom length grew a row a step and
+    the in-place attention kernel, which streams what ``seq_lens``
+    says is live, read all of it (PERF.md, PR 29)."""
+    return jnp.where(seq_pos > 0, new_pos, 0)
+
+
 def _verify_tail(logits, drafts, draft_len, keys, do_sample, temperature,
                  top_k, top_p, mask, spec_k):
     """Matched-sampling acceptance over one verify window (runs inside
@@ -576,12 +587,7 @@ class EngineCore:
             # observability parity with the traced build: the
             # decode_block event still records which path this
             # engine's single decode program runs
-            self.metrics.on_decode_block(
-                active=self.decode_path in ("fused", "tp_fused_block"),
-                reason=None if not self.fused_decode
-                else self.decode_fallback_reason,
-                step=self._step_in_flight,
-                tp=self.tensor_parallel)
+            self._emit_decode_block()
             self._decode_fn = fn
             loads += 1
         if self.spec_on:
@@ -1221,20 +1227,50 @@ class EngineCore:
                                           kv_len=self.pool.max_seq)
         return ("fused", None) if ok else ("unfused", reason)
 
-    def _build_decode_fn(self) -> Callable:
-        model = self.model
-        fused = self.decode_path == "fused"
-        # the discrete obs event marks WHICH path this engine's single
-        # decode program compiled with (and why, on fallback) — traces
-        # distinguish fused from unfused steps without diffing configs;
-        # the tp dimension separates the sharded block from the tp=1
-        # pair in a shared registry (glossary: docs/observability.md)
+    def attention_route(self):
+        """``(route, reason)`` of the decode program's attention
+        (``kernels.decode_attention.decode_attention_route``): static
+        per compiled program, a function of the slot slabs' shape and
+        dtype as the program's trace sees them — each device's heads
+        inside the ``tp_fused`` shard_map, the whole slab under the
+        serving mesh in the composed GSPMD program (where XLA cannot
+        partition a Mosaic call).  The fused blocks stream the slab in
+        place by construction."""
+        from ..kernels.decode_attention import decode_attention_route
+        if self.decode_path in ("fused", "tp_fused_block"):
+            return "slab_in_place", None
+        cfg = self.model.cfg
+        slots, rows, slab_heads, dh = self.pool.ks[0].shape
+        manual = self.decode_path == "tp_fused"
+        tp = self.tensor_parallel if manual else 1
+        kv_heads = getattr(cfg, "kv_heads", None) or cfg.num_heads
+        with contextlib.nullcontext() if manual else self._mesh_scope():
+            return decode_attention_route(
+                (slots, 1, cfg.num_heads // tp, dh),
+                (slots, rows, slab_heads // tp, dh),
+                self.pool.ks[0].dtype, kv_heads // tp)
+
+    def _emit_decode_block(self) -> None:
+        """The discrete obs event that marks WHICH path this engine's
+        single decode program compiled with (and why, on fallback) —
+        traces distinguish fused from unfused steps without diffing
+        configs; the tp dimension separates the sharded block from the
+        tp=1 pair in a shared registry; the attention route says
+        whether the program reads the slot slabs where they lie
+        (glossary: docs/observability.md)."""
+        route, why = self.attention_route()
         self.metrics.on_decode_block(
             active=self.decode_path in ("fused", "tp_fused_block"),
             reason=None if not self.fused_decode
             else self.decode_fallback_reason,
             step=self._step_in_flight,
-            tp=self.tensor_parallel)
+            tp=self.tensor_parallel,
+            attention_route=route, attention_reason=why)
+
+    def _build_decode_fn(self) -> Callable:
+        model = self.model
+        fused = self.decode_path == "fused"
+        self._emit_decode_block()
         if self.decode_path in ("tp_fused", "tp_fused_block"):
             return self._build_tp_decode_fn()
 
@@ -1258,8 +1294,8 @@ class EngineCore:
             nxt = finite_or_sentinel(logits[:, 0], nxt)
             new_ks = [c[0] for c in caches]
             new_vs = [c[1] for c in caches]
-            return (new_ks, new_vs, caches[0][2], nxt.astype(jnp.int32),
-                    split[:, 0])
+            return (new_ks, new_vs, _advance_live(seq_pos, caches[0][2]),
+                    nxt.astype(jnp.int32), split[:, 0])
 
         # donating the KV slabs aliases them in place — pool memory stays
         # a single allocation across the whole serving run
@@ -1301,8 +1337,8 @@ class EngineCore:
             nxt = sample_rows(split[:, 1], lg, do_sample,
                               temperature, top_k, top_p, mask=mask)
             nxt = finite_or_sentinel(lg, nxt)
-            return (new_ks, new_vs, new_pos, nxt.astype(jnp.int32),
-                    split[:, 0])
+            return (new_ks, new_vs, _advance_live(seq_pos, new_pos),
+                    nxt.astype(jnp.int32), split[:, 0])
 
         return functools.partial(
             jax.jit(decode, donate_argnums=(1, 2)), weights)
@@ -1376,7 +1412,7 @@ class EngineCore:
                 committed, accepted[:, None], axis=1)[:, 0]
             # the caches advanced the full window width — the ragged
             # truth is accepted+1, which also re-hides rejected KV
-            new_pos = seq_pos + accepted + 1
+            new_pos = _advance_live(seq_pos, seq_pos + accepted + 1)
             packed = jnp.concatenate([committed, accepted[:, None]],
                                      axis=1)
             new_ks = [c[0] for c in caches]
@@ -1416,7 +1452,7 @@ class EngineCore:
                 top_k, top_p, mask, self.spec_k)
             new_last = jnp.take_along_axis(
                 committed, accepted[:, None], axis=1)[:, 0]
-            new_pos = seq_pos + accepted + 1
+            new_pos = _advance_live(seq_pos, seq_pos + accepted + 1)
             packed = jnp.concatenate([committed, accepted[:, None]],
                                      axis=1)
             return (new_ks, new_vs, new_pos,

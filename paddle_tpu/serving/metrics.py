@@ -334,7 +334,9 @@ class ServingMetrics:
         self._h_gather.observe(seconds)
 
     def on_decode_block(self, active: bool, reason: Optional[str],
-                        step: int = 0, tp: int = 1) -> None:
+                        step: int = 0, tp: int = 1,
+                        attention_route: str = "",
+                        attention_reason: Optional[str] = None) -> None:
         """The engine resolved its decode path (emitted once, when the
         single decode program is built): ``active`` says whether the
         fused decode-block kernels compiled in, ``reason`` carries the
@@ -342,13 +344,19 @@ class ServingMetrics:
         legality refused (None when fused engaged or the flag was off),
         and ``tp`` records the mesh degree — ``active`` at ``tp > 1``
         means the SHARDED block (kernels/decode_block_tp.py), so traces
-        from a shared registry separate the two fused variants.  Lands
+        from a shared registry separate the two fused variants.
+        ``attention_route`` is how that program's attention reaches the
+        KV slabs (``slab_in_place`` / ``head_major_copy`` /
+        ``xla_dense``: kernels/decode_attention.py) and
+        ``attention_reason`` why it is not ``slab_in_place``.  Lands
         as a ``decode_block`` discrete event on the engine lane
         (glossary: docs/observability.md)."""
         self.tracer.event("decode_block", lane=self.engine_lane,
                           active=active,
                           reason=reason if reason is not None else "",
-                          step=step, tp=tp)
+                          step=step, tp=tp,
+                          attention_route=attention_route,
+                          attention_reason=attention_reason or "")
 
     def on_aot_load(self, programs: int, seconds: float,
                     build_s: Optional[float] = None) -> None:

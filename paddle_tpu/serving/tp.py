@@ -295,10 +295,8 @@ def _tp_layer(x_s, pk, pv, seq_pos, blk, arch, rope, axis, tp, overlap,
         k = apply_rotary_pos_emb(k, cos, sin)
     k_buf, v_buf = append_kv(pk, pv, k, v, seq_pos)
     lens = cache_lens(seq_pos, s, b)
-    rep = h_l // kh_l
-    kk = jnp.repeat(k_buf, rep, axis=2) if rep > 1 else k_buf
-    vv = jnp.repeat(v_buf, rep, axis=2) if rep > 1 else v_buf
-    attn = decode_attention_auto(q, kk, vv, lens)       # [B, s, h_l, dh]
+    # GQA inside the kernel: this device's kv heads, never repeated
+    attn = decode_attention_auto(q, k_buf, v_buf, lens)  # [B, s, h_l, dh]
     attn = attn.reshape(rows, h_l * dh)
     # ---- exit: out-proj dot with the reduce-scatter riding it
     o = matmul_reduce_scatter(attn, blk["wo"], axis, tp, overlap=overlap)

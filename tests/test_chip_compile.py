@@ -78,16 +78,75 @@ def test_flash_attention_fwd_bwd(topo, varlen):
         _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
 
 
-@pytest.mark.parametrize("sq", [1, 64])
-def test_decode_attention(topo, sq):
-    """One decode token, and a 64-token prefill chunk, against the slot
-    slabs."""
-    from paddle_tpu.kernels.decode_attention import decode_attention
+@pytest.mark.parametrize("slots,sq,rows,kv_heads,slab_heads,route", [
+    (16, 1, S, H, H, "slab_in_place"),          # gpt3-6.7b.serve-chat
+    (8, 1, 512, 16, 192 * 16, "slab_in_place"),  # one of Ouro-2.6B's planes
+    (8, 1, S, 8, 8, "slab_in_place"),           # GQA 32:8, cache not repeated
+    (8, 4, S, H, H, "slab_in_place"),           # a speculative verify window
+    (1, 64, S, H, H, "head_major_copy"),        # a 64-token prefill chunk
+], ids=["serve_chat", "ouro_plane", "gqa", "verify", "prefill_chunk"])
+def test_decode_attention(topo, slots, sq, rows, kv_heads, slab_heads,
+                          route):
+    """Decode against the slot slabs where they lie (a plane by its
+    TRACED first head), and a prefill chunk on the copying kernel: the
+    kernel each shape takes on the chip, compiled by Mosaic."""
+    import importlib
+    da = importlib.import_module("paddle_tpu.kernels.decode_attention")
     s = _one(topo)
-    b = B if sq == 1 else 1
-    _compile(functools.partial(decode_attention, interpret=False),
-             s((b, sq, H, DH)), s((b, S, H, DH)), s((b, S, H, DH)),
-             s((b,), jnp.int32))
+    heads = H if kv_heads != 16 else 16
+    q, slab = s((slots, sq, heads, DH)), s((slots, rows, slab_heads, DH))
+    assert da.pallas_attention_route(q.shape, slab.shape, BF16,
+                                     kv_heads)[0] == route
+
+    def attn(q, k, v, lens, head0):
+        return da.decode_attention(q, k, v, lens, interpret=False,
+                                   head0=head0, kv_heads=kv_heads)
+
+    mem = _compile(attn, q, slab, slab, s((slots,), jnp.int32),
+                   s((), jnp.int32))
+    if route == "slab_in_place":
+        plane = slots * rows * kv_heads * DH * 2
+        assert mem.temp_size_in_bytes < plane, (
+            f"temp {mem.temp_size_in_bytes} B holds a copy of a plane "
+            f"({plane} B)")
+
+
+def test_looped_decode_step_moves_no_plane(topo, monkeypatch):
+    """Ouro-2.6B's whole decode step (48 layers x 4 passes, 192 planes
+    in ONE slab per K and V, 8 slots x 512 rows: the benchmark's cell)
+    with the chip's routes: the scans append to the 3.2 GB slabs in
+    place and the kernel windows each plane out of them, so ``temp``
+    holds no plane (16.8 MB), let alone a slab (PR 28 read 3.2 GB from
+    one wrong form of the append, 1.2 MB from the right one)."""
+    from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
+    from paddle_tpu.nn.functional_call import bind_state, state
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = OuroConfig(max_seq_len=512, dtype="bfloat16")
+    made = []
+
+    def make():
+        made.append(OuroForCausalLM(cfg).to(dtype=cfg.dtype))
+        return state(made[0])
+
+    s = _one(topo)
+    params, buffers = jax.tree.map(lambda x: s(x.shape, x.dtype),
+                                   jax.eval_shape(make))
+    model = made[0]
+    slots, rows = 8, 512
+    slab = s((slots, rows, cfg.num_cache_layers * cfg.kv_heads,
+              cfg.head_dim))
+
+    def decode(params, k, v, pos, tok):
+        with bind_state(model, params, buffers):
+            logits, caches = model.decode_step(tok[:, None],
+                                               [(k, v, pos)], pos)
+        return jnp.argmax(logits[:, 0], -1), caches[0][0], caches[0][1]
+
+    mem = _compile(decode, params, slab, slab, s((slots,), jnp.int32),
+                   s((slots,), jnp.int32), donate_argnums=(1, 2))
+    plane = slots * rows * cfg.kv_heads * cfg.head_dim * 2
+    assert mem.temp_size_in_bytes < plane // 2, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= 2 * plane * cfg.num_cache_layers
 
 
 @pytest.mark.parametrize("kind", ["rms", "layer"])

@@ -1,0 +1,124 @@
+"""The decode program reads the KV slabs where they lie.
+
+The copy this guards against cost half of a decode step (PERF.md,
+PR 29): ``decode_attention`` re-laid every slot slab head-major ahead of
+its kernel, the looped model sliced each plane out of its slab first,
+and a GQA model repeated the cache up to its query heads.  A number
+cannot be had here, but the PROGRAM can: each case lowers the engine's
+own decode program for the chip (the routes are the chip's; nothing is
+compiled or run) at a tiny depth and the published head size, and looks
+through its StableHLO for any data-movement operation whose result is
+as large as a slab, a plane, or a plane repeated up to the query heads.
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+SLOTS, ROWS, DH = 4, 48, 128
+_MOVES = re.compile(
+    r"stablehlo\.(transpose|copy|dynamic_slice|broadcast\w*|concatenate)\b"
+    r".*->\s*tensor<([0-9x]+)x\w+>")
+
+
+def _gpt(heads, head_dim):
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    return GPTForCausalLM(GPTConfig(
+        vocab_size=96, hidden_size=heads * head_dim, num_layers=2,
+        num_heads=heads, max_seq_len=ROWS, ffn_mult=1, remat=False))
+
+
+def _llama_gqa():
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    return LlamaForCausalLM(LlamaConfig(
+        vocab_size=96, hidden_size=8 * DH, intermediate_size=64,
+        num_layers=2, num_heads=8, num_kv_heads=2, max_seq_len=ROWS))
+
+
+def _ouro():
+    from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
+    return OuroForCausalLM(OuroConfig(
+        vocab_size=96, hidden_size=8 * DH, intermediate_size=64,
+        num_layers=2, num_heads=8, max_seq_len=ROWS, total_ut_steps=2))
+
+
+def _decode_stablehlo(model):
+    """StableHLO of the engine's ONE decode program, lowered for the
+    TPU with its own operands (the AOT builder's list)."""
+    from paddle_tpu.serving import ServingEngine
+    core = ServingEngine(model, num_slots=SLOTS, min_bucket=8,
+                         max_seq=ROWS).core
+    program = core._build_decode_fn()
+    n, vocab = core.num_slots, int(model.cfg.vocab_size)
+    args = (core.pool.ks, core.pool.vs, core.pool.seq_pos,
+            jnp.zeros((n,), jnp.int32),
+            jnp.tile(jax.random.PRNGKey(0)[None], (n, 1)),
+            jnp.zeros((n,), bool), jnp.ones((n,), jnp.float32),
+            jnp.zeros((n,), jnp.int32), jnp.ones((n,), jnp.float32),
+            jnp.ones((n, vocab), bool))
+    text = program.func.trace(*program.args, *args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return core, text
+
+
+def _slab_sized_moves(text, sizes):
+    found = []
+    for line in text.splitlines():
+        m = _MOVES.search(line)
+        if m and int(np.prod([int(d) for d in m.group(2).split("x")])) \
+                in sizes:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("make,route,copies", [
+    (lambda: _gpt(8, DH), "slab_in_place", False),
+    (_ouro, "slab_in_place", False),
+    (_llama_gqa, "slab_in_place", False),
+    # the guard has teeth: 16 x 64 is a slab Mosaic cannot window, the
+    # copying kernel serves it, and its transposes are found
+    (lambda: _gpt(16, 64), "head_major_copy", True),
+], ids=["gpt3_shaped", "ouro_shaped", "llama_gqa", "gpt_h64_copies"])
+def test_decode_program_moves_no_slab(make, route, copies, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = make()
+    core, text = _decode_stablehlo(model)
+    assert core.attention_route()[0] == route, core.attention_route()
+    cfg = model.cfg
+    slab = core.pool.ks[0]
+    kv_heads = getattr(cfg, "kv_heads", None) or cfg.num_heads
+    plane = slab.size // slab.shape[2] * kv_heads
+    sizes = {slab.size, plane, plane * (cfg.num_heads // kv_heads)}
+    found = _slab_sized_moves(text, sizes)
+    assert text.count("tpu_custom_call") >= 1
+    if copies:
+        assert any("transpose" in line for line in found), found
+    else:
+        assert not found, "\n".join(found)
+
+
+@pytest.mark.parametrize("spec_k", [0, 2], ids=["decode", "verify"])
+def test_free_slots_stay_parked_at_row_zero(spec_k):
+    """The in-place kernel streams the rows ``seq_lens`` calls live, so
+    a free slot must not grow a phantom length while the others decode
+    (it rode along a row a step, and the kernel read all of it)."""
+    from paddle_tpu.models import GPTForCausalLM, gpt_tiny
+    from paddle_tpu.serving import ServingEngine
+    model = GPTForCausalLM(gpt_tiny())
+    eng = ServingEngine(model, num_slots=3, min_bucket=8, max_seq=64,
+                        spec_k=spec_k)
+    prompt = np.tile([5, 6, 7, 8], 3)
+    short = eng.submit(prompt, max_new_tokens=2)
+    long_ = eng.submit(prompt[:9], max_new_tokens=12)
+    while eng.step():
+        pos = np.asarray(eng.core.pool.seq_pos)
+        live = set(eng.core._slots)
+        assert all(pos[s] == 0 for s in range(3) if s not in live), pos
+    want = np.asarray(model.generate(prompt[None, :9],
+                                     max_new_tokens=12))[0, 9:]
+    assert list(eng.result(long_).tokens) == list(want)
+    assert eng.result(short).finished

@@ -293,6 +293,79 @@ def test_decode_attention_matches_model_cache_semantics():
                                rtol=2e-5, atol=2e-5)
 
 
+def _da():
+    # ``paddle_tpu.kernels.decode_attention`` names the function the
+    # package exports; the module is reached by its path
+    import importlib
+    return importlib.import_module("paddle_tpu.kernels.decode_attention")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("planes,plane", [(1, 0), (3, 1)])
+@pytest.mark.parametrize("sq", [1, 4])
+@pytest.mark.parametrize("h,kh", [(32, 32), (32, 8), (8, 2)])
+def test_decode_attention_reads_the_slab_in_place(h, kh, sq, planes, plane,
+                                                  dtype):
+    """The in-place kernel against the dense reference: MHA and GQA (the
+    cache never repeated), decode and a causal verify window, ragged
+    lengths from an empty slot to a full one, the whole slab and a middle
+    plane of a many-plane slab reached by a TRACED first head.  A plane
+    of 2 heads is no whole sublane tile: that window keeps the copying
+    kernel, and agrees too."""
+    da = _da()
+    rs = np.random.RandomState(hash((h, kh, sq, planes)) % 2**31)
+    b, s_max, d = 4, 64, 128
+    dt = jnp.dtype(dtype)
+    q = jnp.asarray(rs.randn(b, sq, h, d) * 0.5, dt)
+    kc = jnp.asarray(rs.randn(b, s_max, planes * kh, d) * 0.5, dt)
+    vc = jnp.asarray(rs.randn(b, s_max, planes * kh, d) * 0.5, dt)
+    lens = jnp.asarray([0, sq, 37, s_max], jnp.int32)
+    route, why = da.pallas_attention_route(q.shape, kc.shape, dt, kh)
+    if planes > 1 and kh % 8:
+        assert route == "head_major_copy" and "sublane" in why
+    else:
+        assert (route, why) == ("slab_in_place", None)
+    out = jax.jit(lambda *a: da.decode_attention(
+        *a[:4], block_k=32, interpret=True, head0=a[4], kv_heads=kh))(
+        q, kc, vc, lens, jnp.asarray(plane * kh, jnp.int32))
+    assert out.shape == q.shape and out.dtype == q.dtype
+    f32 = lambda x: x.astype(jnp.float32)
+    ref = da.decode_attention_reference(f32(q), f32(kc), f32(vc), lens,
+                                        head0=plane * kh, kv_heads=kh)
+    tol = 2e-5 if dtype == "float32" else 1e-2
+    np.testing.assert_allclose(np.asarray(f32(out)), np.asarray(ref),
+                               rtol=tol, atol=tol)
+    assert not np.asarray(f32(out))[0].any()        # an empty slot reads 0
+
+
+@pytest.mark.parametrize("sq,h,kh,d,why", [
+    (1, 12, 12, 64, "head_dim 64"),         # h768: 12 x 64
+    (1, 12, 12, 128, "12 kv heads"),
+    (16, 32, 32, 128, "16 query tokens"),   # a prefill chunk: MXU work
+    (8, 32, 8, 128, "past the"),            # 8 tokens x 4 query heads
+])
+def test_decode_attention_keeps_the_copying_kernel(sq, h, kh, d, why):
+    """What the in-place kernel does not take, by shape and with the
+    reason in words; the head-major kernel serves it (GQA as rows of one
+    program, the cache not repeated) and agrees with the reference."""
+    da = _da()
+    rs = np.random.RandomState(sq + h)
+    b, s_max = 2, 64
+    q = jnp.asarray(rs.randn(b, sq, h, d).astype(np.float32) * 0.5)
+    kc = jnp.asarray(rs.randn(b, s_max, kh, d).astype(np.float32) * 0.5)
+    vc = jnp.asarray(rs.randn(b, s_max, kh, d).astype(np.float32) * 0.5)
+    lens = jnp.asarray([sq + 3, s_max], jnp.int32)
+    route, reason = da.pallas_attention_route(q.shape, kc.shape, kc.dtype)
+    assert route == "head_major_copy" and why in reason, reason
+    out = da.decode_attention(q, kc, vc, lens, block_k=32, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), _decode_ref(q, kc, vc, np.asarray(lens)),
+        rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(da.decode_attention_reference(q, kc, vc, lens)),
+        _decode_ref(q, kc, vc, np.asarray(lens)), rtol=2e-5, atol=2e-5)
+
+
 def test_flash_attention_varlen_matches_dense_mask():
     """Segment-masked kernel == dense same-segment masking (packed varlen),
     fwd and grads."""

@@ -344,6 +344,9 @@ def test_obs_event_and_histogram_mark_fused_path(gpt):
     assert len(evs) == 1
     attrs = evs[0][3]
     assert attrs["active"] is True and attrs["reason"] == ""
+    # the fused block streams the slab in place by construction
+    assert attrs["attention_route"] == "slab_in_place"
+    assert attrs["attention_reason"] == ""
     # the fused dispatch is timed by the decode phase's histogram, the
     # event says which path that was
     assert eng.registry.get("serving.phase.decode_dispatch_s").count > 0
@@ -351,6 +354,12 @@ def test_obs_event_and_histogram_mark_fused_path(gpt):
     toks2, eng2 = _serve(gpt, False, False)
     evs2 = eng2.core.metrics.tracer.events("decode_block")
     assert len(evs2) == 1 and evs2[0][3]["active"] is False
+    # gpt_tiny's 4 x 16 slab rows are no window Mosaic can address: the
+    # route and its reason (the compiler's words) ride the same event
+    assert evs2[0][3]["attention_route"] == "head_major_copy"
+    assert "head_dim 16" in evs2[0][3]["attention_reason"]
+    assert eng2.core.attention_route() == (
+        "head_major_copy", evs2[0][3]["attention_reason"])
     assert eng2.registry.get("serving.phase.decode_dispatch_s").count > 0
 
 
