@@ -213,7 +213,7 @@ def argmax_gap(ref, tokens) -> float:
 # ---------------------------------------------------------------------- train
 
 def train_phase(cfg, batch: int, steps: int = 4, lr: float = 3e-4) -> dict:
-    """A few steps of the single-chip training program bench.py times:
+    """A few steps of a single-chip training program:
     GPTForCausalLM + AdamW(f32 masters when bf16) + functional_call +
     parallel_cross_entropy in ONE donated jit step, on a fixed seeded
     batch.  Loss must be finite and lower at the end."""

@@ -29,7 +29,7 @@ _POLICY_NAMES = ("dots_saveable", "nothing_saveable",
 
 def resolve_remat_policy(name: str):
     """jax.checkpoint_policies entry for ``name`` — the ONE resolver for
-    every remat knob (model configs, Engine strategy, bench).  Unknown
+    every remat knob (model configs, Engine strategy).  Unknown
     names raise with the known list (silent fallback to full checkpoint
     would invalidate memory/perf comparisons)."""
     # allowlist, not getattr: jax.checkpoint_policies also exposes
@@ -44,8 +44,9 @@ def resolve_remat_policy(name: str):
 
 
 def remat_from_env(var: str = "BENCH_REMAT", default: str = "0"):
-    """Shared env parsing for the bench entry points: '0' -> False,
-    '1' -> True (full checkpoint), anything else -> policy name."""
+    """Env parsing of a remat knob: '0' -> False, '1' -> True (full
+    checkpoint), anything else -> policy name.  No caller in the tree
+    (ROADMAP D13)."""
     import os
     v = os.environ.get(var, default)
     return True if v == "1" else (False if v == "0" else v)

@@ -26,8 +26,8 @@ the two TP boundaries of a transformer layer:
 
 Both take ``overlap=False`` to run the textbook serialized form
 (``all_gather``/``psum_scatter`` around one big dot) — that is the
-baseline of the bench's overlapped-vs-serialized compare row, and the
-parity oracle for the ring decomposition.
+parity oracle for the ring decomposition
+(``tests/test_zz_tp_serving.py::test_collective_matmul_parity``).
 
 These are shard_map-body functions: they MUST run inside a shard_map
 binding ``axis_name`` (serving/tp.py owns that program).  ``tp`` is the
@@ -112,8 +112,8 @@ def allgather_matmul(x, w, axis_name: str, tp: int, *,
     local columns.  ``overlap=True`` runs the ring decomposition (one
     ``[B_local, K] @ [K, N_local]`` dot per hop, ppermute in flight);
     ``overlap=False`` runs ``all_gather -> dot`` (the serialized
-    baseline, bit-identical contraction per row in both forms — each
-    row's dot contracts the full K locally either way)."""
+    baseline — each row's dot contracts the full K locally either way;
+    a backend may round a ``[B_local, K]`` and a ``[B, K]`` dot apart)."""
     if tp == 1:
         return x @ w
     if not overlap:

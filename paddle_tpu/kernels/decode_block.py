@@ -297,8 +297,8 @@ def fusion_legal(*, max_seq: int, hidden: int, heads: int, kv_heads: int,
                  vmem_budget: int = VMEM_BUDGET):
     """Static legality of the fused decode block for this shape/dtype.
     Returns ``(ok, reason)``; ``reason`` names the first failing check —
-    the engine surfaces it in the ``decode_block`` obs event and bench
-    rows report it as the fallback cause.
+    the engine surfaces it in the ``decode_block`` obs event as the
+    fallback cause.
 
     ``tp > 1`` checks the SHARDED variant (``decode_block_tp``): the
     kv-head axis must tile the mesh (the slabs shard on it, so each
@@ -379,10 +379,10 @@ def resolve_fused_decode(model, *, batch: int, kv_len: int, tp: int = 1):
     legality (the model's ``fused_decode_supported`` ->
     :func:`fusion_legal(tp=...)`, which under tp > 1 checks the
     per-shard plan: kv_heads/batch/ffn tiling and the ring working
-    set).  Shared by ``engine._resolve_decode_path`` and bench's
-    ``decode_path_info`` so the fallback matrix lives in exactly one
-    place.  Returns ``(ok, reason)``; ``reason`` is None when the
-    fused path may engage."""
+    set).  ``engine._resolve_decode_path`` calls it, so the fallback
+    matrix lives in exactly one place.
+    Returns ``(ok, reason)``; ``reason`` is None when the fused path
+    may engage."""
     supported = getattr(model, "fused_decode_supported", None)
     if supported is None:
         return False, "model has no fused_decode_step"
@@ -823,7 +823,7 @@ def decode_block_layer(x, k_slab, v_slab, seq_pos, *, kv_heads, head_dim,
     Tiles come from :func:`plan_decode_block` at THIS call's shapes —
     the budgeted tiles, not the kernels' untiled defaults — so every
     caller of the layer wrapper (models' ``fused_decode_step``, the
-    engine's decode program, bench) launches exactly the working set
+    engine's decode program) launches exactly the working set
     the legality check approved; ``block_k``/``block_f`` override the
     plan's choice.  Raises if no tiling fits: callers are contracted
     to gate on :func:`fusion_legal` / ``fused_decode_supported``

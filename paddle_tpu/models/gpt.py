@@ -16,7 +16,7 @@ TPU-native design:
     fused_multi_transformer equivalent is one jitted decode step whose ops
     XLA fuses; a Pallas fused-block variant lives in paddle_tpu/kernels;
   * ``gpt_train_step_builder`` builds the full dp×mp×pp×sp jitted train
-    step used by __graft_entry__.dryrun_multichip and bench.py.
+    step used by __graft_entry__.dryrun_multichip.
 """
 
 from __future__ import annotations

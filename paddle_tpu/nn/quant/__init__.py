@@ -86,8 +86,8 @@ def weight_only_linear(x, weight, bias=None, weight_scale=None,
     (``sum_i x_i * (q_ij * s_j) == (sum_i x_i * q_ij) * s_j``) and is
     what makes int8 decode actually beat fp: dequantize-then-matmul
     rebuilds the full [in, out] float weight every step — an O(in*out)
-    multiply XLA does NOT reliably sink into the dot, which made the
-    bench's gpt_decode_int8 row SLOWER than fp (0.87x, a CPU run).
+    multiply XLA does NOT reliably sink into the dot, which measured
+    int8 decode SLOWER than fp (0.87x, a CPU run).
     After the dot the rescale is O(out) per row."""
     if weight_dtype == "int4":
         w_int = _unpack_int4(weight).astype(x.dtype)

@@ -1,8 +1,8 @@
 """Production telemetry: metrics registry + request-lifecycle tracing.
 
 ``paddle_tpu.obs`` is the observability layer the serving engine
-(serving/metrics.py wires it in), the hapi training loop, and bench.py
-record into:
+(serving/metrics.py wires it in) and the hapi training loop record
+into:
 
   * :class:`MetricsRegistry` — counters, gauges, log-bucketed
     :class:`Histogram` instruments with p50/p90/p99 quantile estimation,

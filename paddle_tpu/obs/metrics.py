@@ -1,7 +1,7 @@
 """Metrics registry: counters, gauges, log-bucketed histograms.
 
-The process-local instrument store the serving engine, the hapi training
-loop, and bench.py all record into.  Three design rules, enforced by
+The process-local instrument store the serving engine and the hapi
+training loop record into.  Three design rules, enforced by
 tests/test_observability.py:
 
   * **pure host** — this module never imports jax and never touches a

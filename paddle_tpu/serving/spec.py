@@ -18,8 +18,8 @@ Chained greedy lookup: ``propose`` walks the table token by token —
 the trigram successor of the last two committed tokens when one was
 recorded, the bigram successor of the last token otherwise — feeding
 each prediction back in as context, so one table hit can draft a whole
-``spec_k`` window (the shared-prefix chat workloads the bench models
-are exactly the repetitive-suffix traffic this wins on).  Most-recent
+``spec_k`` window (shared-prefix chat workloads are exactly the
+repetitive-suffix traffic this wins on).  Most-recent
 occurrence wins on conflict: recency tracks the request's local
 phrasing better than frequency for the short horizons involved.
 

@@ -3,7 +3,7 @@
 Hot paths (configurable; defaults below) are where a blocking transfer
 stalls the accelerator pipeline: Pallas kernel modules, the trainer's
 step builders, the pipeline-schedule scan bodies, the serving step loop,
-and the bench/entry harness drivers.  Within them the checker flags:
+and the entry/script harness drivers.  Within them the checker flags:
 
   * ``.item()`` / ``.tolist()`` — synchronous readback;
   * ``.block_until_ready()`` — an explicit barrier (benchmarks belong in
@@ -47,7 +47,6 @@ DEFAULT_HOT_PATHS = (
     # perf-critical entrypoints: their jitted step/generate bodies must
     # stay sync-free too (harness-level readbacks around them are host
     # code and stay legal; intentional in-body syncs carry suppressions)
-    "bench.py",
     "__graft_entry__.py",
     "scripts/*.py",
 )

@@ -13,7 +13,7 @@ all import-free (the analysis only ever reads source):
 
     A name may be a function (that function roots the walk) or a class
     (every method roots the walk).  This is the form the serving stack
-    uses (serving/engine.py, serving/tp.py, bench.py): zero imports,
+    uses (serving/engine.py, serving/tp.py, serving/aot.py): zero imports,
     zero runtime cost, provably no behavior change.
 
   * **decorator marker** — ``@compile_surface_root`` (a no-op identity

@@ -71,8 +71,7 @@ CAPACITY_DUNDER = "__memory_capacity_fields__"
 VMEM_PLANS_DUNDER = "__vmem_plans__"
 MEMORY_BYTES_DUNDER = "__memory_bytes__"
 
-# per-chip HBM for the max-resident-blocks ladder (device generations
-# the bench's HBM_BW_BY_GEN already names)
+# per-chip HBM for the max-resident-blocks ladder
 CHIP_HBM_BYTES = {
     "v4": 32 * 1024**3,
     "v5e": 16 * 1024**3,
@@ -383,9 +382,9 @@ PLAN_MIRRORS = {
     "plan_decode_block_tp": mirror_plan_decode_block_tp,
 }
 
-# the reference configuration the capacity manifest is evaluated at:
-# the bench's flagship decode shape (bench.py FLAGSHIP_DECODE) with the
-# engine's default block ladder (num_blocks = num_slots * max_seq /
+# the reference configuration the capacity manifest is evaluated at
+# (the analyser's own constant: a GPT-2-small-width decode shape) with
+# the engine's default block ladder (num_blocks = num_slots * max_seq /
 # block_len)
 REFERENCE_ENV: Dict[str, int] = {
     "vocab_size": 32768, "hidden": 768, "num_heads": 12, "kv_heads": 12,

@@ -10,7 +10,8 @@ holds:
 
   * **module resolution** — every scanned file gets a dotted module name
     relative to the scan root (``paddle_tpu/serving/engine.py`` ->
-    ``paddle_tpu.serving.engine``; ``bench.py`` -> ``bench``), and both
+    ``paddle_tpu.serving.engine``; ``__graft_entry__.py`` ->
+    ``__graft_entry__``), and both
     absolute and relative imports resolve to those names;
   * **symbol tables** — top-level functions, classes and their methods,
     plus module-level ``g = f`` aliases;

@@ -19,7 +19,7 @@ Usage:
                                                   # seam manifest (JSON)
 
 Default scope is the library AND the perf-critical entrypoints:
-``paddle_tpu/``, ``bench.py``, ``__graft_entry__.py``, ``scripts/``.
+``paddle_tpu/``, ``__graft_entry__.py``, ``scripts/``.
 With ``--changed``/``--since`` the whole default scope is still PARSED
 (the project index needs it — interprocedural rules resolve cross-file),
 but only the changed files are linted; the on-disk parse cache under
@@ -37,7 +37,7 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # the library plus every perf-critical entrypoint the gate covers
-DEFAULT_SCOPE = ("paddle_tpu", "bench.py", "__graft_entry__.py", "scripts")
+DEFAULT_SCOPE = ("paddle_tpu", "__graft_entry__.py", "scripts")
 CACHE_PATH = os.path.join(ROOT, ".graftlint_cache", "parse.pkl")
 
 

@@ -6,7 +6,7 @@ files, two miniature registry trees, and two-module packages for the
 cross-module axis-name resolution.  The gate test at the bottom is the
 contract ISSUE 1 pins (and ISSUE 4 widens): zero unsuppressed findings
 over the default scan scope — ``paddle_tpu/`` plus the perf-critical
-entrypoints (``bench.py``, ``__graft_entry__.py``, ``scripts/``).
+entrypoints (``__graft_entry__.py``, ``scripts/``).
 """
 
 import ast
@@ -27,8 +27,7 @@ REPO_ROOT = Path(__file__).resolve().parents[1]
 LINT = REPO_ROOT / "tests" / "fixtures" / "lint"
 # keep in sync with scripts/graftlint.py DEFAULT_SCOPE
 GATE_SCOPE = [str(REPO_ROOT / p)
-              for p in ("paddle_tpu", "bench.py", "__graft_entry__.py",
-                        "scripts")]
+              for p in ("paddle_tpu", "__graft_entry__.py", "scripts")]
 
 
 def run_rule(filename, rule):
@@ -154,7 +153,6 @@ def test_serving_package_is_a_default_hot_path():
     assert "paddle_tpu/serving/*.py" in DEFAULT_HOT_PATHS
     assert any(fnmatch.fnmatch("paddle_tpu/serving/prefix_cache.py", p)
                for p in DEFAULT_HOT_PATHS)
-    assert "bench.py" in DEFAULT_HOT_PATHS
     assert "__graft_entry__.py" in DEFAULT_HOT_PATHS
 
 
@@ -656,7 +654,8 @@ def test_project_index_import_and_call_resolution():
     helper = proj.modules["pkg.mod_b"].classes["C"].methods["helper"]
     assert [c.qname for c in proj.callees(helper)] == ["pkg.mod_a.f"]
     assert module_name_for("pkg/__init__.py") == ("pkg", True)
-    assert module_name_for("bench.py") == ("bench", False)
+    assert module_name_for("__graft_entry__.py") == (
+        "__graft_entry__", False)
     assert proj.imported_modules("pkg.mod_b") == {"pkg.mod_a"}
     # plain dotted import: the submodule itself is imported and must be
     # visible to imported_modules (cross-module axis-name relies on it)
@@ -1181,7 +1180,7 @@ def test_suppression_multi_rule_file_and_next_stacking():
 
 def test_repo_is_lint_clean():
     """THE contract: zero unsuppressed findings over the default scope
-    (library + bench + entry + scripts) — every live finding must be
+    (library + entry + scripts) — every live finding must be
     fixed or carry a reasoned suppression.  Shares the CLI's parse cache
     (cheap here, and it exercises the cache read path in-process)."""
     res = run_analysis(GATE_SCOPE, root=str(REPO_ROOT),

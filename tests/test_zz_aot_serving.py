@@ -76,7 +76,7 @@ def manifest():
     from paddle_tpu.tools.analysis import build_manifest_for_paths
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     scope = [os.path.join(root, p)
-             for p in ("paddle_tpu", "bench.py", "scripts")]
+             for p in ("paddle_tpu", "scripts")]
     return build_manifest_for_paths(scope, root=root)
 
 

@@ -53,7 +53,7 @@ def manifest():
     library entry point the CLI's ``--comm`` uses."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     scope = [os.path.join(root, p)
-             for p in ("paddle_tpu", "bench.py", "scripts")]
+             for p in ("paddle_tpu", "scripts")]
     m = build_comm_manifest_for_paths(scope, root=root)
     assert m["order_safety"]["ok"], m["order_safety"]
     return m

@@ -43,7 +43,7 @@ def engine_plane():
     import os
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     scope = [os.path.join(root, p)
-             for p in ("paddle_tpu", "bench.py", "scripts")]
+             for p in ("paddle_tpu", "scripts")]
     manifest = build_manifest_for_paths(scope, root=root)
     assert ENGINE_PLANE in manifest["planes"], (
         f"manifest lost the EngineCore plane; planes="

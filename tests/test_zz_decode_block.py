@@ -361,23 +361,3 @@ def test_obs_event_and_histogram_mark_fused_path(gpt):
     assert eng2.core.attention_route() == (
         "head_major_copy", evs2[0][3]["attention_reason"])
     assert eng2.registry.get("serving.phase.decode_dispatch_s").count > 0
-
-
-def test_bench_compare_row_smoke():
-    """The fused-vs-unfused kernel_compare row bench emits on every CPU
-    run: parity holds and the interpret-mode caveat note is attached."""
-    import bench
-    row = bench._decode_block_compare(smoke=True)
-    assert row["ok"] and row["fusion_legal"]
-    assert row["max_abs_diff"] < 5e-2
-    assert "interpret" in row.get("note", "")
-
-
-def test_bench_decode_path_info(gpt):
-    import bench
-    info = bench.decode_path_info(gpt, batch=4, kv_len=64)
-    assert info["path"] == "unfused"
-    assert info["fused_available"] is True
-    info16 = bench.decode_path_info(object(), batch=4, kv_len=64)
-    assert info16["fused_available"] is False
-    assert "fused_decode_step" in info16["fused_fallback_reason"]

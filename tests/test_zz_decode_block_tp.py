@@ -295,6 +295,9 @@ def test_resolve_chain_tp_legs():
     ok, reason = resolve_fused_decode(NoBundle(), batch=4, kv_len=128,
                                       tp=2)
     assert not ok and "tp_decode_weights" in reason
+    # a model with no fused surface at all says so, at any degree
+    ok, reason = resolve_fused_decode(object(), batch=4, kv_len=64)
+    assert not ok and "fused_decode_step" in reason
     old = flags.pallas_routing
     flags.pallas_routing = "never"
     try:
@@ -406,37 +409,3 @@ def test_obs_event_carries_tp_dimension():
     assert attrs2["active"] is False
     assert attrs2["tp"] == 2
     assert e2.registry.get("serving.phase.decode_dispatch_s").count > 0
-
-
-# -------------------------------------------------------- bench smokes
-
-def test_kernel_compare_decode_block_tp_rows():
-    """The bench's kernel_compare_decode_block row now carries
-    fused-vs-composed sub-rows at tp in {2, 4} (CPU interpret-mode:
-    parity is the signal; wall times measure the interpreter)."""
-    import bench
-    row = bench._decode_block_compare(smoke=True)
-    assert row["ok"], row
-    tp_rows = row.get("tp_rows")
-    assert tp_rows and [r["tp"] for r in tp_rows] == [2, 4]
-    for r in tp_rows:
-        assert r["ok"], r
-        assert r["fusion_legal"] is True
-        assert r["fused_ms"] > 0 and r["composed_ms"] > 0
-
-
-def test_serving_tp_bench_reports_fused_block():
-    """serving_tp_scaling runs the FUSED engines: tp=1 baseline is the
-    Pallas pair, tp>1 rows the sharded block, and per-chip efficiency
-    is reported against the tp=1 fused number."""
-    import bench
-    row = bench._serving_tp_bench(smoke=True)
-    rows = row["rows"]
-    assert rows[0]["tp"] == 1 and rows[0]["decode_path"] == "fused"
-    for r in rows[1:]:
-        # tp=8 at the smoke's 4 slots cannot slot-shard: the row then
-        # truthfully reports its fallback path — parity still holds
-        if r["tp"] <= 4:
-            assert r["decode_path"] == "tp_fused_block"
-            assert r["scaling_efficiency"] is not None
-        assert r["parity_vs_tp1"] is True

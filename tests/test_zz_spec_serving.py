@@ -304,41 +304,6 @@ def test_spec_metrics_surface():
     assert eng.metrics.spec_acceptance_rate is None
 
 
-# -------------------------------------------------------------- bench
-
-def test_bench_speculative_row_smoke():
-    """The ``serving_speculative`` bench row at smoke scale: it asserts
-    acceptance > 0 and token parity INTERNALLY (the ISSUE 18 CPU-smoke
-    acceptance bar), and its schema carries both sides of the compare
-    plus the spec-threaded decode_path provenance."""
-    import bench
-    row = bench._serving_speculative_bench(_fresh(), smoke=True)
-    assert row["token_parity"] is True
-    assert row["spec_acceptance_rate"] > 0
-    assert row["spec_draft_tokens"] >= row["spec_accepted_tokens"] > 0
-    assert row["tokens_per_sec_spec_on"] > 0
-    assert row["tokens_per_sec_spec_off"] > 0
-    dp = row["decode_path"]
-    assert dp["spec_k"] == row["spec_k"] > 0
-    assert dp["spec_acceptance_rate"] == pytest.approx(
-        row["spec_acceptance_rate"], abs=1e-6)
-
-
-def test_bench_decode_path_info_spec_threading():
-    """decode_path_info defaults stay spec-silent-but-explicit
-    (spec_k=0, no rate key) so pre-18 rows keep their meaning; a
-    speculating caller threads k + measured acceptance through."""
-    import bench
-    m = _fresh()
-    info = bench.decode_path_info(m, batch=4, kv_len=64)
-    assert info["spec_k"] == 0
-    assert "spec_acceptance_rate" not in info
-    info = bench.decode_path_info(m, batch=4, kv_len=64, spec_k=4,
-                                  acceptance=0.3125)
-    assert info["spec_k"] == 4
-    assert info["spec_acceptance_rate"] == 0.3125
-
-
 def test_fleet_chaos_smoke_spec_artifacts(tmp_path):
     """Tier-1 artifact smoke (mirrors
     test_fleet_chaos_smoke_artifacts): the ``--spec`` scenario
