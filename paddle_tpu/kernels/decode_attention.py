@@ -24,7 +24,10 @@ operands' shapes (:func:`pallas_attention_route`, never a flag):
     many-plane slab (models/ouro.py) is windowed by ``head0``, a traced
     scalar: no plane is ever sliced out.  :func:`stream_slab_attention`
     is the core, shared with the fused block's attention kernel
-    (kernels/decode_block.py).
+    (kernels/decode_block.py).  Through :func:`append_and_attend` the
+    same kernel also WRITES the step's fresh K and V rows into the
+    (aliased) slabs, one DMA each per slot (:func:`slab_row_writes`):
+    the models scatter nothing beforehand.
   * **head-major copy** (a prefill chunk: MXU work; slabs Mosaic cannot
     window): grid = (B*KH, num_kv_blocks), kv innermost ("arbitrary"),
     ``[rep*sq, D] x [D, block_k]`` matmuls, m/l/acc carried in VMEM
@@ -49,8 +52,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["decode_attention", "decode_attention_reference",
            "decode_attention_auto", "decode_attention_route",
-           "pallas_attention_route", "mosaic_slab_rule",
-           "stream_slab_attention", "online_softmax_update"]
+           "append_and_attend", "pallas_attention_route",
+           "mosaic_slab_rule", "stream_slab_attention",
+           "slab_row_writes", "online_softmax_update"]
 
 _NEG_INF = float("-inf")
 # slab rows the streaming core up-casts and reduces at a time
@@ -207,35 +211,95 @@ def slab_tiles(max_seq: int, want: int):
     return bk, ck
 
 
+def slab_row_writes(k_rows, v_rows, k_any, v_any, wsem, *, b, row0,
+                    heads=slice(None)):
+    """The in-kernel KV append: the K and the V ``make_async_copy`` of
+    the fresh ``k_rows / v_rows [n, KH, Dh]`` (VMEM, the slab's dtype)
+    into rows ``row0 .. row0 + n`` of slot ``b`` of the HBM slabs
+    ``k_any / v_any`` (the kernel's aliased outputs), ``heads`` as in
+    :func:`stream_slab_attention`; ``wsem`` holds two DMA semaphores.
+    The caller starts both and waits on both before its program ends."""
+    def write(rows, slab, sem):
+        return pltpu.make_async_copy(
+            rows, slab.at[b, pl.ds(row0, rows.shape[0]), heads], sem)
+    return write(k_rows, k_any, wsem.at[0]), write(v_rows, v_any, wsem.at[1])
+
+
 # ================================================== slab in place (VPU)
 
-def _slab_kernel(len_ref, head0_ref, q_ref, k_any, v_any, o_ref,
-                 kbuf, vbuf, rsem, *, S, kh, sq, rep, bk, ck, scale,
-                 causal_tail, windowed):
+def _slab_kernel(len_ref, head0_ref, q_ref, *refs, S, kh, sq, rep, bk, ck,
+                 scale, causal_tail, windowed, append):
     """One slot's attention over the ``kh`` kv heads of its window:
     ``q_ref [1, sq*rep, KH, Dh]`` (query ``t*rep + r`` is token ``t``'s
-    ``r``-th query head of every kv head)."""
+    ``r``-th query head of every kv head).
+
+    ``append``: ``len_ref`` holds the rows the slot held BEFORE this
+    chunk, ``kn_ref / vn_ref [1, sq, KH, Dh]`` are the chunk's fresh
+    rows in the slab's dtype, and the program DMAs them into the
+    aliased slabs at ``posw = clip(pos, 0, S - sq)``
+    (``dynamic_update_slice``'s clamp: a full slot overwrites its last
+    rows).  The read-after-write hazard is closed by construction, the
+    fused block's way: the stream covers the rows ``< posw`` only,
+    which the write never touches, and the fresh rows fold in last from
+    VMEM at their stored rounding; fresh row ``i`` lies at ``posw + i``
+    and query ``t`` sees it where the unfused form's mask would
+    (``posw + i < pos + t + 1`` under the causal tail)."""
+    if append:
+        kn_ref, vn_ref, k_any, v_any, o_ref, ko_any, vo_any, \
+            kbuf, vbuf, rsem, wsem = refs
+    else:
+        k_any, v_any, o_ref, kbuf, vbuf, rsem = refs
     b = pl.program_id(0)
-    seq_len = len_ref[b]
     heads = slice(None)
     if windowed:
         heads = pl.ds(pl.multiple_of(head0_ref[0], kh), kh)
+    if append:
+        pos = len_ref[b]
+        n_rows = jnp.clip(pos, 0, S - sq)
+        writes = slab_row_writes(kn_ref.at[0], vn_ref.at[0], ko_any,
+                                 vo_any, wsem, b=b, row0=n_rows,
+                                 heads=heads)
+        for cp in writes:
+            cp.start()
+        seq_len = pos + sq
+    else:
+        seq_len = len_ref[b]
+        n_rows = jnp.clip(seq_len, 0, S)
     qs = [(q_ref[0, j].astype(jnp.float32) * scale)[None]
           for j in range(sq * rep)]
     # the sq query tokens occupy cache rows [seq_len - sq, seq_len):
     # query t sees kpos <= seq_len - sq + t
     lims = [seq_len - sq + t + 1 if causal_tail else seq_len
             for t in range(sq) for _ in range(rep)]
+    # appending, the stream ends where the write starts, and every
+    # query sees all of it
     state = stream_slab_attention(
         k_any, v_any, kbuf, vbuf, rsem, b=b, heads=heads, qs=qs,
-        lims=lims, n_rows=jnp.clip(seq_len, 0, S), bk=bk, ck=ck)
+        lims=[n_rows] * len(lims) if append else lims, n_rows=n_rows,
+        bk=bk, ck=ck)
+    if append:
+        kq = kn_ref[0].astype(jnp.float32)                  # [sq, KH, Dh]
+        vq = vn_ref[0].astype(jnp.float32)
+        row = n_rows + jax.lax.broadcasted_iota(jnp.int32, (sq, 1, 1), 0)
+        state = [online_softmax_update(
+            st, jnp.where(row < lim,
+                          jnp.sum(kq * q, axis=-1, keepdims=True),
+                          _NEG_INF), vq)
+            for q, lim, st in zip(qs, lims, state)]
     for j, (_, l, acc) in enumerate(state):
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, j] = (acc / l_safe)[0].astype(o_ref.dtype)
+    if append:
+        for cp in writes:
+            cp.wait()
 
 
 def _slab_in_place(q, k_cache, v_cache, seq_lens, head0, kh, *, scale,
-                   block_k, causal_tail, interpret):
+                   block_k, causal_tail, interpret, fresh=None):
+    """``fresh``: None, or the chunk's ``(k_new, v_new) [b, sq, KH,
+    Dh]``: the kernel appends them (``seq_lens`` is then the rows held
+    BEFORE the chunk) and the updated slabs come back beside the
+    output."""
     b, sq, h, d = q.shape
     s_max, slab_heads = k_cache.shape[1:3]
     rep = h // kh
@@ -246,40 +310,51 @@ def _slab_in_place(q, k_cache, v_cache, seq_lens, head0, kh, *, scale,
     # picks "query j of every kv head" by a leading index
     qr = jnp.moveaxis(q.reshape(b, sq, kh, rep, d), 3, 2) \
         .reshape(b, sq * rep, kh, d)
+    append = fresh is not None
     kernel = functools.partial(
         _slab_kernel, S=s_max, kh=kh, sq=sq, rep=rep, bk=bk, ck=ck,
         scale=scale, causal_tail=causal_tail,
-        windowed=slab_heads != kh)
+        windowed=slab_heads != kh, append=append)
     # lengths and the window's first head ride as scalar-prefetch
     # operands: in SMEM before the grid starts (a (1,) SMEM block per
     # program is not lowerable)
     qspec = pl.BlockSpec((1, sq * rep, kh, d),
                          lambda bi, lens, h0: (bi, 0, 0, 0))
+    slab = pl.BlockSpec(memory_space=pl.ANY)
+    rows = pl.BlockSpec((1, sq, kh, d), lambda bi, lens, h0: (bi, 0, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((b, sq * rep, kh, d), q.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[qspec, pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=qspec,
+        in_specs=[qspec] + [rows, rows] * append + [slab, slab],
+        out_specs=[qspec, slab, slab] if append else qspec,
         scratch_shapes=[
             pltpu.VMEM((2, bk, kh, d), k_cache.dtype),
             pltpu.VMEM((2, bk, kh, d), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-        ],
+        ] + ([pltpu.SemaphoreType.DMA((2,))] if append else []),
     )
-    out = pl.pallas_call(
+    res = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq * rep, kh, d), q.dtype),
+        out_shape=[out_shape,
+                   jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)]
+        if append else out_shape,
+        # operand indices count the two scalar-prefetch arguments
+        input_output_aliases={5: 1, 6: 2} if append else {},
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
         name="decode_attention_slab",
         interpret=interpret,
     )(seq_lens.astype(jnp.int32),
-      jnp.asarray(head0, jnp.int32).reshape(1), qr, k_cache, v_cache)
-    return jnp.moveaxis(out.reshape(b, sq, rep, kh, d), 2, 3) \
+      jnp.asarray(head0, jnp.int32).reshape(1), qr,
+      *(fresh or ()), k_cache, v_cache)
+    out, *slabs = res if append else (res,)
+    out = jnp.moveaxis(out.reshape(b, sq, rep, kh, d), 2, 3) \
         .reshape(b, sq, h, d)
+    return (out, *slabs) if append else out
 
 
 # =============================================== head-major copy (MXU)
@@ -513,6 +588,15 @@ def decode_attention_route(q_shape, slab_shape, dtype,
     return pallas_attention_route(q_shape, slab_shape, dtype, kv_heads)
 
 
+def _routed(route, q, k_cache, v_cache, seq_lens, *, interpret, **kw):
+    """Attention over slabs that already hold the chunk, by ``route``."""
+    if route == "xla_dense":
+        return decode_attention_reference(q, k_cache, v_cache, seq_lens,
+                                          **kw)
+    return decode_attention(q, k_cache, v_cache, seq_lens,
+                            interpret=interpret, **kw)
+
+
 def decode_attention_auto(q, k_cache, v_cache, seq_lens,
                           scale: Optional[float] = None,
                           causal_tail: bool = True,
@@ -524,10 +608,50 @@ def decode_attention_auto(q, k_cache, v_cache, seq_lens,
     to XLA — see kernels/routing.py)."""
     route, _ = decode_attention_route(q.shape, k_cache.shape,
                                       k_cache.dtype, kv_heads)
-    if route == "xla_dense":
-        return decode_attention_reference(
-            q, k_cache, v_cache, seq_lens, scale=scale,
-            causal_tail=causal_tail, head0=head0, kv_heads=kv_heads)
-    return decode_attention(q, k_cache, v_cache, seq_lens, scale=scale,
-                            causal_tail=causal_tail, interpret=interpret,
-                            head0=head0, kv_heads=kv_heads)
+    return _routed(route, q, k_cache, v_cache, seq_lens, scale=scale,
+                   causal_tail=causal_tail, interpret=interpret,
+                   head0=head0, kv_heads=kv_heads)
+
+
+def append_and_attend(q, k_new, v_new, k_slab, v_slab, pos, *, head0=0,
+                      kv_heads: Optional[int] = None,
+                      scale: Optional[float] = None,
+                      causal_tail: bool = True,
+                      interpret: Optional[bool] = None):
+    """Append the fresh chunk to the KV slabs and attend to them: what
+    a cached attention layer does each step, as ONE call.
+
+    q [B, sq, H, D]; k_new / v_new [B, sq, kv_heads, D] the chunk's
+    rows in the slabs' dtype; k_slab / v_slab [B, S_max, slab_heads, D];
+    ``pos`` the rows held BEFORE this chunk, a scalar or ``[B]`` int32
+    (``models/kv_cache.py``).  ``head0`` / ``kv_heads`` window one plane
+    of a many-plane slab as in :func:`decode_attention`.  Returns
+    ``(out [B, sq, H, D], k_slab', v_slab')``; the slabs equal
+    ``kv_cache.append_kv``'s bit for bit, its clamp of a write past the
+    slab's end included.
+
+    :func:`decode_attention_route` decides, from the operands' shapes
+    and dtype: ``slab_in_place`` appends inside the kernel (one DMA of
+    K and of V per slot into the aliased slabs, no XLA scatter);
+    ``head_major_copy`` and ``xla_dense`` append with ``append_kv``
+    first and attend as :func:`decode_attention_auto` does."""
+    from ..models.kv_cache import append_kv, cache_lens
+    b, sq, _, d = q.shape
+    kh = kv_heads or k_slab.shape[2]
+    route, _ = decode_attention_route(q.shape, k_slab.shape, k_slab.dtype,
+                                      kv_heads)
+    if route != "slab_in_place":
+        k_slab, v_slab = append_kv(k_slab, v_slab, k_new, v_new, pos,
+                                   head0)
+        out = _routed(route, q, k_slab, v_slab, cache_lens(pos, sq, b),
+                      scale=scale, causal_tail=causal_tail,
+                      interpret=interpret, head0=head0, kv_heads=kv_heads)
+        return out, k_slab, v_slab
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    return _slab_in_place(
+        q, k_slab, v_slab, jnp.broadcast_to(jnp.asarray(pos), (b,)),
+        head0, kh,
+        scale=scale if scale is not None else 1.0 / (d ** 0.5),
+        block_k=SLAB_TILE_ROWS, causal_tail=causal_tail,
+        interpret=interpret, fresh=(k_new, v_new))

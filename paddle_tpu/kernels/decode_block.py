@@ -48,7 +48,7 @@ operands are in the WEIGHT dtype (activations round to it first, as
 the composed path's do), so no weight tile is ever up-cast in VMEM.
 
 Masking contract (exactly ``decode_attention``'s semantics specialised
-to sq=1, matching the unfused ``append_kv`` + ``decode_attention_auto``
+to sq=1, matching the unfused ``decode_attention.append_and_attend``
 path token-for-token): with ``pos`` = the slot's cache length BEFORE the
 step, streamed positions ``kpos < min(pos, S-1)`` are valid and the
 fresh token is appended at ``min(pos, S-1)`` (``dynamic_update_slice``'s
@@ -86,8 +86,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .decode_attention import (ATTN_CHUNK, VMEM_LIMIT, mosaic_slab_rule,
-                               online_softmax_update, slab_tiles,
-                               stream_slab_attention)
+                               online_softmax_update, slab_row_writes,
+                               slab_tiles, stream_slab_attention)
 
 __all__ = ["decode_block_attn", "decode_block_mlp", "decode_block_layer",
            "decode_block_reference", "plan_decode_block", "fusion_legal",
@@ -512,10 +512,8 @@ def _slab_attn_kernel(pos_ref, q_ref, kn_ref, vn_ref, cos_ref, sin_ref,
     posw = jnp.minimum(pos, S - 1)
     knew_sc[...] = kx[None].astype(knew_sc.dtype)
     vnew_sc[...] = vx[None].astype(vnew_sc.dtype)
-    kw_cp = pltpu.make_async_copy(knew_sc, ko_any.at[b, pl.ds(posw, 1)],
-                                  wsem.at[0])
-    vw_cp = pltpu.make_async_copy(vnew_sc, vo_any.at[b, pl.ds(posw, 1)],
-                                  wsem.at[1])
+    kw_cp, vw_cp = slab_row_writes(knew_sc, vnew_sc, ko_any, vo_any, wsem,
+                                   b=b, row0=posw)
     kw_cp.start()
     vw_cp.start()
 

@@ -117,16 +117,16 @@ class GPTBlock(Layer):
             pk, pv, pos = cache
             # pos may be a scalar (dense batch) or a [b] vector of per-row
             # offsets (ragged continuous batching) — models/kv_cache.py
-            from .kv_cache import append_kv, cache_lens
-            k, v = append_kv(pk, pv, k, v, pos)
-            new_cache = (k, v, pos + s)
             # decode: the routed decode-attention path (pallas streaming
-            # kernel or its exact-semantics dense form, kernels/routing.py)
-            # — seq_lens = pos + s with the causal tail gives precisely
-            # the per-query mask (query at chunk offset t sees keys up to
-            # pos + t), without materializing a [*, s, S_max] mask tensor
-            from ..kernels.decode_attention import decode_attention_auto
-            out = decode_attention_auto(q, k, v, cache_lens(pos, s, b))
+            # kernel or its exact-semantics dense form, kernels/routing.py),
+            # which appends the chunk to the cache on its way (inside
+            # the kernel where it reads the slab in place) — seq_lens =
+            # pos + s with the causal tail gives precisely the per-query
+            # mask (query at chunk offset t sees keys up to pos + t),
+            # without materializing a [*, s, S_max] mask tensor
+            from ..kernels.decode_attention import append_and_attend
+            out, k, v = append_and_attend(q, k, v, pk, pv, pos)
+            new_cache = (k, v, pos + s)
         elif cfg.cp:
             # long-context: sequence sharded over the sep axis; ring or
             # Ulysses attention instead of local sdpa (attn dropout is not

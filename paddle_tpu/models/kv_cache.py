@@ -31,18 +31,29 @@ def _is_per_row(pos) -> bool:
     return getattr(pos, "ndim", 0) >= 1
 
 
-def append_kv(pk, pv, k, v, pos):
+def append_kv(pk, pv, k, v, pos, head0=0):
     """Write the fresh chunk ``k/v [b, s, h, d]`` into the cache buffers
-    ``pk/pv [b, max_len, h, d]`` at ``pos`` (scalar, or ``[b]`` int32 for
-    per-row offsets).  Returns the updated full buffers."""
+    ``pk/pv [b, max_len, slab_heads, d]`` at rows ``pos..`` (scalar, or
+    ``[b]`` int32 for per-row offsets) of the heads ``head0 .. head0 +
+    h`` (traced: one plane of a looped model's many-plane slab; 0 where
+    the slab is the plane).  Returns the updated full buffers.  A start
+    past ``max_len - s`` clamps to it (``dynamic_update_slice``).
+
+    The XLA form of the append.  Decode and the verify window take it
+    inside the attention kernel
+    (``kernels.decode_attention.append_and_attend``), which falls back
+    to this one.  Per row it is ONE scatter: unrolled into a
+    ``dynamic_update_slice`` a row, XLA:TPU no longer updates a
+    many-plane slab in place (``temp`` 3.2 GB, a whole slab, compiled
+    for the chip in PR 28)."""
     if _is_per_row(pos):
         def row(buf, new, p):
-            return jax.lax.dynamic_update_slice_in_dim(buf, new, p, axis=0)
+            return jax.lax.dynamic_update_slice(buf, new, (p, head0, 0))
         upd = jax.vmap(row)
         p = jnp.asarray(pos, jnp.int32)
         return upd(pk, k, p), upd(pv, v, p)
-    return (jax.lax.dynamic_update_slice_in_dim(pk, k, pos, axis=1),
-            jax.lax.dynamic_update_slice_in_dim(pv, v, pos, axis=1))
+    return (jax.lax.dynamic_update_slice(pk, k, (0, pos, head0, 0)),
+            jax.lax.dynamic_update_slice(pv, v, (0, pos, head0, 0)))
 
 
 def gather_block_rows(block_buf, idx):

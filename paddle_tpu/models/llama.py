@@ -110,25 +110,19 @@ class LlamaAttention(Layer):
         k = apply_rotary_pos_emb(k, cos, sin)
         new_cache = None
         if cache is not None:
-            pk, pv, pos = cache
-            # pos may be a scalar (dense batch) or a [b] vector of per-row
-            # offsets (ragged continuous batching) — models/kv_cache.py
-            from .kv_cache import append_kv
-            k, v = append_kv(pk, pv, k, v, pos)
-            new_cache = (k, v, pos + s)
-        if cache is not None:
-            # routed decode attention (see gpt.py _attn): seq_lens =
-            # pos + s with the causal tail IS the per-query chunked-
-            # prefill mask, with no [*, s, S_max] mask materialization.
-            # lens derive from the cache POSITION per row (a scalar pos
-            # broadcasts; a [b] vector keeps each row's own context
-            # length — ragged batches were silently wrong under the old
-            # jnp.full((b,), pos + s) which assumed uniform lengths).
+            # routed decode attention (see gpt.py _attn), which appends
+            # the chunk to the cache on its way: seq_lens = pos + s with
+            # the causal tail IS the per-query chunked-prefill mask,
+            # with no [*, s, S_max] mask materialization.  pos may be a
+            # scalar (dense batch: it broadcasts) or a [b] vector of
+            # per-row offsets (ragged continuous batching: each row
+            # keeps its own context length) — models/kv_cache.py.
             # GQA happens inside the kernels: the cache is never
             # repeated up to the query heads
-            from ..kernels.decode_attention import decode_attention_auto
-            from .kv_cache import cache_lens
-            out = decode_attention_auto(q, k, v, cache_lens(cache[2], s, b))
+            from ..kernels.decode_attention import append_and_attend
+            pk, pv, pos = cache
+            out, k, v = append_and_attend(q, k, v, pk, pv, pos)
+            new_cache = (k, v, pos + s)
         else:
             # GQA: repeat kv heads up to q heads (XLA turns this into a
             # broadcast inside the attention einsum — no real copy)

@@ -48,8 +48,7 @@ from ..nn import initializer as I
 from ..nn.layer import Layer
 from ..nn.layers.common import Embedding, Linear
 from ..nn.layers.norm import RMSNorm
-from ..kernels.decode_attention import decode_attention_auto
-from .kv_cache import cache_lens
+from ..kernels.decode_attention import append_and_attend
 from .llama import _rope_tables, apply_rotary_pos_emb
 
 __all__ = ["OuroConfig", "OuroStack", "OuroModel", "OuroForCausalLM",
@@ -166,21 +165,6 @@ def _norm(x, w, eps):
     return F.rms_norm(x.astype(jnp.float32), w, None, eps)
 
 
-def _append_plane(slab, new, pos, head0):
-    """Write the fresh ``new [b, s, kv_heads, d]`` into ``slab [b,
-    max_len, planes * kv_heads, d]`` at rows ``pos..`` (a scalar, or
-    ``[b]`` per-row offsets) of the plane whose first head is ``head0``
-    (traced).  Per row it is ONE scatter: unrolled into a
-    ``dynamic_update_slice`` a row, XLA:TPU no longer updates the slab
-    in place (``temp`` 3.2 GB, a whole slab, compiled for the chip in
-    PR 28)."""
-    if getattr(pos, "ndim", 0) >= 1:
-        def row(buf, n, p):
-            return jax.lax.dynamic_update_slice(buf, n, (p, head0, 0))
-        return jax.vmap(row)(slab, new, jnp.asarray(pos, jnp.int32))
-    return jax.lax.dynamic_update_slice(slab, new, (0, pos, head0, 0))
-
-
 class OuroModel(Layer):
     def __init__(self, cfg: OuroConfig):
         super().__init__()
@@ -238,7 +222,7 @@ class OuroModel(Layer):
         slabs = None
         if caches is not None:
             (pk, pv, cpos), = caches
-            slabs, lens = (pk, pv), cache_lens(cpos, s, b)
+            slabs = (pk, pv)
 
         def layer(t):
             def body(carry, xs):
@@ -249,13 +233,12 @@ class OuroModel(Layer):
                     a = F.scaled_dot_product_attention(
                         q, k, v, is_causal=True, training=False)
                 else:
-                    head0 = plane_index(t, l, n) * kvh
-                    slabs = (_append_plane(slabs[0], k, cpos, head0),
-                             _append_plane(slabs[1], v, cpos, head0))
                     # the plane is a window of the slab's head axis:
-                    # the kernel reads it where it lies
-                    a = decode_attention_auto(q, slabs[0], slabs[1], lens,
-                                              head0=head0, kv_heads=kvh)
+                    # the kernel writes and reads it where it lies
+                    a, ks, vs = append_and_attend(
+                        q, k, v, *slabs, cpos, kv_heads=kvh,
+                        head0=plane_index(t, l, n) * kvh)
+                    slabs = (ks, vs)
                 return (self._finish(w, x, a), slabs), None
             return body
 

@@ -1250,22 +1250,38 @@ class EngineCore:
                 (slots, rows, slab_heads // tp, dh),
                 self.pool.ks[0].dtype, kv_heads // tp)
 
+    def kv_append(self):
+        """``(how, reason)`` the decode program writes a step's fresh K
+        and V rows into the slot slabs: ``("in_kernel", None)`` where
+        its attention kernel does (one DMA of each per slot:
+        ``decode_attention.append_and_attend`` on the ``slab_in_place``
+        route, the fused blocks by construction), else
+        ``("xla_scatter", why)`` with the attention route's own reason
+        (``kv_cache.append_kv`` ahead of the attention)."""
+        route, why = self.attention_route()
+        if route == "slab_in_place":
+            return "in_kernel", None
+        return "xla_scatter", why
+
     def _emit_decode_block(self) -> None:
         """The discrete obs event that marks WHICH path this engine's
         single decode program compiled with (and why, on fallback) —
         traces distinguish fused from unfused steps without diffing
         configs; the tp dimension separates the sharded block from the
         tp=1 pair in a shared registry; the attention route says
-        whether the program reads the slot slabs where they lie
+        whether the program reads the slot slabs where they lie, and
+        ``kv_append`` whether its kernel also writes them
         (glossary: docs/observability.md)."""
         route, why = self.attention_route()
+        append, append_why = self.kv_append()
         self.metrics.on_decode_block(
             active=self.decode_path in ("fused", "tp_fused_block"),
             reason=None if not self.fused_decode
             else self.decode_fallback_reason,
             step=self._step_in_flight,
             tp=self.tensor_parallel,
-            attention_route=route, attention_reason=why)
+            attention_route=route, attention_reason=why,
+            kv_append=append, kv_append_reason=append_why)
 
     def _build_decode_fn(self) -> Callable:
         model = self.model

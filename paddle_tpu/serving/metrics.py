@@ -336,7 +336,9 @@ class ServingMetrics:
     def on_decode_block(self, active: bool, reason: Optional[str],
                         step: int = 0, tp: int = 1,
                         attention_route: str = "",
-                        attention_reason: Optional[str] = None) -> None:
+                        attention_reason: Optional[str] = None,
+                        kv_append: str = "",
+                        kv_append_reason: Optional[str] = None) -> None:
         """The engine resolved its decode path (emitted once, when the
         single decode program is built): ``active`` says whether the
         fused decode-block kernels compiled in, ``reason`` carries the
@@ -348,7 +350,11 @@ class ServingMetrics:
         ``attention_route`` is how that program's attention reaches the
         KV slabs (``slab_in_place`` / ``head_major_copy`` /
         ``xla_dense``: kernels/decode_attention.py) and
-        ``attention_reason`` why it is not ``slab_in_place``.  Lands
+        ``attention_reason`` why it is not ``slab_in_place``;
+        ``kv_append`` is who writes a step's fresh rows into them
+        (``in_kernel``: that attention kernel, by DMA; ``xla_scatter``:
+        ``kv_cache.append_kv`` ahead of it) and ``kv_append_reason`` why
+        it is not ``in_kernel``.  Lands
         as a ``decode_block`` discrete event on the engine lane
         (glossary: docs/observability.md)."""
         self.tracer.event("decode_block", lane=self.engine_lane,
@@ -356,7 +362,9 @@ class ServingMetrics:
                           reason=reason if reason is not None else "",
                           step=step, tp=tp,
                           attention_route=attention_route,
-                          attention_reason=attention_reason or "")
+                          attention_reason=attention_reason or "",
+                          kv_append=kv_append,
+                          kv_append_reason=kv_append_reason or "")
 
     def on_aot_load(self, programs: int, seconds: float,
                     build_s: Optional[float] = None) -> None:

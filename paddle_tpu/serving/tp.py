@@ -272,8 +272,7 @@ def _tp_layer(x_s, pk, pv, seq_pos, blk, arch, rope, axis, tp, overlap,
     — query t of a slot's window sees keys up to ``pos + t``."""
     from ..kernels.collective_matmul import (allgather_matmul,
                                              matmul_reduce_scatter)
-    from ..kernels.decode_attention import decode_attention_auto
-    from ..models.kv_cache import append_kv, cache_lens
+    from ..kernels.decode_attention import append_and_attend
     from ..nn import functional as F
     dh = arch["head_dim"]
     h_l = arch["heads"] // tp
@@ -293,10 +292,8 @@ def _tp_layer(x_s, pk, pv, seq_pos, blk, arch, rope, axis, tp, overlap,
         cos, sin = rope
         q = apply_rotary_pos_emb(q, cos, sin)
         k = apply_rotary_pos_emb(k, cos, sin)
-    k_buf, v_buf = append_kv(pk, pv, k, v, seq_pos)
-    lens = cache_lens(seq_pos, s, b)
     # GQA inside the kernel: this device's kv heads, never repeated
-    attn = decode_attention_auto(q, k_buf, v_buf, lens)  # [B, s, h_l, dh]
+    attn, k_buf, v_buf = append_and_attend(q, k, v, pk, pv, seq_pos)
     attn = attn.reshape(rows, h_l * dh)
     # ---- exit: out-proj dot with the reduce-scatter riding it
     o = matmul_reduce_scatter(attn, blk["wo"], axis, tp, overlap=overlap)

@@ -89,7 +89,10 @@ def test_decode_attention(topo, slots, sq, rows, kv_heads, slab_heads,
                           route):
     """Decode against the slot slabs where they lie (a plane by its
     TRACED first head), and a prefill chunk on the copying kernel: the
-    kernel each shape takes on the chip, compiled by Mosaic."""
+    kernel each shape takes on the chip, compiled by Mosaic, alone and
+    as ``append_and_attend`` runs it.  The appending form writes the
+    fresh rows into the donated slabs itself: both are aliased and
+    ``temp`` holds no plane."""
     import importlib
     da = importlib.import_module("paddle_tpu.kernels.decode_attention")
     s = _one(topo)
@@ -104,11 +107,24 @@ def test_decode_attention(topo, slots, sq, rows, kv_heads, slab_heads,
 
     mem = _compile(attn, q, slab, slab, s((slots,), jnp.int32),
                    s((), jnp.int32))
+    plane = slots * rows * kv_heads * DH * 2
     if route == "slab_in_place":
-        plane = slots * rows * kv_heads * DH * 2
         assert mem.temp_size_in_bytes < plane, (
             f"temp {mem.temp_size_in_bytes} B holds a copy of a plane "
             f"({plane} B)")
+
+    def append(q, kn, vn, k, v, pos, head0):
+        return da.append_and_attend(q, kn, vn, k, v, pos, head0=head0,
+                                    kv_heads=kv_heads, interpret=False)
+
+    fresh = s((slots, sq, kv_heads, DH))
+    mem = _compile(append, q, fresh, fresh, slab, slab,
+                   s((slots,), jnp.int32), s((), jnp.int32),
+                   donate_argnums=(3, 4))
+    if route == "slab_in_place":
+        assert mem.temp_size_in_bytes < plane, mem.temp_size_in_bytes
+        assert mem.alias_size_in_bytes >= 2 * plane * (slab_heads
+                                                       // kv_heads)
 
 
 def test_looped_decode_step_moves_no_plane(topo, monkeypatch):

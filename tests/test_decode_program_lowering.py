@@ -9,6 +9,12 @@ own decode program for the chip (the routes are the chip's; nothing is
 compiled or run) at a tiny depth and the published head size, and looks
 through its StableHLO for any data-movement operation whose result is
 as large as a slab, a plane, or a plane repeated up to the query heads.
+
+Since PR 31 the in-place kernel also WRITES the step's fresh rows, so
+such a program holds no XLA append either (PERF.md, PR 31: 384 loops of
+8 one-row updates were a quarter of the looped model's step): no
+``scatter`` or ``dynamic_update_slice`` with a slab-sized result, and no
+``while`` that carries a slab but the model's own scans.
 """
 
 import re
@@ -23,6 +29,10 @@ SLOTS, ROWS, DH = 4, 48, 128
 _MOVES = re.compile(
     r"stablehlo\.(transpose|copy|dynamic_slice|broadcast\w*|concatenate)\b"
     r".*->\s*tensor<([0-9x]+)x\w+>")
+
+
+_RESULT = re.compile(r"->\s*tensor<([0-9x]+)x\w+>")
+_TENSOR = re.compile(r"tensor<([0-9x]+)x\w+>")
 
 
 def _gpt(heads, head_dim):
@@ -75,30 +85,67 @@ def _slab_sized_moves(text, sizes):
     return found
 
 
-@pytest.mark.parametrize("make,route,copies", [
-    (lambda: _gpt(8, DH), "slab_in_place", False),
-    (_ouro, "slab_in_place", False),
-    (_llama_gqa, "slab_in_place", False),
-    # the guard has teeth: 16 x 64 is a slab Mosaic cannot window, the
-    # copying kernel serves it, and its transposes are found
-    (lambda: _gpt(16, 64), "head_major_copy", True),
+def _size(dims: str) -> int:
+    return int(np.prod([int(d) for d in dims.split("x")]))
+
+
+def _slab_sized_appends(text, sizes):
+    """``(updates, loops)``: the ``scatter`` / ``dynamic_update_slice``
+    operations whose result is slab-sized (a vmapped per-row
+    ``dynamic_update_slice`` lowers to a ``scatter``, which XLA:TPU runs
+    as a loop of one-row updates), and the ``while`` loops that carry a
+    slab-sized value."""
+    updates, loops = [], []
+    lines = text.splitlines()
+    for i, line in enumerate(lines):
+        if "stablehlo.while" in line:
+            carried = _TENSOR.findall(line.rsplit(") :", 1)[-1])
+            if any(_size(d) in sizes for d in carried):
+                loops.append(line.strip()[:80])
+        elif re.search(r"stablehlo\.(scatter|dynamic_update_slice)\b",
+                       line):
+            # a scatter's type follows its region, lines further down
+            typed = next(ln for ln in lines[i:] if _RESULT.search(ln))
+            if _size(_RESULT.search(typed).group(1)) in sizes:
+                updates.append(line.strip()[:80])
+    return updates, loops
+
+
+@pytest.mark.parametrize("make,route,copies,scans", [
+    (lambda: _gpt(8, DH), "slab_in_place", False, 0),
+    # the looped model's two scans (passes, layers) carry its one slab
+    (_ouro, "slab_in_place", False, 2),
+    (_llama_gqa, "slab_in_place", False, 0),
+    # the guards have teeth: 16 x 64 is a slab Mosaic cannot window, the
+    # copying kernel serves it behind the XLA append, and its
+    # transposes and its scatters (K and V of two layers) are found
+    (lambda: _gpt(16, 64), "head_major_copy", True, 0),
 ], ids=["gpt3_shaped", "ouro_shaped", "llama_gqa", "gpt_h64_copies"])
-def test_decode_program_moves_no_slab(make, route, copies, monkeypatch):
+def test_decode_program_moves_no_slab(make, route, copies, scans,
+                                      monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model = make()
     core, text = _decode_stablehlo(model)
     assert core.attention_route()[0] == route, core.attention_route()
+    in_place = route == "slab_in_place"
+    assert core.kv_append() == (
+        ("in_kernel", None) if in_place
+        else ("xla_scatter", core.attention_route()[1]))
     cfg = model.cfg
     slab = core.pool.ks[0]
     kv_heads = getattr(cfg, "kv_heads", None) or cfg.num_heads
     plane = slab.size // slab.shape[2] * kv_heads
     sizes = {slab.size, plane, plane * (cfg.num_heads // kv_heads)}
     found = _slab_sized_moves(text, sizes)
+    updates, loops = _slab_sized_appends(text, {slab.size})
     assert text.count("tpu_custom_call") >= 1
+    assert len(loops) == scans, loops
     if copies:
         assert any("transpose" in line for line in found), found
+        assert len(updates) == 2 * cfg.num_layers, updates
     else:
         assert not found, "\n".join(found)
+        assert not updates, "\n".join(updates)
 
 
 @pytest.mark.parametrize("spec_k", [0, 2], ids=["decode", "verify"])

@@ -366,6 +366,118 @@ def test_decode_attention_keeps_the_copying_kernel(sq, h, kh, d, why):
         _decode_ref(q, kc, vc, np.asarray(lens)), rtol=2e-5, atol=2e-5)
 
 
+_APPEND_SHAPES = {
+    # name: (query heads, kv heads, planes of the slab, the plane)
+    "mha32": (32, 32, 1, 0),            # gpt3-6.7b's slab, unwindowed
+    "plane_head0_16": (16, 16, 3, 1),   # a middle plane, traced head0
+    "gqa_kh8_rep2": (16, 8, 1, 0),
+}
+
+
+def _append_operands(shape, sq, dtype, s_max, b, seed=0):
+    h, kh, planes, plane = _APPEND_SHAPES[shape]
+    rs = np.random.RandomState(seed + 7 * sq + h + planes)
+    dt, d = jnp.dtype(dtype), 128
+    draw = lambda *dims: jnp.asarray(rs.randn(*dims) * 0.5, dt)
+    return (draw(b, sq, h, d), draw(b, sq, kh, d), draw(b, sq, kh, d),
+            draw(b, s_max, planes * kh, d), draw(b, s_max, planes * kh, d),
+            plane * kh, kh)
+
+
+def _check_append(da, ops, pos, sq, dtype, **kw):
+    """``append_and_attend`` against ``append_kv`` (the slabs, bit for
+    bit) and the dense reference over ITS slabs (the output)."""
+    from paddle_tpu.models.kv_cache import append_kv, cache_lens
+    q, kn, vn, kc, vc, head0, kh = ops
+    b = q.shape[0]
+    out, k2, v2 = jax.jit(lambda *a: da.append_and_attend(
+        *a[:6], head0=a[6], kv_heads=kh, interpret=True, **kw))(
+        q, kn, vn, kc, vc, pos, jnp.asarray(head0, jnp.int32))
+    kw_, vw_ = append_kv(kc, vc, kn, vn, pos, head0)
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))
+    np.testing.assert_array_equal(f32(k2), f32(kw_))
+    np.testing.assert_array_equal(f32(v2), f32(vw_))
+    assert k2.dtype == kc.dtype and out.dtype == q.dtype
+    outside = np.ones(kc.shape[2], bool)
+    outside[head0:head0 + kh] = False
+    np.testing.assert_array_equal(f32(k2)[:, :, outside],
+                                  f32(kc)[:, :, outside])
+    np.testing.assert_array_equal(f32(v2)[:, :, outside],
+                                  f32(vc)[:, :, outside])
+    ref = da.decode_attention_reference(
+        q.astype(jnp.float32), kw_.astype(jnp.float32),
+        vw_.astype(jnp.float32), cache_lens(pos, sq, b), head0=head0,
+        kv_heads=kh, **kw)
+    tol = 2e-5 if dtype == "float32" else 1e-2
+    np.testing.assert_allclose(f32(out), np.asarray(ref), rtol=tol,
+                               atol=tol)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("pos", ["per_row", "scalar", "scalar_clamped"])
+@pytest.mark.parametrize("sq", [1, 3, 8])
+@pytest.mark.parametrize("shape", list(_APPEND_SHAPES))
+def test_append_and_attend_writes_the_rows_in_the_kernel(shape, sq, pos,
+                                                         dtype):
+    """The appending kernel: the returned slabs equal ``append_kv``'s
+    bit for bit (no head outside the window touched) and the output is
+    the reference's over those slabs, for decode and a causal verify
+    window, with slots parked at row 0, on either side of a streamed
+    tile's edge, at the last start that fits, and past it (where the
+    write clamps to ``S - sq`` as ``dynamic_update_slice`` does and the
+    mask follows ``pos``), per row and as one scalar."""
+    da = _da()
+    s_max, t = 2 * da.SLAB_TILE_ROWS, da.SLAB_TILE_ROWS
+    rows = [0, t - 1, t, t + 1, s_max - sq, s_max - sq + 1, s_max + 3]
+    ops = _append_operands(shape, sq, dtype, s_max, len(rows))
+    q, _, _, kc = ops[:4]
+    assert da.decode_attention_route(q.shape, kc.shape, kc.dtype,
+                                     ops[-1]) == ("slab_in_place", None)
+    at = {"per_row": jnp.asarray(rows, jnp.int32),
+          "scalar": jnp.asarray(t - 1, jnp.int32),
+          "scalar_clamped": jnp.asarray(s_max - 1, jnp.int32)}[pos]
+    _check_append(da, ops, at, sq, dtype)
+    if pos == "per_row" and sq == 3:
+        _check_append(da, ops, at, sq, dtype, causal_tail=False)
+    text = str(jax.make_jaxpr(lambda *a: da.append_and_attend(
+        *a, kv_heads=ops[-1], interpret=True))(*ops[:5], at))
+    assert "pallas_call" in text
+    assert "scatter" not in text and "dynamic_update_slice" not in text
+
+
+@pytest.mark.parametrize("route", ["head_major_copy_prefill",
+                                   "head_major_copy_h64", "xla_dense"])
+def test_append_and_attend_keeps_the_xla_append(route, monkeypatch):
+    """What the in-place kernel does not take still appends with
+    ``append_kv`` ahead of its attention (a scatter per row, one
+    ``dynamic_update_slice`` for a scalar ``pos``): a prefill chunk, a
+    slab Mosaic cannot window, and ``FLAGS_pallas_routing=never``."""
+    from paddle_tpu.core.flags import flags
+    da = _da()
+    b, s_max = 3, 64
+    sq, h, d = {"head_major_copy_prefill": (16, 8, 128),
+                "head_major_copy_h64": (1, 12, 64),
+                "xla_dense": (1, 8, 128)}[route]
+    if route == "xla_dense":
+        monkeypatch.setattr(flags, "pallas_routing", "never")
+    rs = np.random.RandomState(len(route))
+    draw = lambda *dims: jnp.asarray(
+        rs.randn(*dims).astype(np.float32) * 0.5)
+    ops = (draw(b, sq, h, d), draw(b, sq, h, d), draw(b, sq, h, d),
+           draw(b, s_max, h, d), draw(b, s_max, h, d), 0, h)
+    got = da.decode_attention_route(ops[0].shape, ops[3].shape,
+                                    jnp.float32)[0]
+    assert route.startswith(got)
+    for pos, op in ((jnp.asarray([0, 9, s_max - sq + 2], jnp.int32),
+                     "scatter"),
+                    (jnp.asarray(5, jnp.int32), "dynamic_update_slice")):
+        _check_append(da, ops, pos, sq, "float32")
+        text = str(jax.make_jaxpr(lambda *a: da.append_and_attend(
+            *a, interpret=True))(*ops[:5], pos))
+        assert op in text, (route, op)
+        assert ("pallas_call" in text) == (route != "xla_dense")
+
+
 def test_flash_attention_varlen_matches_dense_mask():
     """Segment-masked kernel == dense same-segment masking (packed varlen),
     fwd and grads."""

@@ -347,6 +347,10 @@ def test_obs_event_and_histogram_mark_fused_path(gpt):
     # the fused block streams the slab in place by construction
     assert attrs["attention_route"] == "slab_in_place"
     assert attrs["attention_reason"] == ""
+    # ... and appends the fresh row inside its attention kernel
+    assert attrs["kv_append"] == "in_kernel"
+    assert attrs["kv_append_reason"] == ""
+    assert eng.core.kv_append() == ("in_kernel", None)
     # the fused dispatch is timed by the decode phase's histogram, the
     # event says which path that was
     assert eng.registry.get("serving.phase.decode_dispatch_s").count > 0
@@ -360,4 +364,11 @@ def test_obs_event_and_histogram_mark_fused_path(gpt):
     assert "head_dim 16" in evs2[0][3]["attention_reason"]
     assert eng2.core.attention_route() == (
         "head_major_copy", evs2[0][3]["attention_reason"])
+    # the copying kernel writes nothing: kv_cache.append_kv goes first,
+    # for the route's own reason
+    assert evs2[0][3]["kv_append"] == "xla_scatter"
+    assert evs2[0][3]["kv_append_reason"] == \
+        evs2[0][3]["attention_reason"]
+    assert eng2.core.kv_append() == (
+        "xla_scatter", evs2[0][3]["attention_reason"])
     assert eng2.registry.get("serving.phase.decode_dispatch_s").count > 0
