@@ -9,6 +9,9 @@ from .llama import (LlamaConfig, LlamaModel, LlamaForCausalLM,  # noqa: F401
                     llama_shard_fn, llama_tiny, llama_7b)
 from .ouro import (OuroConfig, OuroStack, OuroModel,  # noqa: F401
                    OuroForCausalLM, ouro_tiny)
+from .jamba import (JambaConfig, JambaModel, JambaForCausalLM,  # noqa: F401
+                    JambaAttention, JambaMambaMixer, JambaDecoderLayer,
+                    jamba_tiny)
 from .gpt_moe import (GPTMoEConfig, GPTMoEForCausalLM,  # noqa: F401
                       gpt_moe_tiny)
 from .bert import (BertConfig, BertModel, BertForMaskedLM,  # noqa: F401

@@ -557,6 +557,13 @@ def _export_programs(core, writer: AOTStoreWriter) -> None:
     writer.add("scatter", exported, build_s=time.perf_counter() - t0)
 
 
+# why a model that declares a recurrent state (kv_pool.
+# recurrent_state_spec) is neither built into a store nor served from one
+RECURRENT_STATE_REFUSAL = (
+    "the AOT store's program signatures carry no recurrent state "
+    "operands: a model that declares one is served by tracing")
+
+
 def build_engine_store(path: str, core,
                        manifest: Optional[Dict[str, Any]] = None
                        ) -> Dict[str, Any]:
@@ -570,6 +577,8 @@ def build_engine_store(path: str, core,
     enabled: the manifest plane holds the gather/scatter programs, and
     publish refuses an incomplete store.  Returns the published index.
     """
+    if core.pool.state_bytes_per_slot:
+        raise AOTStoreError(RECURRENT_STATE_REFUSAL)
     if manifest is None:
         manifest = _default_manifest()
     plane = manifest.get("planes", {}).get(ENGINE_PLANE)

@@ -24,6 +24,14 @@ rule is the design constraint):
     and mid-prefill slots ride along as no-ops: their rows decode garbage
     that nothing reads, their writes land at positions a later adopt
     overwrites wholesale;
+    A model with a RECURRENT state (``model.recurrent_state_spec``: a
+    state-space layer's, of fixed size per request) has it carried
+    beside the KV slabs as an opaque pytree: ``[num_slots, ...]`` arrays
+    in the pool, donated through this same program; ``[1, ...]`` arrays
+    in a prefill's staging, threaded chunk to chunk, with the chunk's
+    ``valid`` count handed to the model (a recurrence does not forgive
+    padding as a length mask does) and written over the slot's row at
+    ``adopt``.  A free slot's state row rides along like its KV row;
   * ``verify``   — ONE program (speculative decoding, ``spec_k > 0``):
     ``[num_slots, spec_k+1]`` draft windows — each slot's last committed
     token followed by its host-proposed n-gram draft (serving/spec.py) —
@@ -70,11 +78,12 @@ import jax.numpy as jnp
 
 from ..models.generation import _filter_top_p
 from ..nn.functional_call import bind_state, state
-from .aot import AOTStoreError, engine_aot_context, aot_fingerprint
+from .aot import (AOTStoreError, RECURRENT_STATE_REFUSAL,
+                  aot_fingerprint, engine_aot_context)
 from .errors import EngineStalledError, RequestRejected
 from .health import (DegradationLadder, EngineHealth,
                      FaultToleranceConfig)
-from .kv_pool import BlockPool, KVPool
+from .kv_pool import BlockPool, KVPool, recurrent_state_spec, zero_state
 from .metrics import ServingMetrics
 from .prefix_cache import MatchResult, PrefixCache
 from .scheduler import Request, Scheduler
@@ -105,7 +114,9 @@ __memory_bytes__ = {
     "row_state._keys": "8 * num_slots",
     "row_state._sampling_dev": "13 * num_slots",
     "row_state._mask_dev": "num_slots * vocab_size",
-    "staging": "2 * num_layers * max_seq * kv_heads * head_dim * itemsize",
+    "row_state.recurrent_state": "num_slots * state_bytes_per_slot",
+    "staging": "2 * num_layers * max_seq * kv_heads * head_dim * itemsize"
+               " + state_bytes_per_slot",
 }
 
 # token-readback encoding of the device-side health check: a decode row
@@ -286,15 +297,16 @@ class _Prefill:
     from the radix cache's matched blocks), and the scheduler's chunk
     plan drives one decode_step append per chunk."""
 
-    __slots__ = ("req", "slot", "ks", "vs", "plan", "next_chunk", "match",
-                 "last_logits")
+    __slots__ = ("req", "slot", "ks", "vs", "state", "plan", "next_chunk",
+                 "match", "last_logits")
 
     def __init__(self, req: Request, slot: int, ks, vs, plan,
-                 match: Optional[MatchResult]):
+                 match: Optional[MatchResult], state=()):
         self.req = req
         self.slot = slot
         self.ks = ks                # staging caches, threaded per chunk
         self.vs = vs
+        self.state = state          # the model's recurrent state, ditto
         self.plan = plan            # [(offset, width, valid), ...]
         self.next_chunk = 0
         self.match = match
@@ -342,6 +354,16 @@ class EngineCore:
             raise ValueError("block_len must be >= 1")
         if max_queue is not None and max_queue < 1:
             raise ValueError("max_queue must be >= 1 (or None)")
+        # what this model says the engine may not do with it, each with
+        # its reason (a model with a recurrent state: models/jamba.py)
+        self.model_refusals: Dict[str, str] = dict(
+            getattr(model, "serving_refusals", dict)())
+        if enable_prefix_cache and "prefix_cache" in self.model_refusals:
+            raise ValueError(
+                "enable_prefix_cache=True (and the fleet handoff built "
+                "on it): " + self.model_refusals["prefix_cache"])
+        if aot_store is not None and recurrent_state_spec(model):
+            raise AOTStoreError(RECURRENT_STATE_REFUSAL)
         self.model = model
         self.num_slots = num_slots
         self.prefill_chunk = prefill_chunk
@@ -657,6 +679,9 @@ class EngineCore:
                                   mesh=self.mesh)
         self.pool.faults = self.faults
         self.metrics.set_kv_planes(self.pool.planes)
+        self.metrics.set_state_bytes(self.pool.state_bytes_per_slot)
+        # whether the programs carry a recurrent state beside the slabs
+        self._stateful = bool(self.pool.state_bytes_per_slot)
         self.prefix_cache: Optional[PrefixCache] = None
         self.block_pool: Optional[BlockPool] = None
         # once the degradation ladder bypassed the cache, a quarantine
@@ -752,22 +777,33 @@ class EngineCore:
         return state(self.model)
 
     def _build_prefill_fn(self) -> Callable:
-        model = self.model
+        model, stateful = self.model, self._stateful
         params, buffers = self._model_weights()
 
-        def prefill(params, ks, vs, ids, pos, valid):
+        def prefill(params, ks, vs, ids, pos, valid, state=None):
             self.trace_counts["prefill"] += 1  # trace-time side effect
             caches = [(k, v, pos) for k, v in zip(ks, vs)]
             with bind_state(model, params, buffers):
-                logits, caches = model.decode_step(ids, caches, pos)
+                if not stateful:
+                    logits, caches = model.decode_step(ids, caches, pos)
+                else:
+                    # padding past ``valid`` must leave a recurrent
+                    # state as token ``valid - 1`` left it: the count
+                    # goes INTO the model
+                    logits, caches, state = model.decode_step(
+                        ids, caches, pos, state=state, valid=valid)
             last = jnp.take_along_axis(
                 logits, (valid - 1)[None, None, None], axis=1)[0, 0]
-            return (last.astype(jnp.float32),
-                    [c[0] for c in caches], [c[1] for c in caches])
+            out = (last.astype(jnp.float32),
+                   [c[0] for c in caches], [c[1] for c in caches])
+            return out + (state,) if stateful else out
 
-        # donating the staging rows threads them chunk to chunk in place
+        # donating the staging rows (and state) threads them chunk to
+        # chunk in place; a model without a recurrent state is called
+        # without the operand and lowers to the program it always did
+        donate = (1, 2, 6) if stateful else (1, 2)
         return functools.partial(
-            jax.jit(prefill, donate_argnums=(1, 2)), params)
+            jax.jit(prefill, donate_argnums=donate), params)
 
     def _prefill_cost(self, req: Request) -> int:
         """Tokens of prefill work admitting ``req`` costs THIS step: the
@@ -904,6 +940,7 @@ class EngineCore:
                     match, matched = None, 0
                 t_match1 = time.perf_counter()
             t_gather0 = time.perf_counter()
+            state = ()      # a recurrent state, where the model has one
             if matched:
                 try:
                     ks, vs = self.prefix_cache.load_staging(match)
@@ -919,7 +956,10 @@ class EngineCore:
                 # eager jnp.zeros dispatches per miss admission
                 if self._staging_init_fn is None:
                     self._staging_init_fn = self._build_staging_init_fn()
-                ks, vs = self._staging_init_fn()
+                staged = self._staging_init_fn()
+                ks, vs = staged[:2]
+                if self._stateful:
+                    state = staged[2]
             t_gather1 = time.perf_counter()
             plan = self.scheduler.chunk_plan(matched, req.prompt_len,
                                              self.prefill_chunk)
@@ -947,7 +987,8 @@ class EngineCore:
                                     request=rid)
                 tracer.add_span("gather", lane, t_gather0, t_gather1,
                                 hit=bool(matched), request=rid)
-            self._prefills.append(_Prefill(req, slot, ks, vs, plan, match))
+            self._prefills.append(_Prefill(req, slot, ks, vs, plan, match,
+                                           state=state))
             self.progress_counter += 1          # admission = progress
         except BaseException:
             if match is not None:
@@ -963,9 +1004,14 @@ class EngineCore:
         prefill trace per width."""
         model, max_seq = self.model, self.pool.max_seq
 
+        state_spec = self.pool.state_spec if self._stateful else None
+
         def fresh_staging():
             caches = model.init_cache(1, max_seq)
-            return [c[0] for c in caches], [c[1] for c in caches]
+            rows = [c[0] for c in caches], [c[1] for c in caches]
+            if state_spec is None:
+                return rows
+            return rows + (zero_state(state_spec, 1),)
 
         sharding = None
         if self.mesh is not None:
@@ -991,9 +1037,15 @@ class EngineCore:
         ids = np.zeros((1, width), np.int32)
         ids[0, :valid] = np.asarray(st.req.prompt[off:off + valid],
                                     np.int32)
-        last_logits, st.ks, st.vs = self._prefill_fn(
-            st.ks, st.vs, jnp.asarray(ids),
-            jnp.asarray(off, jnp.int32), jnp.asarray(valid, jnp.int32))
+        if self._stateful:
+            last_logits, st.ks, st.vs, st.state = self._prefill_fn(
+                st.ks, st.vs, jnp.asarray(ids),
+                jnp.asarray(off, jnp.int32), jnp.asarray(valid, jnp.int32),
+                st.state)
+        else:
+            last_logits, st.ks, st.vs = self._prefill_fn(
+                st.ks, st.vs, jnp.asarray(ids),
+                jnp.asarray(off, jnp.int32), jnp.asarray(valid, jnp.int32))
         t1 = time.perf_counter()
         st.next_chunk += 1
         st.req.prefill_chunks += 1
@@ -1003,7 +1055,11 @@ class EngineCore:
         self.metrics.tracer.add_span(
             "prefill_chunk", self._lane(st.req), t0, t1,
             chunk=st.next_chunk - 1, width=width, tokens=valid,
-            request=st.req.request_id)
+            request=st.req.request_id,
+            # whether the chunk started from an earlier chunk's
+            # recurrent state (False on a request's first, and always
+            # for a model that carries none)
+            state_carried=self._stateful and st.next_chunk > 1)
         if st.done:
             st.last_logits = last_logits
 
@@ -1045,7 +1101,8 @@ class EngineCore:
             from .spec import NGramDraftTable
             draft = NGramDraftTable()
             draft.seed(req.prompt)
-        self.pool.adopt(slot, list(zip(st.ks, st.vs)), req.prompt_len)
+        self.pool.adopt(slot, list(zip(st.ks, st.vs)), req.prompt_len,
+                        state=st.state if self._stateful else None)
         self._slots[slot] = _Slot(req, req.prompt_len, match=st.match,
                                   draft=draft, allowed=allowed)
         self._last_tok = self._last_tok.at[slot].set(first[0])
@@ -1191,6 +1248,9 @@ class EngineCore:
             self.spec_on = False
             self.spec_fallback_reason = \
                 "spec_k=0 (speculation not requested)"
+        elif "speculation" in self.model_refusals:
+            self.spec_on = False
+            self.spec_fallback_reason = self.model_refusals["speculation"]
         elif self.pool.max_seq <= self.spec_k + 1:
             self.spec_on = False
             self.spec_fallback_reason = (
@@ -1263,6 +1323,21 @@ class EngineCore:
             return "in_kernel", None
         return "xla_scatter", why
 
+    def scan_route(self):
+        """``(route, reason)`` of the model's recurrence over positions,
+        ``("", None)`` for a model without one: which form runs it in
+        the prefill programs (at the widest chunk) and in the decode
+        program, as the model says (``model.recurrence_route(width)``,
+        static per compiled program like :meth:`attention_route`), as
+        ``"prefill=<form>,decode=<form>"``."""
+        route_of = getattr(self.model, "recurrence_route", None)
+        if route_of is None:
+            return "", None
+        width = self.prefill_chunk or max(self._warm_buckets or (1,))
+        chunk, why = route_of(width)
+        step, _ = route_of(1)
+        return f"prefill={chunk},decode={step}", why
+
     def _emit_decode_block(self) -> None:
         """The discrete obs event that marks WHICH path this engine's
         single decode program compiled with (and why, on fallback) —
@@ -1274,6 +1349,7 @@ class EngineCore:
         (glossary: docs/observability.md)."""
         route, why = self.attention_route()
         append, append_why = self.kv_append()
+        scan, scan_why = self.scan_route()
         self.metrics.on_decode_block(
             active=self.decode_path in ("fused", "tp_fused_block"),
             reason=None if not self.fused_decode
@@ -1281,10 +1357,11 @@ class EngineCore:
             step=self._step_in_flight,
             tp=self.tensor_parallel,
             attention_route=route, attention_reason=why,
-            kv_append=append, kv_append_reason=append_why)
+            kv_append=append, kv_append_reason=append_why,
+            scan_route=scan, scan_reason=scan_why)
 
     def _build_decode_fn(self) -> Callable:
-        model = self.model
+        model, stateful = self.model, self._stateful
         fused = self.decode_path == "fused"
         self._emit_decode_block()
         if self.decode_path in ("tp_fused", "tp_fused_block"):
@@ -1293,14 +1370,19 @@ class EngineCore:
         params, buffers = self._model_weights()
 
         def decode(params, ks, vs, seq_pos, last_tok, keys, do_sample,
-                   temperature, top_k, top_p, mask):
+                   temperature, top_k, top_p, mask, state=None):
             self.trace_counts["decode"] += 1  # trace-time side effect
             caches = [(k, v, seq_pos) for k, v in zip(ks, vs)]
             step_fn = model.fused_decode_step if fused else \
                 model.decode_step
             with bind_state(model, params, buffers):
-                logits, caches = step_fn(last_tok[:, None], caches,
-                                         seq_pos)
+                if not stateful:
+                    logits, caches = step_fn(last_tok[:, None], caches,
+                                             seq_pos)
+                else:
+                    # every slot's row advances, parked ones included
+                    logits, caches, state = step_fn(
+                        last_tok[:, None], caches, seq_pos, state=state)
             split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
             nxt = sample_rows(split[:, 1], logits[:, 0], do_sample,
                               temperature, top_k, top_p, mask=mask)
@@ -1310,13 +1392,16 @@ class EngineCore:
             nxt = finite_or_sentinel(logits[:, 0], nxt)
             new_ks = [c[0] for c in caches]
             new_vs = [c[1] for c in caches]
-            return (new_ks, new_vs, _advance_live(seq_pos, caches[0][2]),
-                    nxt.astype(jnp.int32), split[:, 0])
+            out = (new_ks, new_vs, _advance_live(seq_pos, caches[0][2]),
+                   nxt.astype(jnp.int32), split[:, 0])
+            return out + (state,) if stateful else out
 
-        # donating the KV slabs aliases them in place — pool memory stays
-        # a single allocation across the whole serving run
+        # donating the KV slabs (and the recurrent state, where the
+        # model has one) aliases them in place — pool memory stays a
+        # single allocation across the whole serving run
+        donate = (1, 2, 11) if stateful else (1, 2)
         return functools.partial(
-            jax.jit(decode, donate_argnums=(1, 2)), params)
+            jax.jit(decode, donate_argnums=donate), params)
 
     def _build_tp_decode_fn(self) -> Callable:
         """The tensor-parallel fused compute-collective decode: ONE
@@ -1381,10 +1466,17 @@ class EngineCore:
                                   jnp.asarray(self._top_p))
         if self._mask_dev is None:
             self._mask_dev = jnp.asarray(self._mask_host)
-        ks, vs, pos, nxt, self._keys = self._decode_fn(
-            self.pool.ks, self.pool.vs, self.pool.seq_pos,
-            self._last_tok, self._keys, *self._sampling_dev,
-            self._mask_dev)
+        if self._stateful:
+            ks, vs, pos, nxt, self._keys, self.pool.state = \
+                self._decode_fn(
+                    self.pool.ks, self.pool.vs, self.pool.seq_pos,
+                    self._last_tok, self._keys, *self._sampling_dev,
+                    self._mask_dev, self.pool.state)
+        else:
+            ks, vs, pos, nxt, self._keys = self._decode_fn(
+                self.pool.ks, self.pool.vs, self.pool.seq_pos,
+                self._last_tok, self._keys, *self._sampling_dev,
+                self._mask_dev)
         self.pool.ks, self.pool.vs, self.pool.seq_pos = ks, vs, pos
         self._last_tok = nxt
         return nxt
@@ -1630,6 +1722,10 @@ class EngineCore:
             if self._slots:
                 counts["active_slots"] = len(self._slots)
                 counts["loop_passes"] = self.loop_passes
+                # slots whose recurrent state this step's decode program
+                # reads and writes: all of them, parked ones included
+                counts["state_slots"] = self.num_slots \
+                    if self._stateful else 0
                 # free slots read False in the mirror, so this counts
                 # occupied slots only; > 0 exactly when the decode
                 # program's sampling branch runs this step

@@ -12,11 +12,21 @@ The pool's per-layer view ``(k, v, pos_vector)`` is EXACTLY the models'
 functional cache tuple with a per-row position (models/kv_cache.py), so
 ``model.decode_step`` runs over all slots unchanged — one fixed-shape
 compiled program regardless of which slots are live.
+
+A model may carry a SECOND kind of per-request state: a recurrent state
+of fixed shape, whatever the request's length (a state-space layer's).
+The model declares it per slot (``recurrent_state_spec``, a pytree of
+``jax.ShapeDtypeStruct``; :func:`recurrent_state_spec`), and the pool
+holds it as ``state``, the same pytree of ``[num_slots, ...]`` arrays,
+opaque here: made with the pool, overwritten wholesale by ``adopt``,
+threaded through the decode program by the engine.  Empty (``()``) for
+every model that declares none.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import List, Optional, Tuple
 
 import jax
@@ -24,7 +34,8 @@ import jax.numpy as jnp
 
 from ..models.kv_cache import gather_block_rows, scatter_block_rows
 
-__all__ = ["KVPool", "BlockPool", "cache_geometry"]
+__all__ = ["KVPool", "BlockPool", "cache_geometry",
+           "recurrent_state_spec", "state_bytes", "zero_state"]
 
 # graftmem marker (tools/analysis/memory.py): every slab extent in the
 # pool constructors below must flow from registered capacity fields —
@@ -53,6 +64,37 @@ def cache_geometry(cfg) -> Tuple[int, int, int]:
     return planes, planes // per_slab, per_slab * kv_heads
 
 
+def recurrent_state_spec(model):
+    """The per-slot recurrent state ``model`` declares beside its KV
+    rows: a pytree of ``jax.ShapeDtypeStruct`` (shapes WITHOUT the slot
+    axis), ``()`` where the model declares none."""
+    declare = getattr(model, "recurrent_state_spec", None)
+    return () if declare is None else declare()
+
+
+def state_bytes(spec) -> int:
+    """Bytes one slot's recurrent state holds."""
+    return sum(math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+               for s in jax.tree_util.tree_leaves(spec))
+
+
+def zero_state(spec, rows: int):
+    """``spec``'s pytree as ``[rows, ...]`` arrays of zeros: where a
+    fresh sequence starts its recurrence."""
+    return jax.tree_util.tree_map(
+        lambda s: jnp.zeros((rows,) + tuple(s.shape), s.dtype), spec)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _adopt_state(state, rows, slot):
+    """Write a request's ``[1, ...]`` recurrent state into row ``slot``
+    of every ``[num_slots, ...]`` leaf: ONE program for the pytree."""
+    return jax.tree_util.tree_map(
+        lambda buf, row: jax.lax.dynamic_update_slice(
+            buf, row.astype(buf.dtype), (slot,) + (0,) * (buf.ndim - 1)),
+        state, rows)
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _adopt_row(buf, row, slot):
     """Write a [1, max_seq, h, d] prefilled row into slab row ``slot``.
@@ -67,7 +109,11 @@ class KVPool:
       * ``ks/vs``   — per-layer [num_slots, max_seq, kv_heads, head_dim];
       * ``seq_pos`` — [num_slots] int32, each slot's current cache length
         (the per-row ``pos`` the models append at AND the ``seq_lens`` the
-        ragged attention masks by, after the in-step +1).
+        ragged attention masks by, after the in-step +1);
+      * ``state``   — the model's recurrent state, a pytree of
+        ``[num_slots, ...]`` arrays (``()`` for most models).  A free
+        slot's row keeps whatever its last occupant (and the ride-along
+        decode steps since) left: ``adopt`` overwrites it wholesale.
 
     Host state: the free list.  Alloc/free/reset are host-side list ops —
     no device sync, no reallocation.
@@ -75,9 +121,12 @@ class KVPool:
 
     def __init__(self, num_slots: int, max_seq: int, num_layers: int,
                  kv_heads: int, head_dim: int, dtype=jnp.float32,
-                 mesh=None, planes: Optional[int] = None):
+                 mesh=None, planes: Optional[int] = None, state_spec=()):
         if num_slots < 1:
             raise ValueError("num_slots must be >= 1")
+        if mesh is not None and jax.tree_util.tree_leaves(state_spec):
+            raise ValueError("a recurrent state has no tensor-parallel "
+                             "layout")
         if mesh is not None and kv_heads % mesh.devices.size:
             raise ValueError(
                 f"kv_heads {kv_heads} must divide evenly over the "
@@ -112,6 +161,9 @@ class KVPool:
             self.vs = [mk() for _ in range(num_layers)]
             self.seq_pos = replicated(
                 jnp.zeros((num_slots,), jnp.int32), mesh)
+        self.state_spec = state_spec
+        self.state_bytes_per_slot = state_bytes(state_spec)
+        self.state = zero_state(state_spec, num_slots)
         self._free: List[int] = list(range(num_slots - 1, -1, -1))
         # lifetime slot-churn counters (telemetry: metrics_dict reports
         # them; high churn relative to finished requests = thrashing)
@@ -131,7 +183,8 @@ class KVPool:
         max_seq = max_seq or cfg.max_seq_len
         planes, slabs, slab_heads = cache_geometry(cfg)
         return cls(num_slots, max_seq, slabs, slab_heads, cfg.head_dim,
-                   dtype=jnp.dtype(cfg.dtype), mesh=mesh, planes=planes)
+                   dtype=jnp.dtype(cfg.dtype), mesh=mesh, planes=planes,
+                   state_spec=recurrent_state_spec(model))
 
     # ------------------------------------------------------------ slots
     @property
@@ -175,12 +228,14 @@ class KVPool:
             self.seq_pos = replicated(self.seq_pos, self.mesh)
 
     def adopt(self, slot: int, layer_caches, length: int,
-              set_pos: bool = True) -> None:
+              set_pos: bool = True, state=None) -> None:
         """Move a freshly prefilled single-request cache (per-layer
         ``(k [1, max_seq, h, d], v, _)`` tuples) into ``slot`` and record
         its ``length`` valid positions.  The copy is a jitted
         dynamic_update_slice with a traced slot index — admitting to a
-        different slot never recompiles.
+        different slot never recompiles.  ``state`` is the request's
+        recurrent state after its last prompt token (``[1, ...]``
+        leaves), written over the slot's row wholesale.
 
         ``set_pos=False`` skips the position write: the fleet KV handoff
         (serving/handoff.py) stages transferred rows through a transient
@@ -191,6 +246,11 @@ class KVPool:
         for i, layer in enumerate(layer_caches):
             self.ks[i] = _adopt_row(self.ks[i], layer[0], s)
             self.vs[i] = _adopt_row(self.vs[i], layer[1], s)
+        if self.state_bytes_per_slot:
+            if state is None:
+                raise ValueError("adopt: the pool holds a recurrent "
+                                 "state and was given none")
+            self.state = _adopt_state(self.state, state, s)
         if set_pos:
             self.seq_pos = self.seq_pos.at[slot].set(length)
 

@@ -44,7 +44,7 @@ STEP_PHASES = ("admission", "prefill", "first_token_readback", "draft",
 # host ints the engine already holds, set on the ``serving.step`` span
 STEP_COUNTS = ("admitted", "prefill_tokens", "prefills_completed",
                "active_slots", "sampling_slots", "live_kv_rows",
-               "loop_passes", "new_tokens", "queue_depth")
+               "loop_passes", "state_slots", "new_tokens", "queue_depth")
 
 # admission-projection clamps: a degenerate measurement window (one
 # finish inside a denormal-small busy window, or a finish against an
@@ -233,6 +233,10 @@ class ServingMetrics:
         self._g_kv_planes = reg.gauge("serving.kv.planes",
                                       "KV planes one cached position "
                                       "spans (layers x passes)")
+        self._g_state_bytes = reg.gauge(
+            "serving.state.bytes_per_slot",
+            "bytes of recurrent state a slot holds beside its KV rows "
+            "(0 for a model without one)")
         # zero-cold-start surface (docs/serving.md "Zero cold start"):
         # warm-load accounting for the AOT program store.  The event
         # counters window-reset with the rest; the two gauges are
@@ -338,7 +342,9 @@ class ServingMetrics:
                         attention_route: str = "",
                         attention_reason: Optional[str] = None,
                         kv_append: str = "",
-                        kv_append_reason: Optional[str] = None) -> None:
+                        kv_append_reason: Optional[str] = None,
+                        scan_route: str = "",
+                        scan_reason: Optional[str] = None) -> None:
         """The engine resolved its decode path (emitted once, when the
         single decode program is built): ``active`` says whether the
         fused decode-block kernels compiled in, ``reason`` carries the
@@ -354,7 +360,11 @@ class ServingMetrics:
         ``kv_append`` is who writes a step's fresh rows into them
         (``in_kernel``: that attention kernel, by DMA; ``xla_scatter``:
         ``kv_cache.append_kv`` ahead of it) and ``kv_append_reason`` why
-        it is not ``in_kernel``.  Lands
+        it is not ``in_kernel``; ``scan_route`` names the form that
+        runs a model's recurrence over positions in the prefill and
+        decode programs (``prefill=<form>,decode=<form>``,
+        kernels/selective_scan.py; empty for a model without one) and
+        ``scan_reason`` why the prefill form is not the kernel.  Lands
         as a ``decode_block`` discrete event on the engine lane
         (glossary: docs/observability.md)."""
         self.tracer.event("decode_block", lane=self.engine_lane,
@@ -364,7 +374,9 @@ class ServingMetrics:
                           attention_route=attention_route,
                           attention_reason=attention_reason or "",
                           kv_append=kv_append,
-                          kv_append_reason=kv_append_reason or "")
+                          kv_append_reason=kv_append_reason or "",
+                          scan_route=scan_route,
+                          scan_reason=scan_reason or "")
 
     def on_aot_load(self, programs: int, seconds: float,
                     build_s: Optional[float] = None) -> None:
@@ -402,6 +414,9 @@ class ServingMetrics:
 
     def set_kv_planes(self, planes: int) -> None:
         self._g_kv_planes.set(planes)
+
+    def set_state_bytes(self, nbytes: int) -> None:
+        self._g_state_bytes.set(nbytes)
 
     def on_compile(self, program: str, n: int = 1) -> None:
         self._c_compiles.inc(n)

@@ -97,7 +97,12 @@ def serving_refusal(model) -> Optional[str]:
     all, or None.  The slot and block slabs partition on their head
     axis (``KV_SLAB_SPEC``); a cache that keeps several planes on that
     axis (``cfg.cache_planes_per_slab``, models/ouro.py) would be split
-    by plane, not by head, and its model has no Megatron layout here."""
+    by plane, not by head, and its model has no Megatron layout here.
+    A model may also refuse for itself (``serving_refusals()``:
+    models/jamba.py's recurrent state has no layout over the mesh)."""
+    own = getattr(model, "serving_refusals", dict)().get("tensor_parallel")
+    if own is not None:
+        return own
     per_slab = getattr(model.cfg, "cache_planes_per_slab", None) or 1
     if per_slab > 1:
         return (f"the model's cache slab holds {per_slab} KV planes on "

@@ -189,6 +189,10 @@ ALLOWLIST: Dict[str, str] = {
         # pool sizing control plane, not array ops; contract =
         # tests/test_ouro.py
         "cache_geometry", "serving_refusal",
+        # recurrent state (ISSUE 32): what a model declares a slot holds
+        # beside its KV rows, and its bytes — pool sizing control plane,
+        # not array ops; contract = tests/test_jamba.py
+        "recurrent_state_spec", "state_bytes", "zero_state",
     )},
     # ---- paddle_tpu.obs public surface (the OBS registry surface:
     #      counters/gauges/histograms and the span tracer are telemetry

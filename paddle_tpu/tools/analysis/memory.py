@@ -92,6 +92,9 @@ DEFAULT_CAPACITY_FIELDS = frozenset({
     "num_slots", "max_seq", "num_layers", "kv_heads", "head_dim",
     "num_blocks", "block_len", "blocks_per_row", "num_heads", "hidden",
     "vocab_size", "ffn", "itemsize", "spec_k",
+    # bytes of recurrent state a slot holds beside its KV rows (what the
+    # model declares in ``recurrent_state_spec``; 0 for most)
+    "state_bytes_per_slot",
 })
 _EXTRA_CAPACITY_FIELDS: List[str] = []
 
@@ -390,6 +393,7 @@ REFERENCE_ENV: Dict[str, int] = {
     "vocab_size": 32768, "hidden": 768, "num_heads": 12, "kv_heads": 12,
     "head_dim": 64, "ffn": 3072, "num_layers": 12, "max_seq": 1024,
     "num_slots": 8, "block_len": 16, "num_blocks": 512, "itemsize": 2,
+    "state_bytes_per_slot": 0,
 }
 
 # every tiling the static VMEM check proves: the flagship decode shape

@@ -2,7 +2,9 @@
 KV cache, against its family's plain float32 ``highest`` reference, at
 the configuration's full size on seeded weights.
 
-    python3 scripts/looped_reference_check.py --config benchmarks/configs/ouro-2.6b.json --seed 1
+    python3 scripts/served_reference_check.py --config benchmarks/configs/ouro-2.6b.json --seed 1
+    python3 scripts/served_reference_check.py --config benchmarks/configs/jamba2-3b.json --seed 1 \
+        --prompts 1500,700 --new 64 --max-seq 4096 --width 512
 
 The benchmark's ``correct`` judges sampled TOKENS of the engine
 (``argmax_gap``); this reads the LOGITS the same programs' model code
@@ -10,12 +12,16 @@ produces, which the engine never hands out: ``max|system - reference| /
 max|reference|`` over every compared position, beside ``argmax_gap`` of
 the system's own argmax.  Written for PR 28's first chip experiment (a
 model that passes 192 layer applications where the 0.03 bar of
-``benchmarks/lib/reference.py`` was set at 4-8).  Two requests of
-different lengths: each prefilled alone into a one-row cache, as the
-engine prefills, then decoded together as one ragged batch with per-row
-positions, fed the sequence's own next tokens so that system and
-reference see the same inputs.  Needs a TPU, like ``benchmarks/run.py``;
-prints one JSON line.
+``benchmarks/lib/reference.py`` was set at 4-8), and since PR 32 also
+the check of a model that carries a RECURRENT state (``model.
+recurrent_state_spec``): its state rides beside the caches, chunk to
+chunk and step to step, and every chunk is given its ``valid`` count, as
+the engine's prefill program gives it.  Two requests of different
+lengths: each prefilled alone into a one-row cache in chunks of
+``--width`` tokens (the last one right-padded), as the engine prefills,
+then decoded together as one ragged batch with per-row positions, fed
+the sequence's own next tokens so that system and reference see the same
+inputs.  Needs a TPU, like ``benchmarks/run.py``; prints one JSON line.
 """
 
 import argparse
@@ -59,12 +65,18 @@ def main(argv=None) -> int:
     jax.block_until_ready(params)
     R.log(f"model built in {time.perf_counter() - t0:.1f}s")
 
-    def step(params, caches, ids, pos):
-        with bind_state(model, params, buffers):
-            logits, caches = model.decode_step(ids, caches, pos)
-        return logits.astype(jnp.float32), caches
+    stateful = hasattr(model, "recurrent_state_spec")
 
-    step = functools.partial(jax.jit(step, donate_argnums=(1,)), params)
+    def step(params, caches, ids, pos, valid, state):
+        with bind_state(model, params, buffers):
+            if stateful:
+                logits, caches, state = model.decode_step(
+                    ids, caches, pos, state=state, valid=valid)
+            else:
+                logits, caches = model.decode_step(ids, caches, pos)
+        return logits.astype(jnp.float32), caches, state
+
+    step = functools.partial(jax.jit(step, donate_argnums=(1, 5)), params)
     lens = [int(n) for n in args.prompts.split(",")]
     rs = np.random.default_rng(np.random.SeedSequence([args.seed, 9]))
     seqs = [rs.integers(0, mcfg.vocab_size, n + args.new, dtype=np.int32)
@@ -72,19 +84,28 @@ def main(argv=None) -> int:
 
     system, rows = [[] for _ in lens], []
     for r, (n, seq) in enumerate(zip(lens, seqs)):
-        ids = np.zeros((1, args.width), np.int32)
-        ids[0, :n] = seq[:n]
+        cache = model.init_cache(1, args.max_seq)
+        state = model.init_state(1) if stateful else ()
         t0 = time.perf_counter()
-        logits, cache = step(model.init_cache(1, args.max_seq),
-                             jnp.asarray(ids), jnp.asarray(0, jnp.int32))
-        system[r].append(np.asarray(logits)[0, :n])
-        R.log(f"prefill of {n} tokens at width {args.width}: "
+        for off in range(0, n, args.width):
+            valid = min(args.width, n - off)
+            ids = np.zeros((1, args.width), np.int32)
+            ids[0, :valid] = seq[off:off + valid]
+            logits, cache, state = step(
+                [(c[0], c[1], jnp.asarray(off, jnp.int32)) for c in cache],
+                jnp.asarray(ids), jnp.asarray(off, jnp.int32),
+                jnp.asarray(valid, jnp.int32), state)
+            system[r].append(np.asarray(logits)[0, :valid])
+        R.log(f"prefill of {n} tokens in chunks of {args.width}: "
               f"{time.perf_counter() - t0:.2f}s (the first compiles)")
-        rows.append(cache)
-    # the ragged batch: every slab's rows side by side, per-row positions
-    caches = [tuple(jnp.concatenate([c[i][j] for c in rows], 0)
+        rows.append((cache, state))
+    # the ragged batch: every slab's rows (and every state leaf's) side
+    # by side, per-row positions
+    caches = [tuple(jnp.concatenate([c[i][j] for c, _ in rows], 0)
                     for j in (0, 1)) + (None,)
-              for i in range(len(rows[0]))]
+              for i in range(len(rows[0][0]))]
+    state = jax.tree_util.tree_map(lambda *leaves: jnp.concatenate(leaves, 0),
+                                   *[st for _, st in rows])
     del rows
     pos = np.asarray(lens, np.int32)
     walls = []
@@ -92,14 +113,14 @@ def main(argv=None) -> int:
         ids = np.stack([seq[n + k] for n, seq in zip(lens, seqs)])[:, None]
         t0 = time.perf_counter()
         # the caches are donated: their position is an array of its own
-        logits, caches = step(
+        logits, caches, state = step(
             [(c[0], c[1], jnp.asarray(pos + k)) for c in caches],
-            jnp.asarray(ids), jnp.asarray(pos + k))
+            jnp.asarray(ids), jnp.asarray(pos + k), None, state)
         logits = np.asarray(logits)
         walls.append(time.perf_counter() - t0)
         for r in range(len(lens)):
             system[r].append(logits[r])
-    del caches
+    del caches, state
     system = [np.concatenate(s, 0) for s in system]
 
     refs = reference.reference_logits(builder, cfg, params, seqs)

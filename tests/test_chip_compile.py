@@ -165,6 +165,76 @@ def test_looped_decode_step_moves_no_plane(topo, monkeypatch):
     assert mem.alias_size_in_bytes >= 2 * plane * cfg.num_cache_layers
 
 
+@pytest.mark.parametrize("width", [512, 16])
+def test_selective_scan(topo, width):
+    """The state-space recurrence over one prefill chunk at Jamba2-3B's
+    widths (d_inner 5120, d_state 16): ``[N, 8, 128]`` state tiles in
+    vregs, B and C as SMEM scalars.  ``temp`` holds nothing of ``[width,
+    16, 5120]``."""
+    from paddle_tpu.kernels import selective_scan as ss
+    s = _one(topo)
+    f32 = jnp.float32
+    d, n = 5120, 16
+    assert ss.scan_route(width, d) == ("pallas_chunk", None)
+    seq, bc = s((1, width, d), f32), s((1, width, n), f32)
+    mem = _compile(functools.partial(ss.selective_scan, interpret=False),
+                   seq, seq, s((n, d), f32), bc, bc, s((1, n, d), f32))
+    assert mem.temp_size_in_bytes < width * d * 4, mem.temp_size_in_bytes
+
+
+def test_hybrid_prefill_chunk_and_decode_step(topo, monkeypatch):
+    """Jamba2-3B's whole prefill program at the cell's chunk (512 tokens
+    into a 4096-row staging, the recurrent state carried and ``valid``
+    handed in) and its decode step over 16 slots, with the chip's
+    routes: 26 scan kernels, two attention layers of 20 queries a KV
+    head (the copying kernel, in sub-blocks of 64 tokens for a chunk:
+    512 x 20 query rows at once are 27 MB of its VMEM).  KV rows and
+    all 52 state arrays are updated in place."""
+    from paddle_tpu.models.jamba import JambaConfig, JambaForCausalLM
+    from paddle_tpu.nn.functional_call import bind_state, state
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = JambaConfig(max_seq_len=4096, dtype="bfloat16")
+    made = []
+
+    def make():
+        made.append(JambaForCausalLM(cfg).to(dtype=cfg.dtype))
+        return state(made[0])
+
+    s = _one(topo)
+    params, buffers = jax.tree.map(lambda x: s(x.shape, x.dtype),
+                                   jax.eval_shape(make))
+    model = made[0]
+    assert model.recurrence_route(512) == ("pallas_chunk", None)
+
+    def carried(batch):
+        return jax.tree.map(lambda z: s((batch,) + z.shape, z.dtype),
+                            model.recurrent_state_spec())
+
+    def step(params, ks, vs, ids, pos, valid, st):
+        caches = [(k, v, pos) for k, v in zip(ks, vs)]
+        with bind_state(model, params, buffers):
+            logits, caches, st = model.decode_step(ids, caches, pos,
+                                                   state=st, valid=valid)
+        return (logits[:, -1], [c[0] for c in caches],
+                [c[1] for c in caches], st)
+
+    i32 = jnp.int32
+    per_slot = 26 * (16 * 5120 * 4 + 3 * 5120 * 2)
+    slab = s((1, 4096, 1, 128))
+    mem = _compile(step, params, [slab, slab], [slab, slab],
+                   s((1, 512), i32), s((), i32), s((), i32), carried(1),
+                   donate_argnums=(1, 2, 6))
+    assert mem.alias_size_in_bytes >= 4 * 4096 * 128 * 2 + per_slot
+    assert mem.temp_size_in_bytes < 512 * 65536 * 4 + (64 << 20)
+    slab = s((16, 4096, 1, 128))
+    mem = _compile(lambda p, ks, vs, ids, pos, st: step(
+        p, ks, vs, ids, pos, None, st), params, [slab, slab], [slab, slab],
+        s((16, 1), i32), s((16,), i32), carried(16),
+        donate_argnums=(1, 2, 5))
+    assert mem.alias_size_in_bytes >= 16 * (4 * 4096 * 128 * 2 + per_slot)
+    assert mem.temp_size_in_bytes < 16 * per_slot // 2
+
+
 @pytest.mark.parametrize("kind", ["rms", "layer"])
 def test_fused_norm_fwd_bwd(topo, kind):
     from paddle_tpu.kernels.fused_norm import (fused_layer_norm_pallas,
@@ -289,4 +359,5 @@ def test_every_kernel_module_is_covered():
                     holders.add(name)
     assert holders == {"flash_attention.py", "decode_attention.py",
                        "fused_norm.py", "fused_adamw.py",
-                       "decode_block.py", "decode_block_tp.py"}, holders
+                       "decode_block.py", "decode_block_tp.py",
+                       "selective_scan.py"}, holders

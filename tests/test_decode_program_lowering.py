@@ -169,3 +169,58 @@ def test_free_slots_stay_parked_at_row_zero(spec_k):
                                      max_new_tokens=12))[0, 9:]
     assert list(eng.result(long_).tokens) == list(want)
     assert eng.result(short).finished
+
+
+# ------------------------------------------------- held to the parent's
+
+# sha256 of the StableHLO of the engine's decode program and of its
+# 16-wide prefill program at the models above, lowered for the TPU, as
+# PR 31's tree (869de36) lowers them under this suite's conftest
+# (``highest`` matmul precision, eight virtual devices).  A Pallas kernel's serialized body
+# carries the checkout's path in its locations, so each custom call's
+# ``backend_config`` is blanked before hashing (the kernels' own files
+# are what they were).  PR 32 threads a recurrent state through both
+# programs for the model that declares one: a model that declares none
+# is called without the operand and must lower to the program it always
+# did.  A PR that means to change these programs re-pins them and says
+# what moved.
+_PARENT_SHA256 = {
+    ("gpt", "decode"):
+    "d55080e61d8782bd77fad04eaf1509d6f9b6089cdd3306a02bb3be4872ea7f5a",
+    ("gpt", "prefill"):
+    "c3d853159b67a46c25c8bfb1e013117a75a6f7ed76e2d9a08bcb5a14f132f81d",
+    ("llama", "decode"):
+    "493c0f9bc34750d03113c00c002cab0c49086a47aaf9ed36d8c244159f3922a1",
+    ("llama", "prefill"):
+    "3295d651c084c24978f19776ceffac80c4e8391e2e3866109e6e22493b9da6fc",
+    ("ouro", "decode"):
+    "517f7023dd6d249a34a8037d68633b0ab9affd4c2921221132f66157f31b3e10",
+    ("ouro", "prefill"):
+    "4d9e9163e842dfa48352063bb342dfd573ea06f23a94128d9217d1b7083f29e0",
+}
+_KERNEL_BODY = re.compile(r'backend_config = "(?:[^"\\]|\\.)*"')
+
+
+def _prefill_stablehlo(core, width=16, valid=9):
+    program = core._build_prefill_fn()
+    ks, vs = core._build_staging_init_fn()()
+    return program.func.trace(
+        *program.args, ks, vs, jnp.zeros((1, width), jnp.int32),
+        jnp.asarray(0, jnp.int32), jnp.asarray(valid, jnp.int32)).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("family, program", sorted(_PARENT_SHA256))
+def test_a_stateless_models_programs_lower_as_on_the_parent(
+        family, program, monkeypatch):
+    import hashlib
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = {"gpt": lambda: _gpt(8, DH), "llama": _llama_gqa,
+             "ouro": _ouro}[family]()
+    core, text = _decode_stablehlo(model)
+    assert not core._stateful and core.pool.state == ()
+    if program == "prefill":
+        text = _prefill_stablehlo(core)
+    digest = hashlib.sha256(
+        _KERNEL_BODY.sub('backend_config = ""', text).encode()).hexdigest()
+    assert digest == _PARENT_SHA256[family, program]

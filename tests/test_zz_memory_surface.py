@@ -56,6 +56,8 @@ TINY_ENV = {
     "block_len": BLOCK_LEN,
     "num_blocks": NUM_SLOTS * (MAX_SEQ // BLOCK_LEN),
     "blocks_per_row": MAX_SEQ // BLOCK_LEN,
+    # recurrent state a slot holds beside its KV rows: none for GPT
+    "state_bytes_per_slot": 0,
 }
 
 
@@ -103,8 +105,15 @@ def _measured_block_bytes(bp):
 
 
 def _measured_staging(model):
+    """One in-flight prefill's staging: its KV rows and, where the
+    model carries one, its recurrent state."""
+    import jax
     cache = model.init_cache(1, MAX_SEQ)
-    return sum(layer[0].nbytes + layer[1].nbytes for layer in cache)
+    rows = sum(layer[0].nbytes + layer[1].nbytes for layer in cache)
+    if hasattr(model, "init_state"):
+        rows += sum(leaf.nbytes for leaf in
+                    jax.tree_util.tree_leaves(model.init_state(1)))
+    return rows
 
 
 def _check_pools_exact(manifest, eng, model, leg):
@@ -179,6 +188,48 @@ def test_static_bytes_equal_the_live_pool_for_every_served_family(
         # a cached row: 2 x planes x kv_heads x head_dim x itemsize
         row = 2 * planes * model_kv_heads * model.cfg.head_dim * 4
         assert _measured_staging(model) == MAX_SEQ * row
+    finally:
+        eng.close()
+
+
+def test_recurrent_state_is_counted_exactly(manifest):
+    """A model that carries a recurrent state beside its KV rows
+    (models/jamba.py): the pool's state arrays equal the declared
+    ``row_state.recurrent_state`` leg, a prefill's staging its KV rows
+    plus one slot's state, and the KV slabs the pool's own formula at 2
+    planes of 1 head, byte for byte."""
+    import jax
+    from paddle_tpu.models import JambaForCausalLM, jamba_tiny
+    paddle_tpu.seed(0)
+    model = JambaForCausalLM(jamba_tiny())
+    model.eval()
+    eng = ServingEngine(model, num_slots=NUM_SLOTS, max_seq=MAX_SEQ,
+                        min_bucket=8, prefill_chunk=16,
+                        enable_prefix_cache=False)
+    try:
+        eng.serve_batch([np.arange(3, 40) % 128], max_new_tokens=3)
+        pool = eng.core.pool
+        # 2 Mamba layers x (8 x 128 float32 of state + 3 x 128 float32
+        # of convolution window)
+        per_slot = 2 * (8 * 128 * 4 + 3 * 128 * 4)
+        assert pool.state_bytes_per_slot == per_slot
+        env = {**TINY_ENV, "num_layers": pool.num_layers,
+               "kv_heads": pool.ks[0].shape[2],
+               "head_dim": model.cfg.head_dim,
+               "vocab_size": model.cfg.vocab_size,
+               "state_bytes_per_slot": per_slot}
+        assert (env["num_layers"], env["kv_heads"]) == (2, 1)
+        plane = manifest["planes"][ENGINE_PLANE]
+        state_arrays = jax.tree_util.tree_leaves(pool.state)
+        assert len(state_arrays) == 4
+        assert sum(a.nbytes for a in state_arrays) == _eval(
+            plane["row_state"]["recurrent_state"]["formula"], env) \
+            == NUM_SLOTS * per_slot
+        assert _measured_pool_bytes(pool) \
+            == _eval(manifest["pools"][KV_POOL]["formula"], env)
+        assert _measured_staging(model) \
+            == _eval(plane["staging"]["formula"], env) \
+            == 2 * 2 * MAX_SEQ * 16 * 4 + per_slot
     finally:
         eng.close()
 
