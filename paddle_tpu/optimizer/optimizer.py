@@ -105,6 +105,20 @@ class Optimizer:
         def upd(g, p, slots, master):
             if g is None:
                 return p, slots, master
+            # The identity on values, and NOT dead code: it ends the
+            # fusion that computes g at g.  Without it XLA:TPU makes this
+            # whole update the epilogue of the weight-gradient matmul,
+            # which then drags master, both moments and their new values
+            # through its output window, takes smaller tiles and runs at
+            # half the speed of either part alone.  One barrier per leaf:
+            # one over the whole tree would hold every update back until
+            # the LAST gradient exists.  Every leaf, the small ones too:
+            # sparing those under 16.8M elements read 284.9 ms a step
+            # against 285.7, not worth a constant that knows one model's
+            # shapes, and a spared leaf can fuse badly again.  Measured on
+            # a v5e, PR 33 (docs/performance.md, "Why Optimizer.update
+            # holds a barrier"; scripts/train_step_fusions.py counts them).
+            g = jax.lax.optimization_barrier(g)
             compute_p = master if master is not None else p
             g32 = g.astype(jnp.float32) if master is not None else g
             if l1:

@@ -344,6 +344,38 @@ def test_decode_block_tp_layer(topo):
              donate_argnums=(1, 2))
 
 
+@pytest.mark.parametrize("barrier", [True, False],
+                         ids=["barrier", "identity"])
+def test_train_step_updates_hold_no_matmul(topo, barrier, monkeypatch):
+    """PR 33: with ``Optimizer.update``'s per-leaf gradient barrier no
+    fusion of the compiled train step both holds a weight-gradient
+    ``convolution`` and writes an updated parameter or optimizer state;
+    with the barrier replaced by the identity the compiler fuses them
+    again (so this case can see what it guards).  The benchmark's train
+    step at small widths, through ``scripts/train_step_fusions.py``."""
+    import os
+    from benchmarks import run as R
+    from scripts import train_step_fusions as T
+    files = R.Files(os.path.join(T.ROOT, "BENCHMARK.json"))
+    cfg = files.json(files.find("configs/mistral-7b-d2.json"))
+    cfg.update(hidden_size=256, intermediate_size=512, vocab_size=1024,
+               num_attention_heads=2, num_key_value_heads=1)
+    mix = dict(files.json(files.find("traffic/train-4k.json")), seq=512)
+    if not barrier:
+        monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    rep = T.train_step_report(files.module("builders/llama.py"), cfg, mix,
+                              "AdamW", topo.devices[0])
+    leaves = rep["gradient_leaves"]
+    assert rep["update_fusions"] == leaves == 21
+    if barrier:
+        assert rep["update_fusions_with_matmul"] == 0, rep["fusions"]
+        assert rep["grad_barriers"] == leaves
+        assert rep["weight_gradient_fusions"] == 15
+    else:
+        assert rep["update_fusions_with_matmul"] > 0
+        assert rep["grad_barriers"] == 0
+
+
 def test_every_kernel_module_is_covered():
     """A new ``pallas_call`` site must join this file: the modules that
     hold one are exactly the ones compiled above."""

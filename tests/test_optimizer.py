@@ -202,3 +202,104 @@ def test_update_preserves_param_dtype_all_optimizers():
         assert p2["b"].dtype == jnp.float32, type(o).__name__
         p3, _ = o.update(grads, st, p2)
         assert p3["w"].dtype == jnp.bfloat16, (type(o).__name__, "step 2")
+
+
+# ---------------------------------------------------------------------
+# the per-leaf gradient barrier of Optimizer.update (PR 33)
+# ---------------------------------------------------------------------
+
+def _barrier_params(dtype):
+    rs = np.random.default_rng(0)
+    return {"w": jnp.asarray(rs.standard_normal((16, 24)), dtype),
+            "norm": jnp.asarray(rs.standard_normal((24,)), dtype),
+            "b": jnp.asarray(rs.standard_normal((24,)), jnp.float32)}
+
+
+_BARRIER_CASES = [
+    (cls, mp, kw)
+    for cls, kw in (("SGD", {}), ("Momentum", {"momentum": 0.9}),
+                    ("Adam", {}), ("AdamW", {"weight_decay": 0.05}))
+    for mp in (False, True)
+] + [("AdamW", True, {"weight_decay": 0.05,
+                      "apply_decay_param_fun": lambda n: n != "norm"})]
+
+
+@pytest.mark.parametrize(
+    "cls,mp,kw", _BARRIER_CASES,
+    ids=[f"{c}-{'mp' if mp else 'plain'}"
+         + ("-decay_fun" if "apply_decay_param_fun" in kw else "")
+         for c, mp, kw in _BARRIER_CASES])
+def test_update_barrier_is_the_identity_on_values(cls, mp, kw, monkeypatch):
+    """Two jitted steps with the gradient barrier and two with it
+    replaced by the identity give bit-equal parameters and state."""
+    params = _barrier_params(jnp.bfloat16)
+    grads = jax.tree.map(lambda p: (p * 0.37 + 0.01).astype(p.dtype), params)
+
+    def two_steps():
+        o = getattr(opt, cls)(learning_rate=0.01, multi_precision=mp, **kw)
+        upd = jax.jit(o.update)       # a fresh cache: traced anew
+        p, s = upd(grads, o.init(params), params)
+        return upd(grads, s, p)
+
+    with_barrier = two_steps()
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    without = two_steps()
+    a, b = jax.tree.leaves(with_barrier), jax.tree.leaves(without)
+    assert len(a) == len(b)
+    for x, y in zip(a, b):
+        assert x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                      np.asarray(y, np.float32))
+
+
+@pytest.mark.parametrize("frozen", [(), ("b",), ("w", "b")],
+                         ids=["none_frozen", "one_frozen", "two_frozen"])
+def test_update_holds_one_barrier_per_gradient_leaf(frozen):
+    """The jaxpr of a jitted value_and_grad + update step holds exactly
+    one optimization_barrier per gradient leaf, and none for a leaf
+    whose gradient is None."""
+    from scripts.train_step_fusions import count_primitive
+    params = _barrier_params(jnp.float32)
+    o = opt.AdamW(learning_rate=0.01)
+
+    @jax.jit
+    def step(p, s, x):
+        loss, g = jax.value_and_grad(
+            lambda p: jnp.sum((x @ p["w"] * p["norm"] + p["b"]) ** 2))(p)
+        g = {k: None if k in frozen else v for k, v in g.items()}
+        return o.update(g, s, p), loss
+
+    x = jnp.ones((4, 16), jnp.float32)
+    jaxpr = step.trace(params, o.init(params), x).jaxpr.jaxpr
+    assert count_primitive(jaxpr, "optimization_barrier") \
+        == len(params) - len(frozen)
+    (newp, _), _ = step(params, o.init(params), x)
+    for k in params:
+        same = bool(jnp.all(newp[k] == params[k]))
+        assert same == (k in frozen), k
+
+
+@pytest.mark.parametrize("how", ["vmap", "shard_map"])
+def test_update_traces_under_vmap_and_shard_map(how):
+    """The barrier has a batching rule and a shard_map rule: a batch of
+    independent updates, and an update inside shard_map on a one-device
+    mesh, equal the plain update."""
+    params = _barrier_params(jnp.float32)
+    grads = jax.tree.map(lambda p: p * 0.37 + 0.01, params)
+    o = opt.AdamW(learning_rate=0.01)
+    want, _ = jax.jit(o.update)(grads, o.init(params), params)
+    if how == "vmap":
+        stack = lambda t: jax.tree.map(lambda v: jnp.stack([v, 2 * v]), t)
+        state0 = o.init(params)
+        got, _ = jax.jit(jax.vmap(o.update, in_axes=(0, None, 0)))(
+            stack(grads), state0, stack(params))
+        got = jax.tree.map(lambda v: v[0], got)
+    else:
+        from jax.sharding import Mesh, PartitionSpec as P
+        mesh = Mesh(np.asarray(jax.devices()[:1]), ("dp",))
+        got, _ = jax.jit(jax.shard_map(
+            o.update, mesh=mesh, in_specs=(P(), P(), P()),
+            out_specs=P()))(grads, o.init(params), params)
+    for k in params:
+        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
+                                   rtol=1e-6, atol=1e-7)
