@@ -12,6 +12,9 @@ from .ouro import (OuroConfig, OuroStack, OuroModel,  # noqa: F401
 from .jamba import (JambaConfig, JambaModel, JambaForCausalLM,  # noqa: F401
                     JambaAttention, JambaMambaMixer, JambaDecoderLayer,
                     jamba_tiny)
+from .deepseek_v3 import (DeepseekV3Config, DeepseekV3Model,  # noqa: F401
+                          DeepseekV3ForCausalLM, DeepseekV3Attention,
+                          DeepseekV3DecoderLayer, deepseek_v3_tiny)
 from .gpt_moe import (GPTMoEConfig, GPTMoEForCausalLM,  # noqa: F401
                       gpt_moe_tiny)
 from .bert import (BertConfig, BertModel, BertForMaskedLM,  # noqa: F401

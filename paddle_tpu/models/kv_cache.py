@@ -23,7 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["append_kv", "cache_lens", "gather_block_rows",
+__all__ = ["append_kv", "append_rows", "cache_lens", "gather_block_rows",
            "scatter_block_rows"]
 
 
@@ -54,6 +54,16 @@ def append_kv(pk, pv, k, v, pos, head0=0):
         return upd(pk, k, p), upd(pv, v, p)
     return (jax.lax.dynamic_update_slice(pk, k, (0, pos, head0, 0)),
             jax.lax.dynamic_update_slice(pv, v, (0, pos, head0, 0)))
+
+
+def append_rows(buf, new, pos):
+    """:func:`append_kv` for a cache of ONE row kind (a latent row: no K
+    and V apart): the chunk ``new [b, s, h, d]`` into ``buf [b, max_len,
+    h, d]`` at rows ``pos..``."""
+    if _is_per_row(pos):
+        return jax.vmap(lambda b, n, p: jax.lax.dynamic_update_slice(
+            b, n, (p, 0, 0)))(buf, new, jnp.asarray(pos, jnp.int32))
+    return jax.lax.dynamic_update_slice(buf, new, (0, pos, 0, 0))
 
 
 def gather_block_rows(block_buf, idx):

@@ -149,12 +149,16 @@ class Tracer:
         self._spans.append(span)
 
     def add_span(self, name: str, lane: int, start: float, end: float,
-                 **attrs) -> None:
+                 **attrs) -> Optional[Span]:
         """Record a completed span from timestamps the caller already
-        holds — the off-hot-path shape (no clock reads here)."""
+        holds — the off-hot-path shape (no clock reads here).  Returns
+        the span (None when disabled): a caller may add an attribute it
+        learns later (a count that rides a later readback)."""
         if not self.enabled:
-            return
-        self._spans.append(Span(name, lane, start, end, attrs or None))
+            return None
+        span = Span(name, lane, start, end, attrs or None)
+        self._spans.append(span)
+        return span
 
     # ------------------------------------------------------------ events
     def event(self, name: str, lane: int = 0, t: Optional[float] = None,

@@ -68,6 +68,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -106,7 +107,8 @@ __compile_surface_roots__ = ("EngineCore",)
 # (per-slab k+v at the model dtype; ``num_layers`` and ``kv_heads`` are
 # the POOL's: its slabs and the heads one slab holds, whose product is
 # planes x the model's kv heads for every family, kv_pool.
-# cache_geometry).  Pure data, read by the AST
+# cache_geometry; ``v_slabs`` its V slabs, ``num_layers`` again or 0
+# where a position holds one row kind, kv_pool.cache_row).  Pure data, read by the AST
 # analysis and pinned against runtime measurement by
 # tests/test_zz_memory_surface.py; zero runtime effect.
 __memory_bytes__ = {
@@ -115,8 +117,8 @@ __memory_bytes__ = {
     "row_state._sampling_dev": "13 * num_slots",
     "row_state._mask_dev": "num_slots * vocab_size",
     "row_state.recurrent_state": "num_slots * state_bytes_per_slot",
-    "staging": "2 * num_layers * max_seq * kv_heads * head_dim * itemsize"
-               " + state_bytes_per_slot",
+    "staging": "(num_layers + v_slabs) * max_seq * kv_heads * head_dim"
+               " * itemsize + state_bytes_per_slot",
 }
 
 # token-readback encoding of the device-side health check: a decode row
@@ -214,6 +216,13 @@ def _first_token(key, logits, do_sample, temperature, top_k, top_p, mask):
     return finite_or_sentinel(logits[None], first)
 
 
+@jax.jit
+def _first_token_counted(touched, *sampling):
+    """:func:`_first_token` with the request's per-chunk expert counts
+    behind it, ``[1 + chunks]``: they ride the first token's readback."""
+    return jnp.concatenate([_first_token(*sampling), touched])
+
+
 def _advance_live(seq_pos, new_pos):
     """The positions a decode or verify program hands back: a slot
     parked at 0 (free, or claimed and still prefilling: ``KVPool.free``
@@ -298,10 +307,10 @@ class _Prefill:
     plan drives one decode_step append per chunk."""
 
     __slots__ = ("req", "slot", "ks", "vs", "state", "plan", "next_chunk",
-                 "match", "last_logits")
+                 "match", "last_logits", "touched", "chunk_spans")
 
     def __init__(self, req: Request, slot: int, ks, vs, plan,
-                 match: Optional[MatchResult], state=()):
+                 match: Optional[MatchResult], state=(), touched=None):
         self.req = req
         self.slot = slot
         self.ks = ks                # staging caches, threaded per chunk
@@ -311,6 +320,11 @@ class _Prefill:
         self.next_chunk = 0
         self.match = match
         self.last_logits = None     # final chunk's last-token logits
+        # a routing model's experts touched per chunk, on the device
+        # (``[chunks a row can take]`` int32) until the first token's
+        # readback, and the chunk spans that wait for them
+        self.touched = touched
+        self.chunk_spans = []
 
     @property
     def done(self) -> bool:
@@ -364,6 +378,8 @@ class EngineCore:
                 "on it): " + self.model_refusals["prefix_cache"])
         if aot_store is not None and recurrent_state_spec(model):
             raise AOTStoreError(RECURRENT_STATE_REFUSAL)
+        if aot_store is not None and "aot_store" in self.model_refusals:
+            raise AOTStoreError(self.model_refusals["aot_store"])
         self.model = model
         self.num_slots = num_slots
         self.prefill_chunk = prefill_chunk
@@ -680,8 +696,22 @@ class EngineCore:
         self.pool.faults = self.faults
         self.metrics.set_kv_planes(self.pool.planes)
         self.metrics.set_state_bytes(self.pool.state_bytes_per_slot)
+        self.metrics.set_cache_row_bytes(self.pool.row_bytes)
         # whether the programs carry a recurrent state beside the slabs
         self._stateful = bool(self.pool.state_bytes_per_slot)
+        # a model with expert layers (``expert_routing_spec``: (expert
+        # layers, experts)): its programs pass the live mask in, count
+        # the rows each expert got, and carry the running load
+        # ``[expert layers, experts]`` on the device
+        routing = getattr(model, "expert_routing_spec", None)
+        self._routed = routing is not None
+        if self._routed and self._stateful:
+            raise ValueError("a model with both a recurrent state and "
+                             "expert layers has no program here")
+        self._expert_load = None
+        if self._routed:
+            self._expert_load = jnp.zeros(routing(), jnp.int32)
+            self.metrics.set_moe_experts(math.prod(routing()))
         self.prefix_cache: Optional[PrefixCache] = None
         self.block_pool: Optional[BlockPool] = None
         # once the degradation ladder bypassed the cache, a quarantine
@@ -778,13 +808,19 @@ class EngineCore:
 
     def _build_prefill_fn(self) -> Callable:
         model, stateful = self.model, self._stateful
+        routed, chunk = self._routed, self._chunk_stride
         params, buffers = self._model_weights()
 
-        def prefill(params, ks, vs, ids, pos, valid, state=None):
+        def prefill(params, ks, vs, ids, pos, valid, state=None,
+                    load=None, touched=None):
             self.trace_counts["prefill"] += 1  # trace-time side effect
             caches = [(k, v, pos) for k, v in zip(ks, vs)]
             with bind_state(model, params, buffers):
-                if not stateful:
+                if routed:
+                    # padding past ``valid`` reaches no expert
+                    logits, caches, rows = model.decode_step(
+                        ids, caches, pos, valid=valid)
+                elif not stateful:
                     logits, caches = model.decode_step(ids, caches, pos)
                 else:
                     # padding past ``valid`` must leave a recurrent
@@ -796,14 +832,26 @@ class EngineCore:
                 logits, (valid - 1)[None, None, None], axis=1)[0, 0]
             out = (last.astype(jnp.float32),
                    [c[0] for c in caches], [c[1] for c in caches])
+            if routed:
+                # the running load, and this chunk's experts touched at
+                # the chunk's own place in the request's vector
+                return out + (load + rows, touched.at[pos // chunk].set(
+                    jnp.count_nonzero(rows).astype(jnp.int32)))
             return out + (state,) if stateful else out
 
         # donating the staging rows (and state) threads them chunk to
         # chunk in place; a model without a recurrent state is called
         # without the operand and lowers to the program it always did
-        donate = (1, 2, 6) if stateful else (1, 2)
+        donate = (1, 2, 6) if stateful else \
+            (1, 2, 7, 8) if routed else (1, 2)
         return functools.partial(
             jax.jit(prefill, donate_argnums=donate), params)
+
+    @property
+    def _chunk_stride(self) -> int:
+        """Positions between the starts of a request's prefill chunks
+        (the whole row without chunking)."""
+        return self.prefill_chunk or self.pool.max_seq
 
     def _prefill_cost(self, req: Request) -> int:
         """Tokens of prefill work admitting ``req`` costs THIS step: the
@@ -941,6 +989,7 @@ class EngineCore:
                 t_match1 = time.perf_counter()
             t_gather0 = time.perf_counter()
             state = ()      # a recurrent state, where the model has one
+            touched = None  # experts touched per chunk, where it routes
             if matched:
                 try:
                     ks, vs = self.prefix_cache.load_staging(match)
@@ -960,6 +1009,8 @@ class EngineCore:
                 ks, vs = staged[:2]
                 if self._stateful:
                     state = staged[2]
+                elif self._routed:
+                    touched = staged[2]
             t_gather1 = time.perf_counter()
             plan = self.scheduler.chunk_plan(matched, req.prompt_len,
                                              self.prefill_chunk)
@@ -988,7 +1039,7 @@ class EngineCore:
                 tracer.add_span("gather", lane, t_gather0, t_gather1,
                                 hit=bool(matched), request=rid)
             self._prefills.append(_Prefill(req, slot, ks, vs, plan, match,
-                                           state=state))
+                                           state=state, touched=touched))
             self.progress_counter += 1          # admission = progress
         except BaseException:
             if match is not None:
@@ -1005,10 +1056,13 @@ class EngineCore:
         model, max_seq = self.model, self.pool.max_seq
 
         state_spec = self.pool.state_spec if self._stateful else None
+        chunks = -(-max_seq // self._chunk_stride) if self._routed else 0
 
         def fresh_staging():
             caches = model.init_cache(1, max_seq)
             rows = [c[0] for c in caches], [c[1] for c in caches]
+            if chunks:
+                return rows + (jnp.zeros((chunks,), jnp.int32),)
             if state_spec is None:
                 return rows
             return rows + (zero_state(state_spec, 1),)
@@ -1042,6 +1096,13 @@ class EngineCore:
                 st.ks, st.vs, jnp.asarray(ids),
                 jnp.asarray(off, jnp.int32), jnp.asarray(valid, jnp.int32),
                 st.state)
+        elif self._routed:
+            last_logits, st.ks, st.vs, self._expert_load, st.touched = \
+                self._prefill_fn(
+                    st.ks, st.vs, jnp.asarray(ids),
+                    jnp.asarray(off, jnp.int32),
+                    jnp.asarray(valid, jnp.int32), None,
+                    self._expert_load, st.touched)
         else:
             last_logits, st.ks, st.vs = self._prefill_fn(
                 st.ks, st.vs, jnp.asarray(ids),
@@ -1052,14 +1113,17 @@ class EngineCore:
         self.progress_counter += 1              # chunk ran = progress
         self.metrics.on_prefill_chunk(valid, seconds=t1 - t0)
         self.metrics.step_count("prefill_tokens", valid)
-        self.metrics.tracer.add_span(
+        span = self.metrics.tracer.add_span(
             "prefill_chunk", self._lane(st.req), t0, t1,
             chunk=st.next_chunk - 1, width=width, tokens=valid,
-            request=st.req.request_id,
+            offset=off, request=st.req.request_id,
             # whether the chunk started from an earlier chunk's
             # recurrent state (False on a request's first, and always
             # for a model that carries none)
             state_carried=self._stateful and st.next_chunk > 1)
+        if self._routed and span is not None:
+            # ``experts_touched`` lands with the first token's readback
+            st.chunk_spans.append((off // self._chunk_stride, span))
         if st.done:
             st.last_logits = last_logits
 
@@ -1089,13 +1153,14 @@ class EngineCore:
         self._mask_dev = None
         # host arrays go in as the call's operands: no eager transfer
         # programs of their own ahead of it
-        first = _first_token(
-            sub, st.last_logits,
-            np.asarray([s.do_sample], bool),
-            np.asarray([s.temperature], np.float32),
-            np.asarray([s.top_k], np.int32),
-            np.asarray([s.top_p], np.float32),
-            self._mask_host[slot])
+        sampling = (sub, st.last_logits,
+                    np.asarray([s.do_sample], bool),
+                    np.asarray([s.temperature], np.float32),
+                    np.asarray([s.top_k], np.int32),
+                    np.asarray([s.top_p], np.float32),
+                    self._mask_host[slot])
+        first = _first_token_counted(st.touched, *sampling) \
+            if self._routed else _first_token(*sampling)
         draft = None
         if self.spec_on:
             from .spec import NGramDraftTable
@@ -1178,6 +1243,13 @@ class EngineCore:
         self.metrics.phase("first_token_readback")
         self.metrics.step_count("prefills_completed", len(staged))
         toks = np.asarray(jnp.concatenate([f for _, f in staged]))
+        if self._routed:
+            # each request's token leads its per-chunk expert counts
+            toks = toks.reshape(len(staged), -1)
+            for (st, _), counts in zip(staged, toks[:, 1:]):
+                for chunk, span in st.chunk_spans:
+                    span.attrs["experts_touched"] = int(counts[chunk])
+            toks = toks[:, 0]
         emitted = 0
         flush_exc = None
         for (st, _), tok in zip(staged, toks):
@@ -1299,6 +1371,10 @@ class EngineCore:
         from ..kernels.decode_attention import decode_attention_route
         if self.decode_path in ("fused", "tp_fused_block"):
             return "slab_in_place", None
+        own = getattr(self.model, "attention_route", None)
+        if own is not None:
+            # a cache of another row kind has kernels of its own
+            return own(self.pool.ks[0].shape, self.pool.ks[0].dtype)
         cfg = self.model.cfg
         slots, rows, slab_heads, dh = self.pool.ks[0].shape
         manual = self.decode_path == "tp_fused"
@@ -1319,7 +1395,7 @@ class EngineCore:
         ``("xla_scatter", why)`` with the attention route's own reason
         (``kv_cache.append_kv`` ahead of the attention)."""
         route, why = self.attention_route()
-        if route == "slab_in_place":
+        if route in ("slab_in_place", "latent_in_place"):
             return "in_kernel", None
         return "xla_scatter", why
 
@@ -1333,10 +1409,36 @@ class EngineCore:
         route_of = getattr(self.model, "recurrence_route", None)
         if route_of is None:
             return "", None
-        width = self.prefill_chunk or max(self._warm_buckets or (1,))
-        chunk, why = route_of(width)
+        chunk, why = route_of(self._widest_chunk())
         step, _ = route_of(1)
         return f"prefill={chunk},decode={step}", why
+
+    def _widest_chunk(self) -> int:
+        return self.prefill_chunk or max(self._warm_buckets or (1,))
+
+    def expert_route(self):
+        """``(route, reason)`` of a routing model's grouped matmul over
+        its experts, ``("", None)`` for a model without expert layers:
+        which form runs in the prefill programs (at the widest chunk)
+        and in the decode program, as the model says
+        (``model.expert_route(rows)``, static per compiled program), as
+        ``"prefill=<form>,decode=<form>"``; the reason is why the
+        decode program's is not the kernel."""
+        route_of = getattr(self.model, "expert_route", None)
+        if route_of is None:
+            return "", None
+        chunk, _ = route_of(self._widest_chunk())
+        step, why = route_of(self.num_slots)
+        return f"prefill={chunk},decode={step}", why
+
+    def expert_load(self):
+        """Rows each expert got since the engine was built, ``[expert
+        layers, experts]`` (decode steps and prefill chunks, live rows
+        only), read from the device HERE; None for a model without
+        expert layers."""
+        if self._expert_load is None:
+            return None
+        return np.asarray(self._expert_load)
 
     def _emit_decode_block(self) -> None:
         """The discrete obs event that marks WHICH path this engine's
@@ -1350,6 +1452,7 @@ class EngineCore:
         route, why = self.attention_route()
         append, append_why = self.kv_append()
         scan, scan_why = self.scan_route()
+        expert, expert_why = self.expert_route()
         self.metrics.on_decode_block(
             active=self.decode_path in ("fused", "tp_fused_block"),
             reason=None if not self.fused_decode
@@ -1358,7 +1461,8 @@ class EngineCore:
             tp=self.tensor_parallel,
             attention_route=route, attention_reason=why,
             kv_append=append, kv_append_reason=append_why,
-            scan_route=scan, scan_reason=scan_why)
+            scan_route=scan, scan_reason=scan_why,
+            expert_route=expert, expert_reason=expert_why)
 
     def _build_decode_fn(self) -> Callable:
         model, stateful = self.model, self._stateful
@@ -1369,14 +1473,24 @@ class EngineCore:
 
         params, buffers = self._model_weights()
 
+        routed = self._routed
+
         def decode(params, ks, vs, seq_pos, last_tok, keys, do_sample,
-                   temperature, top_k, top_p, mask, state=None):
+                   temperature, top_k, top_p, mask, state=None, load=None):
             self.trace_counts["decode"] += 1  # trace-time side effect
             caches = [(k, v, seq_pos) for k, v in zip(ks, vs)]
             step_fn = model.fused_decode_step if fused else \
                 model.decode_step
             with bind_state(model, params, buffers):
-                if not stateful:
+                if routed:
+                    # a parked slot's ride-along token is ROUTED to no
+                    # expert: where the experts are most of the bytes a
+                    # step reads, 24 parked rows of 32 would touch 2.5
+                    # times the experts the 8 live ones need
+                    logits, caches, rows = step_fn(
+                        last_tok[:, None], caches, seq_pos,
+                        valid=(seq_pos > 0).astype(jnp.int32))
+                elif not stateful:
                     logits, caches = step_fn(last_tok[:, None], caches,
                                              seq_pos)
                 else:
@@ -1394,12 +1508,21 @@ class EngineCore:
             new_vs = [c[1] for c in caches]
             out = (new_ks, new_vs, _advance_live(seq_pos, caches[0][2]),
                    nxt.astype(jnp.int32), split[:, 0])
+            if routed:
+                # what the step's ONE readback carries behind the
+                # tokens: experts that got a live row (over the expert
+                # layers) and the fullest expert's rows
+                counts = jnp.stack([jnp.count_nonzero(rows),
+                                    jnp.max(rows)]).astype(jnp.int32)
+                return out + (jnp.concatenate([out[3], counts]),
+                              load + rows)
             return out + (state,) if stateful else out
 
         # donating the KV slabs (and the recurrent state, where the
         # model has one) aliases them in place — pool memory stays a
         # single allocation across the whole serving run
-        donate = (1, 2, 11) if stateful else (1, 2)
+        donate = (1, 2, 11) if stateful else \
+            (1, 2, 12) if routed else (1, 2)
         return functools.partial(
             jax.jit(decode, donate_argnums=donate), params)
 
@@ -1472,6 +1595,14 @@ class EngineCore:
                     self.pool.ks, self.pool.vs, self.pool.seq_pos,
                     self._last_tok, self._keys, *self._sampling_dev,
                     self._mask_dev, self.pool.state)
+        elif self._routed:
+            # ``back``: the tokens with the step's expert counts behind
+            # them, what the step reads back in place of the tokens
+            ks, vs, pos, nxt, self._keys, back, self._expert_load = \
+                self._decode_fn(
+                    self.pool.ks, self.pool.vs, self.pool.seq_pos,
+                    self._last_tok, self._keys, *self._sampling_dev,
+                    self._mask_dev, None, self._expert_load)
         else:
             ks, vs, pos, nxt, self._keys = self._decode_fn(
                 self.pool.ks, self.pool.vs, self.pool.seq_pos,
@@ -1479,7 +1610,7 @@ class EngineCore:
                 self._mask_dev)
         self.pool.ks, self.pool.vs, self.pool.seq_pos = ks, vs, pos
         self._last_tok = nxt
-        return nxt
+        return back if self._routed else nxt
 
     # ----------------------------------------- speculative decode (spec)
     def _build_verify_fn(self) -> Callable:
@@ -1771,6 +1902,10 @@ class EngineCore:
                 metrics.phase("readback")
                 toks = np.asarray(nxt)     # THE per-step device readback
                 metrics.phase("harvest")
+                if self._routed and spec is None:
+                    counts["experts_touched"] = int(toks[self.num_slots])
+                    counts["expert_rows_max"] = int(
+                        toks[self.num_slots + 1])
                 self._fault_phase = None
                 # the readback already advanced EVERY slot's device
                 # state: a raise mid-loop (a user stream callback, an

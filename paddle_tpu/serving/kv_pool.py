@@ -21,6 +21,15 @@ holds it as ``state``, the same pytree of ``[num_slots, ...]`` arrays,
 opaque here: made with the pool, overwritten wholesale by ``adopt``,
 threaded through the decode program by the engine.  Empty (``()``) for
 every model that declares none.
+
+What a cached position HOLDS is the model's to declare too
+(:func:`cache_row`): ordinarily a K row and a V row of ``head_dim``
+values a kv head; a model with latent attention holds ONE row kind
+(``cfg.cache_row_kinds`` 1, ``cfg.cache_row_width`` values: the
+compressed KV and the shared rotary key, models/deepseek_v3.py).  The
+pool then makes no V slabs at all: ``vs`` is a list of ``None``, which
+every program that takes ``(ks, vs)`` carries as no operand, and the
+model's cache tuple is ``(rows, None, pos)``.
 """
 
 from __future__ import annotations
@@ -34,14 +43,14 @@ import jax.numpy as jnp
 
 from ..models.kv_cache import gather_block_rows, scatter_block_rows
 
-__all__ = ["KVPool", "BlockPool", "cache_geometry",
+__all__ = ["KVPool", "BlockPool", "cache_geometry", "cache_row",
            "recurrent_state_spec", "state_bytes", "zero_state"]
 
 # graftmem marker (tools/analysis/memory.py): every slab extent in the
 # pool constructors below must flow from registered capacity fields —
 # the derived blocks-per-row ratio is declared here so the capacity
 # manifest can name it alongside the constructor parameters
-__memory_capacity_fields__ = ("blocks_per_row",)
+__memory_capacity_fields__ = ("blocks_per_row", "v_slabs")
 
 
 def cache_geometry(cfg) -> Tuple[int, int, int]:
@@ -62,6 +71,18 @@ def cache_geometry(cfg) -> Tuple[int, int, int]:
                          f"the {planes} cache planes")
     kv_heads = getattr(cfg, "kv_heads", None) or cfg.num_heads
     return planes, planes // per_slab, per_slab * kv_heads
+
+
+def cache_row(cfg) -> Tuple[int, int]:
+    """``(row kinds, values a kv head)`` of one cached position of one
+    plane: ``(2, head_dim)``, a K row and a V row, unless the model says
+    otherwise (``cfg.cache_row_kinds`` / ``cfg.cache_row_width``)."""
+    kinds = getattr(cfg, "cache_row_kinds", None) or 2
+    if kinds not in (1, 2):
+        raise ValueError(f"cache_row_kinds {kinds}: a position holds one "
+                         f"row kind or a K and a V")
+    width = getattr(cfg, "cache_row_width", None) or cfg.head_dim
+    return kinds, width
 
 
 def recurrent_state_spec(model):
@@ -106,7 +127,9 @@ class KVPool:
     """Fixed-shape KV slab + free-list slot accounting.
 
     Device state:
-      * ``ks/vs``   — per-layer [num_slots, max_seq, kv_heads, head_dim];
+      * ``ks/vs``   — per-layer [num_slots, max_seq, kv_heads, head_dim]
+        (``vs`` a list of ``None`` where a position holds one row kind:
+        ``row_kinds`` 1, :func:`cache_row`);
       * ``seq_pos`` — [num_slots] int32, each slot's current cache length
         (the per-row ``pos`` the models append at AND the ``seq_lens`` the
         ragged attention masks by, after the in-step +1);
@@ -121,9 +144,13 @@ class KVPool:
 
     def __init__(self, num_slots: int, max_seq: int, num_layers: int,
                  kv_heads: int, head_dim: int, dtype=jnp.float32,
-                 mesh=None, planes: Optional[int] = None, state_spec=()):
+                 mesh=None, planes: Optional[int] = None, state_spec=(),
+                 row_kinds: int = 2):
         if num_slots < 1:
             raise ValueError("num_slots must be >= 1")
+        if mesh is not None and row_kinds != 2:
+            raise ValueError("a cache of one row kind has no "
+                             "tensor-parallel layout")
         if mesh is not None and jax.tree_util.tree_leaves(state_spec):
             raise ValueError("a recurrent state has no tensor-parallel "
                              "layout")
@@ -140,12 +167,19 @@ class KVPool:
         self.num_layers = num_layers
         self.planes = planes if planes is not None else num_layers
         self.mesh = mesh
+        self.row_kinds = row_kinds
+        # the V slabs: as many as the K slabs, or none where a position
+        # holds one row kind (graftmem counts both lists by these names)
+        v_slabs = num_layers * (row_kinds - 1)
         shape = (num_slots, max_seq, kv_heads, head_dim)
+        # bytes ONE cached position holds over every slab
+        self.row_bytes = (row_kinds * num_layers * kv_heads * head_dim
+                          * jnp.dtype(dtype).itemsize)
         if mesh is None:
             self.ks: List[jax.Array] = [jnp.zeros(shape, dtype)
                                         for _ in range(num_layers)]
-            self.vs: List[jax.Array] = [jnp.zeros(shape, dtype)
-                                        for _ in range(num_layers)]
+            self.vs: List[Optional[jax.Array]] = [
+                jnp.zeros(shape, dtype) for _ in range(v_slabs)]
             self.seq_pos = jnp.zeros((num_slots,), jnp.int32)
         else:
             # tensor-parallel serving (serving/tp.py): slabs partition
@@ -161,6 +195,11 @@ class KVPool:
             self.vs = [mk() for _ in range(num_layers)]
             self.seq_pos = replicated(
                 jnp.zeros((num_slots,), jnp.int32), mesh)
+        if row_kinds == 1:
+            # the programs' ``(ks, vs)`` lists pair up slab by slab: a
+            # ``None`` is no operand, and the model's tuple is
+            # ``(rows, None, pos)``
+            self.vs = [None] * num_layers
         self.state_spec = state_spec
         self.state_bytes_per_slot = state_bytes(state_spec)
         self.state = zero_state(state_spec, num_slots)
@@ -182,9 +221,10 @@ class KVPool:
         cfg = model.cfg
         max_seq = max_seq or cfg.max_seq_len
         planes, slabs, slab_heads = cache_geometry(cfg)
-        return cls(num_slots, max_seq, slabs, slab_heads, cfg.head_dim,
+        kinds, width = cache_row(cfg)
+        return cls(num_slots, max_seq, slabs, slab_heads, width,
                    dtype=jnp.dtype(cfg.dtype), mesh=mesh, planes=planes,
-                   state_spec=recurrent_state_spec(model))
+                   state_spec=recurrent_state_spec(model), row_kinds=kinds)
 
     # ------------------------------------------------------------ slots
     @property
@@ -230,7 +270,8 @@ class KVPool:
     def adopt(self, slot: int, layer_caches, length: int,
               set_pos: bool = True, state=None) -> None:
         """Move a freshly prefilled single-request cache (per-layer
-        ``(k [1, max_seq, h, d], v, _)`` tuples) into ``slot`` and record
+        ``(k [1, max_seq, h, d], v, _)`` tuples, ``v`` None where the
+        pool holds one row kind) into ``slot`` and record
         its ``length`` valid positions.  The copy is a jitted
         dynamic_update_slice with a traced slot index — admitting to a
         different slot never recompiles.  ``state`` is the request's
@@ -245,7 +286,8 @@ class KVPool:
         s = jnp.asarray(slot, jnp.int32)
         for i, layer in enumerate(layer_caches):
             self.ks[i] = _adopt_row(self.ks[i], layer[0], s)
-            self.vs[i] = _adopt_row(self.vs[i], layer[1], s)
+            if self.vs[i] is not None:
+                self.vs[i] = _adopt_row(self.vs[i], layer[1], s)
         if self.state_bytes_per_slot:
             if state is None:
                 raise ValueError("adopt: the pool holds a recurrent "
@@ -335,8 +377,12 @@ class BlockPool:
                max_seq: int, mesh=None) -> "BlockPool":
         cfg = model.cfg
         _, slabs, slab_heads = cache_geometry(cfg)
+        kinds, width = cache_row(cfg)
+        if kinds != 2:
+            raise ValueError("the block pool holds K and V blocks: a "
+                             "cache of one row kind has no blocks here")
         return cls(num_blocks, block_len, max_seq, slabs, slab_heads,
-                   cfg.head_dim, dtype=jnp.dtype(cfg.dtype), mesh=mesh)
+                   width, dtype=jnp.dtype(cfg.dtype), mesh=mesh)
 
     # ------------------------------------------------------------ blocks
     @property

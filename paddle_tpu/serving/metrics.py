@@ -44,7 +44,12 @@ STEP_PHASES = ("admission", "prefill", "first_token_readback", "draft",
 # host ints the engine already holds, set on the ``serving.step`` span
 STEP_COUNTS = ("admitted", "prefill_tokens", "prefills_completed",
                "active_slots", "sampling_slots", "live_kv_rows",
-               "loop_passes", "state_slots", "new_tokens", "queue_depth")
+               "loop_passes", "state_slots", "new_tokens", "queue_depth",
+               # a routing model's decode program, riding the step's one
+               # token readback (0 for every other model): experts that
+               # got at least one LIVE row, summed over the expert
+               # layers, and the fullest expert's rows in any layer
+               "experts_touched", "expert_rows_max")
 
 # admission-projection clamps: a degenerate measurement window (one
 # finish inside a denormal-small busy window, or a finish against an
@@ -237,6 +242,15 @@ class ServingMetrics:
             "serving.state.bytes_per_slot",
             "bytes of recurrent state a slot holds beside its KV rows "
             "(0 for a model without one)")
+        self._g_row_bytes = reg.gauge(
+            "serving.cache.row_bytes",
+            "bytes ONE cached position holds over every layer: K and V "
+            "rows, or the one latent row of a model that declares one "
+            "row kind (kv_pool.cache_row)")
+        self._g_moe_experts = reg.gauge(
+            "serving.moe.experts",
+            "routed experts the model holds over its expert layers "
+            "(0 for a model without expert layers)")
         # zero-cold-start surface (docs/serving.md "Zero cold start"):
         # warm-load accounting for the AOT program store.  The event
         # counters window-reset with the rest; the two gauges are
@@ -344,7 +358,9 @@ class ServingMetrics:
                         kv_append: str = "",
                         kv_append_reason: Optional[str] = None,
                         scan_route: str = "",
-                        scan_reason: Optional[str] = None) -> None:
+                        scan_reason: Optional[str] = None,
+                        expert_route: str = "",
+                        expert_reason: Optional[str] = None) -> None:
         """The engine resolved its decode path (emitted once, when the
         single decode program is built): ``active`` says whether the
         fused decode-block kernels compiled in, ``reason`` carries the
@@ -364,7 +380,12 @@ class ServingMetrics:
         runs a model's recurrence over positions in the prefill and
         decode programs (``prefill=<form>,decode=<form>``,
         kernels/selective_scan.py; empty for a model without one) and
-        ``scan_reason`` why the prefill form is not the kernel.  Lands
+        ``scan_reason`` why the prefill form is not the kernel;
+        ``expert_route`` the form of a routing model's grouped matmul
+        over its experts, likewise (``gmm`` / ``ragged_dot``,
+        distributed/moe_dropless.py; empty for a model without expert
+        layers) and ``expert_reason`` why the decode program's is not
+        the kernel.  Lands
         as a ``decode_block`` discrete event on the engine lane
         (glossary: docs/observability.md)."""
         self.tracer.event("decode_block", lane=self.engine_lane,
@@ -376,7 +397,9 @@ class ServingMetrics:
                           kv_append=kv_append,
                           kv_append_reason=kv_append_reason or "",
                           scan_route=scan_route,
-                          scan_reason=scan_reason or "")
+                          scan_reason=scan_reason or "",
+                          expert_route=expert_route,
+                          expert_reason=expert_reason or "")
 
     def on_aot_load(self, programs: int, seconds: float,
                     build_s: Optional[float] = None) -> None:
@@ -417,6 +440,12 @@ class ServingMetrics:
 
     def set_state_bytes(self, nbytes: int) -> None:
         self._g_state_bytes.set(nbytes)
+
+    def set_cache_row_bytes(self, nbytes: int) -> None:
+        self._g_row_bytes.set(nbytes)
+
+    def set_moe_experts(self, experts: int) -> None:
+        self._g_moe_experts.set(experts)
 
     def on_compile(self, program: str, n: int = 1) -> None:
         self._c_compiles.inc(n)

@@ -193,6 +193,10 @@ ALLOWLIST: Dict[str, str] = {
         # beside its KV rows, and its bytes — pool sizing control plane,
         # not array ops; contract = tests/test_jamba.py
         "recurrent_state_spec", "state_bytes", "zero_state",
+        # what a cached position holds (ISSUE 34): K and V rows, or the
+        # one latent row of a model that declares one row kind — pool
+        # sizing control plane; contract = tests/test_deepseek_v3.py
+        "cache_row",
     )},
     # ---- paddle_tpu.obs public surface (the OBS registry surface:
     #      counters/gauges/histograms and the span tracer are telemetry

@@ -95,6 +95,9 @@ DEFAULT_CAPACITY_FIELDS = frozenset({
     # bytes of recurrent state a slot holds beside its KV rows (what the
     # model declares in ``recurrent_state_spec``; 0 for most)
     "state_bytes_per_slot",
+    # the pool's V slabs: ``num_layers`` of them, or 0 where a cached
+    # position holds ONE row kind (a latent row: kv_pool.cache_row)
+    "v_slabs",
 })
 _EXTRA_CAPACITY_FIELDS: List[str] = []
 
@@ -393,7 +396,7 @@ REFERENCE_ENV: Dict[str, int] = {
     "vocab_size": 32768, "hidden": 768, "num_heads": 12, "kv_heads": 12,
     "head_dim": 64, "ffn": 3072, "num_layers": 12, "max_seq": 1024,
     "num_slots": 8, "block_len": 16, "num_blocks": 512, "itemsize": 2,
-    "state_bytes_per_slot": 0,
+    "state_bytes_per_slot": 0, "v_slabs": 12,
 }
 
 # every tiling the static VMEM check proves: the flagship decode shape

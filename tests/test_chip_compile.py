@@ -235,6 +235,120 @@ def test_hybrid_prefill_chunk_and_decode_step(topo, monkeypatch):
     assert mem.temp_size_in_bytes < 16 * per_slot // 2
 
 
+@pytest.mark.parametrize("rows, tile", [(256, 16), (4096, 128)])
+def test_grouped_matmul_over_the_experts(topo, rows, tile):
+    """``megablox.gmm`` at JoyAI-LLM-Flash's expert widths with a whole
+    ``[2048, 768]`` / ``[768, 2048]`` expert matrix a grid step: a decode
+    step's 32 slots x 8 assignments in row tiles of 16, a 512-token
+    chunk's 4096 in tiles of 128."""
+    from paddle_tpu.distributed import moe_dropless as M
+    s = _one(topo)
+    assert M._row_tile(rows) == tile
+    for k, n in ((2048, 768), (768, 2048)):
+        mem = _compile(functools.partial(M.gmm_form, interpret=False),
+                       s((rows, k)), s((256, k, n)),
+                       s((256,), jnp.int32))
+        assert mem.temp_size_in_bytes < (8 << 20), mem.temp_size_in_bytes
+
+
+def test_latent_decode_attention(topo):
+    """The latent-row decode kernel over 32 slots x 4096 rows of 640
+    lanes (the 576-wide row in whole tiles), 32 query heads: the slab is
+    updated in place."""
+    from paddle_tpu.kernels.latent_attention import latent_decode_attention
+    s = _one(topo)
+    slab = s((32, 4096, 1, 640))
+    mem = _compile(functools.partial(latent_decode_attention, lora=512,
+                                     scale=192 ** -0.5, interpret=False),
+                   s((32, 32, 640)), s((32, 1, 1, 640)), slab,
+                   s((32,), jnp.int32), donate_argnums=(2,))
+    assert mem.alias_size_in_bytes == 32 * 4096 * 640 * 2
+    assert mem.temp_size_in_bytes < (1 << 20), mem.temp_size_in_bytes
+
+
+def test_latent_expert_decode_step_prefill_chunk_and_reference(
+        topo, monkeypatch, capsys):
+    """JoyAI-LLM-Flash at the cell's depth (5 layers, every expert,
+    5.56 B parameters): its decode step over 32 slots, its prefill
+    program at the cell's chunk into a 4096-row staging, both with the
+    chip's routes (12 grouped-matmul kernels, 5 latent-attention kernels
+    in the decode step), ``alias`` covering the cache; the initializer
+    as ``lib/build.py`` runs it; and the plain float32 reference at the
+    cell's longest sequence beside the 11.1 GB of weights.  ``temp`` of
+    each is printed."""
+    import json
+    import os
+    from benchmarks.builders import deepseek_v3 as builder
+    from benchmarks.lib import reference
+    from paddle_tpu.framework.random import rng_context
+    from paddle_tpu.nn.functional_call import bind_state, state
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "joyai-llm-flash-d5.json")) as f:
+        cfg_file = json.load(f)
+    cfg = builder.model_config(cfg_file, 4096)
+    made = []
+
+    def make(key=None):
+        if key is None:
+            made.append(builder.model_class()(cfg).to(dtype=cfg.dtype))
+        else:
+            with rng_context(key):
+                made.append(builder.model_class()(cfg).to(dtype=cfg.dtype))
+        return state(made[-1])
+
+    s = _one(topo)
+    params, buffers = jax.tree.map(lambda x: s(x.shape, x.dtype),
+                                   jax.eval_shape(make))
+    model = made[0]
+    assert model.expert_route(32) == model.expert_route(512) == ("gmm", None)
+    i32, gb = jnp.int32, 1e9
+
+    def step(params, ks, ids, pos, valid):
+        caches = [(k, None, pos) for k in ks]
+        with bind_state(model, params, buffers):
+            logits, caches, rows = model.decode_step(ids, caches, pos,
+                                                     valid=valid)
+        return logits[:, -1], [c[0] for c in caches], rows
+
+    temps = {}
+    slabs = [s((32, 4096, 1, 640))] * 5
+    text = jax.jit(step, donate_argnums=(1,)).trace(
+        params, slabs, s((32, 1), i32), s((32,), i32),
+        s((32,), i32)).lower(lowering_platforms=("tpu",)).compile()
+    mem = text.memory_analysis()
+    assert mem.alias_size_in_bytes >= 5 * 32 * 4096 * 640 * 2
+    hlo = text.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 17
+    temps["decode"] = mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < 0.1 * gb
+    mem = _compile(step, params, [s((1, 4096, 1, 640))] * 5,
+                   s((1, 512), i32), s((), i32), s((), i32),
+                   donate_argnums=(1,))
+    assert mem.alias_size_in_bytes >= 5 * 4096 * 640 * 2
+    temps["prefill_512"] = mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < 1.0 * gb
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=SingleDeviceSharding(topo.devices[0]))
+    mem = _compile(make, key)
+    # small arrays are padded out to their tiles
+    assert 0 <= mem.output_size_in_bytes - 2 * 5_558_141_952 < (1 << 20)
+    temps["initializer"] = mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < 0.5 * gb
+
+    def forward(p, ids):
+        with jax.default_matmul_precision("highest"):
+            return builder.reference_forward(cfg_file, reference._f32(p), ids)
+
+    mem = _compile(forward, params, s((1, 3840), i32))
+    temps["reference_3840"] = mem.temp_size_in_bytes
+    # beside 11.12 GB of weights on a 16 GiB chip
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4.5 * gb
+    with capsys.disabled():
+        print("\njoyai-llm-flash-d5 temp bytes:", json.dumps(temps))
+
+
 @pytest.mark.parametrize("kind", ["rms", "layer"])
 def test_fused_norm_fwd_bwd(topo, kind):
     from paddle_tpu.kernels.fused_norm import (fused_layer_norm_pallas,
@@ -392,4 +506,4 @@ def test_every_kernel_module_is_covered():
     assert holders == {"flash_attention.py", "decode_attention.py",
                        "fused_norm.py", "fused_adamw.py",
                        "decode_block.py", "decode_block_tp.py",
-                       "selective_scan.py"}, holders
+                       "selective_scan.py", "latent_attention.py"}, holders

@@ -58,6 +58,9 @@ TINY_ENV = {
     "blocks_per_row": MAX_SEQ // BLOCK_LEN,
     # recurrent state a slot holds beside its KV rows: none for GPT
     "state_bytes_per_slot": 0,
+    # the pool's V slabs: one per K slab (0 where a position holds one
+    # row kind, a latent row)
+    "v_slabs": 2,
 }
 
 
@@ -97,7 +100,8 @@ def _fresh_engine(**engine_kw):
 
 def _measured_pool_bytes(pool):
     return sum(a.nbytes for a in pool.ks) \
-        + sum(a.nbytes for a in pool.vs) + pool.seq_pos.nbytes
+        + sum(a.nbytes for a in pool.vs if a is not None) \
+        + pool.seq_pos.nbytes
 
 
 def _measured_block_bytes(bp):
@@ -109,7 +113,8 @@ def _measured_staging(model):
     model carries one, its recurrent state."""
     import jax
     cache = model.init_cache(1, MAX_SEQ)
-    rows = sum(layer[0].nbytes + layer[1].nbytes for layer in cache)
+    rows = sum(a.nbytes for layer in cache for a in layer[:2]
+               if a is not None)
     if hasattr(model, "init_state"):
         rows += sum(leaf.nbytes for leaf in
                     jax.tree_util.tree_leaves(model.init_state(1)))
@@ -173,6 +178,7 @@ def test_static_bytes_equal_the_live_pool_for_every_served_family(
         pool, bp = eng.core.pool, eng.core.block_pool
         assert pool.planes == planes
         env = {**TINY_ENV, "num_layers": pool.num_layers,
+               "v_slabs": pool.num_layers,
                "kv_heads": pool.ks[0].shape[2],
                "head_dim": model.cfg.head_dim,
                "vocab_size": model.cfg.vocab_size}
@@ -214,6 +220,7 @@ def test_recurrent_state_is_counted_exactly(manifest):
         per_slot = 2 * (8 * 128 * 4 + 3 * 128 * 4)
         assert pool.state_bytes_per_slot == per_slot
         env = {**TINY_ENV, "num_layers": pool.num_layers,
+               "v_slabs": pool.num_layers,
                "kv_heads": pool.ks[0].shape[2],
                "head_dim": model.cfg.head_dim,
                "vocab_size": model.cfg.vocab_size,
@@ -230,6 +237,39 @@ def test_recurrent_state_is_counted_exactly(manifest):
         assert _measured_staging(model) \
             == _eval(plane["staging"]["formula"], env) \
             == 2 * 2 * MAX_SEQ * 16 * 4 + per_slot
+    finally:
+        eng.close()
+
+
+def test_latent_row_is_counted_exactly(manifest):
+    """A model whose cached position is ONE latent row
+    (models/deepseek_v3.py, ``cache_row_kinds`` 1): the pool makes no V
+    slabs (``v_slabs`` 0), and the pool's formula, a prefill's staging
+    and ``row_bytes`` are 3 layers x 1 head x 128 values (the 32 + 8 of
+    the row in one lane tile) x 4 B a position, byte for byte: not
+    twice that."""
+    from paddle_tpu.models import DeepseekV3ForCausalLM, deepseek_v3_tiny
+    paddle_tpu.seed(0)
+    model = DeepseekV3ForCausalLM(deepseek_v3_tiny())
+    model.eval()
+    eng = ServingEngine(model, num_slots=NUM_SLOTS, max_seq=MAX_SEQ,
+                        min_bucket=8, prefill_chunk=16,
+                        enable_prefix_cache=False)
+    try:
+        eng.serve_batch([np.arange(3, 40) % 128], max_new_tokens=3)
+        pool = eng.core.pool
+        assert pool.row_kinds == 1 and pool.vs == [None] * 3
+        assert pool.row_bytes == 3 * 128 * 4
+        env = {**TINY_ENV, "num_layers": 3, "v_slabs": 0, "kv_heads": 1,
+               "head_dim": model.cfg.cache_row_width,
+               "vocab_size": model.cfg.vocab_size}
+        plane = manifest["planes"][ENGINE_PLANE]
+        assert _measured_pool_bytes(pool) \
+            == _eval(manifest["pools"][KV_POOL]["formula"], env) \
+            == NUM_SLOTS * MAX_SEQ * pool.row_bytes + 4 * NUM_SLOTS
+        assert _measured_staging(model) \
+            == _eval(plane["staging"]["formula"], env) \
+            == MAX_SEQ * pool.row_bytes
     finally:
         eng.close()
 
