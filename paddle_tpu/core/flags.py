@@ -139,8 +139,3 @@ define_flag("pallas_routing", "auto",
             "Pallas-vs-XLA kernel routing: 'auto' follows the measured "
             "per-shape table (paddle_tpu/kernels/routing.py), 'always' "
             "forces every flag-enabled kernel, 'never' disables Pallas")
-define_flag("flash_block_q", 256,
-            "Flash-attention query block rows (kernel tile size); "
-            "env-tunable so on-chip sweeps need no code edits")
-define_flag("flash_block_k", 512,
-            "Flash-attention key/value block rows streamed through VMEM")

@@ -183,15 +183,14 @@ def _ring_core_bwd(axis_name, axis_size, causal, scale, res, g):
     lse (p = exp(s·scale - lse_global) is then the exact softmax slice),
     accumulate dq locally while dk/dv travel WITH their block — after the
     full cycle (+1 closing rotation) they are back at the owner rank."""
-    from ...kernels.flash_attention import _flash_bwd, _pick_block, \
+    from ...kernels.flash_attention import _flash_bwd, _tiles, \
         _interpret_default
     q, k, v, out, lse = res
     B, Sl, H, D = q.shape
     my = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
     interpret = _interpret_default()
-    bq = _pick_block(Sl, 256)
-    bk = _pick_block(Sl, 512)
+    tiles = _tiles(Sl, Sl, D, q.dtype)
 
     def to3(x):
         return jnp.moveaxis(x, 1, 2).reshape(B * H, x.shape[1], D)
@@ -214,7 +213,7 @@ def _ring_core_bwd(axis_name, axis_size, causal, scale, res, g):
             dv = jax.lax.ppermute(dv, axis_name, perm)
         dq_t, dk_t, dv_t = _flash_bwd(
             (q3, to3(k_blk), to3(v_blk), o3, lse3), g3, scale,
-            causal and t == 0, bq, bk, interpret)
+            causal and t == 0, tiles, interpret)
         if causal and t > 0:
             w = (my >= t).astype(jnp.float32)
             dq_t, dk_t, dv_t = dq_t * w, dk_t * w, dv_t * w

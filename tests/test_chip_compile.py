@@ -78,6 +78,30 @@ def test_flash_attention_fwd_bwd(topo, varlen):
         _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
 
 
+@pytest.mark.parametrize("seq,head_dim,dtype", [
+    (4096, 128, BF16),              # mistral-7b.train-4k's attention
+    (4096, 128, jnp.float32), (4096, 64, BF16), (4096, 64, jnp.float32),
+    (4096, 256, BF16), (4096, 256, jnp.float32), (2048, 128, BF16),
+    (8192, 128, BF16)])
+def test_flash_attention_planned_tiles(topo, seq, head_dim, dtype):
+    """Mosaic takes the tiles ``flash_attention_plan`` picks from the
+    shape and the dtype, under the scoped-VMEM limit the kernels state,
+    for all three kernels: the plan's budget arithmetic against the
+    chip's compiler."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    plan = fa.flash_attention_plan(seq, seq, head_dim, dtype, causal=True)
+    assert all(p["vmem_bytes"] <= fa.VMEM_BUDGET for p in plan.values())
+    s = _one(topo)
+
+    def loss(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)),
+             *[s((2, seq, 4, head_dim), dtype)] * 3)
+
+
 @pytest.mark.parametrize("slots,sq,rows,kv_heads,slab_heads,route", [
     (16, 1, S, H, H, "slab_in_place"),          # gpt3-6.7b.serve-chat
     (8, 1, 512, 16, 192 * 16, "slab_in_place"),  # one of Ouro-2.6B's planes

@@ -693,3 +693,245 @@ def test_layer_norm_flag_routing(monkeypatch):
     np.testing.assert_allclose(np.asarray(out_fused),
                                np.asarray(out_default), rtol=1e-5,
                                atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# flash attention on bf16 inputs (PR 35): operands reach the MXU as given
+# ---------------------------------------------------------------------------
+
+# bf16 keeps 8 significant bits: rounding to nearest moves a value by at
+# most U = 2^-8 of its magnitude.  The bounds below count roundings; no
+# factor in them was fitted to a run.
+U = 2.0 ** -8
+
+
+def _bf16_case(name):
+    """``(kernel, reference, (q, k, v))`` of one bf16 case: both take
+    ``(q, k, v)`` in any dtype, layout [B, S, H, D]."""
+    from paddle_tpu.kernels import flash_attention_varlen
+    sq, sk, h, kh, causal, blocks = {
+        "dense": (128, 128, 2, 2, False, {}),
+        "causal": (128, 128, 2, 2, True, {}),
+        "gqa": (128, 128, 4, 2, True, {}),
+        "cross_length": (64, 128, 2, 2, True, {}),
+        "uneven_blocks": (96, 96, 2, 2, True, {}),
+        # several tiles each way: the online softmax rescales across k
+        # tiles and the causal grid skips the tiles above the diagonal
+        "tiled": (256, 256, 2, 2, True, {"block_q": 64, "block_k": 128}),
+        "varlen": (128, 128, 2, 2, True, {"block_q": 64, "block_k": 64}),
+    }[name]
+    rs = np.random.RandomState(len(name))
+    q, k, v = (jnp.asarray(rs.randn(2, s, n, 64) * 0.5, jnp.bfloat16)
+               for s, n in ((sq, h), (sk, kh), (sk, kh)))
+    if name != "varlen":
+        return (lambda q, k, v: flash_attention(
+                    q, k, v, causal=causal, interpret=True, **blocks),
+                lambda q, k, v: sdpa_reference(
+                    q, k, v, is_causal=causal, training=False),
+                (q, k, v))
+    seg = np.zeros((2, sq), np.int32)   # two packs: [50, 78], [30, 60, 38]
+    seg[0, 50:] = 1
+    seg[1, 30:90] = 1
+    seg[1, 90:] = 2
+    seg = jnp.asarray(seg)
+    i = jnp.arange(sq)
+    mask = (seg[:, None, :, None] == seg[:, None, None, :]) \
+        & (i[None, None, None, :] <= i[None, None, :, None])
+    return (lambda q, k, v: flash_attention_varlen(
+                q, k, v, seg, seg, causal=True, interpret=True, **blocks),
+            lambda q, k, v: sdpa_reference(
+                q, k, v, attn_mask=mask, training=False),
+            (q, k, v))
+
+
+_BF16_CASES = ["dense", "causal", "gqa", "cross_length", "uneven_blocks",
+               "tiled", "varlen"]
+
+
+@pytest.mark.parametrize("case", _BF16_CASES)
+def test_flash_attention_bf16_forward(case):
+    """bf16 in, bf16 out.  Against the float32 reference on float32 copies
+    of the same inputs the kernel commits two roundings, each at most U
+    of the largest value a row mixes: P before ``P V`` (the scores and the
+    denominator are float32) and the result.  ``sdpa_reference`` on the
+    bf16 inputs commits the same two, so the pair may differ by four."""
+    kernel, reference, (q, k, v) = _bf16_case(case)
+    out = kernel(q, k, v)
+    assert out.dtype == jnp.bfloat16
+    out = np.asarray(out, np.float32)
+    vmax = float(jnp.max(jnp.abs(v.astype(jnp.float32))))
+    exact = reference(*(x.astype(jnp.float32) for x in (q, k, v)))
+    np.testing.assert_allclose(out, np.asarray(exact), rtol=0,
+                               atol=2 * U * vmax)
+    same = reference(q, k, v)
+    assert same.dtype == jnp.bfloat16
+    np.testing.assert_allclose(out, np.asarray(same, np.float32), rtol=0,
+                               atol=4 * U * vmax)
+
+
+@pytest.mark.parametrize("case", _BF16_CASES)
+def test_flash_attention_bf16_grad(case):
+    """Gradients of ``sum(out * w)`` in bf16.  Roundings on the way to a
+    gradient: the saved output (inside ``delta``), dO, P or dS before
+    their matmuls, the result: four, each at most U of the largest term
+    the gradient sums, taken as the gradient's own largest magnitude;
+    the bf16 reference's backward commits as many of its own."""
+    kernel, reference, (q, k, v) = _bf16_case(case)
+    rs = np.random.RandomState(1)
+    w = jnp.asarray(rs.randn(*q.shape[:3], 64), jnp.bfloat16)
+
+    def grads(fn, dtype):
+        args = [x.astype(dtype) for x in (q, k, v)]
+        return jax.grad(lambda q, k, v: jnp.sum(
+            fn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32)),
+            argnums=(0, 1, 2))(*args)
+
+    got = grads(kernel, jnp.bfloat16)
+    exact = grads(reference, jnp.float32)
+    same = grads(reference, jnp.bfloat16)
+    for g, e, s, name in zip(got, exact, same, "qkv"):
+        assert g.dtype == jnp.bfloat16
+        g = np.asarray(g, np.float32)
+        scale = float(jnp.max(jnp.abs(e)))
+        np.testing.assert_allclose(g, np.asarray(e), rtol=0,
+                                   atol=4 * U * scale,
+                                   err_msg=f"d{name} against float32")
+        np.testing.assert_allclose(g, np.asarray(s, np.float32), rtol=0,
+                                   atol=8 * U * scale,
+                                   err_msg=f"d{name} against bf16")
+
+
+def _pallas_dots(fn, *args):
+    """``(lhs dtype, rhs dtype, result dtype)`` of every ``dot_general``
+    inside the ``pallas_call`` bodies of ``fn``'s jaxpr, by kernel name."""
+    found = {}
+
+    def walk(jaxpr, kernel):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general" and kernel:
+                found.setdefault(kernel, []).append(
+                    tuple(str(x.aval.dtype) for x in
+                          (*eqn.invars, eqn.outvars[0])))
+            inner = kernel
+            if eqn.primitive.name == "pallas_call":
+                inner = eqn.params["name"]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_attention_dots_take_the_inputs_dtype(dtype):
+    """All nine matmuls of the three kernels take their operands in the
+    inputs' dtype (P and dS rounded to it) and accumulate in float32:
+    bf16 inputs cost one MXU pass a product, float32 inputs behave as
+    they always did."""
+    q, k, v = (x.astype(dtype) for x in _qkv(b=1, s=128, h=2, d=64))
+    dots = _pallas_dots(
+        jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=True).astype(jnp.float32)),
+            argnums=(0, 1, 2)), q, k, v)
+    assert {name: len(d) for name, d in dots.items()} == {
+        "flash_attention_fwd": 2, "flash_attention_bwd_dq": 3,
+        "flash_attention_bwd_dkv": 4}
+    for name, kernel_dots in dots.items():
+        assert set(kernel_dots) == {(dtype, dtype, "float32")}, name
+
+
+# ---------------------------------------------------------------------------
+# flash_attention_plan: the tiles, from the shapes and the dtype
+# ---------------------------------------------------------------------------
+
+def _plan(*args, **kw):
+    import importlib
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    return fa, fa.flash_attention_plan(*args, **kw)
+
+
+@pytest.mark.parametrize("sq,sk", [
+    (4096, 4096), (2048, 2048), (96, 96), (64, 128), (32, 96), (320, 320),
+    (192, 192), (2048, 8192), (3000, 3000)])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_attention_plan_tiles_divide_and_count(sq, sk, dtype):
+    """Every kernel's tile divides both sequences, and the computing
+    steps equal a brute-force count of the tiles that hold at least one
+    (query, key) pair the bottom-right-aligned causal mask admits."""
+    _, plan = _plan(sq, sk, 128, dtype, causal=True)
+    _, dense = _plan(sq, sk, 128, dtype, causal=False)
+    assert set(plan) == {"fwd", "bwd_dq", "bwd_dkv"}
+    off = sk - sq
+    for kernel, p in plan.items():
+        bq, bk = p["block_q"], p["block_k"]
+        assert sq % bq == 0 and sk % bk == 0, (kernel, p)
+        assert p["operand_dtype"] == dtype
+        nq, nk = sq // bq, sk // bk
+        assert p["grid_steps"] == nq * nk == dense[kernel]["grid_steps"]
+        assert dense[kernel]["computing_steps"] == nq * nk
+        brute = sum(1 for qi in range(nq) for ki in range(nk)
+                    if ki * bk <= qi * bq + bq - 1 + off)
+        assert p["computing_steps"] == brute, (kernel, p)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128, 256])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_attention_plan_fits_vmem_budget(head_dim, dtype):
+    """At long sequences every kernel's grid step stays inside the budget
+    the kernels hand Mosaic as their scoped-VMEM limit, and the tile
+    shrinks rather than the budget stretching when the budget is small."""
+    fa, plan = _plan(8192, 8192, head_dim, dtype, causal=True)
+    for kernel, p in plan.items():
+        assert p["vmem_bytes"] <= fa.VMEM_BUDGET, (kernel, p)
+        assert p["vmem_bytes"] == fa._vmem_bytes(
+            kernel, p["block_q"], p["block_k"], head_dim,
+            jnp.dtype(dtype).itemsize)
+    whole = fa.VMEM_BUDGET
+    try:
+        fa.VMEM_BUDGET = 4 * 1024 * 1024
+        small = fa.flash_attention_plan(8192, 8192, head_dim, dtype, True)
+    finally:
+        fa.VMEM_BUDGET = whole
+    for kernel, p in small.items():
+        assert p["vmem_bytes"] <= 4 * 1024 * 1024, (kernel, p)
+        assert p["block_q"] * p["block_k"] \
+            < plan[kernel]["block_q"] * plan[kernel]["block_k"]
+
+
+def test_flash_attention_explicit_blocks_override_the_plan():
+    """``block_q=`` / ``block_k=`` reach all three kernels (clamped to a
+    divisor); left out, the plan's tiles do."""
+    fa, plan = _plan(256, 256, 64, "float32", causal=True)
+    assert fa._tiles(256, 256, 64, jnp.float32, 64, 128) == fa._Tiles(
+        (64, 128), (64, 128), (64, 128))
+    assert fa._tiles(96, 96, 64, jnp.float32, 64, None).fwd == (32, 96)
+    assert fa._tiles(256, 256, 64, jnp.float32) == fa._Tiles(*(
+        (plan[k]["block_q"], plan[k]["block_k"])
+        for k in ("fwd", "bwd_dq", "bwd_dkv")))
+
+
+def test_flash_attention_cost_script_prints_no_time_off_chip(capsys):
+    """``scripts/flash_attention_cost.py`` without a TPU: the plan and
+    the causal FLOPs of each kernel, never a time."""
+    import json
+    from scripts import flash_attention_cost as C
+    assert C.main(["--shapes", "64x4096x128,16x2048x128",
+                   "--dtypes", "bfloat16"]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert lines[0].startswith("platform=cpu")
+    rows = [json.loads(line) for line in lines[1:]]
+    assert [r["shape"] for r in rows] == [[64, 4096, 128], [16, 2048, 128]]
+    for row in rows:
+        assert set(row) == {"shape", "dtype", "plan", "causal_gflop"}
+        assert "ms" not in json.dumps(row)
+        assert set(row["plan"]) == set(C.KERNELS)
+    # forward: 2 matmuls x 2 x 64 x 4096^2 x 128 / 2 (ISSUE 35: 275 GFLOP)
+    assert rows[0]["causal_gflop"] == {"fwd": 274.88, "bwd_dq": 412.32,
+                                       "bwd_dkv": 549.76}
+    # the cell's shape takes 1024 x 1024 in every kernel; at 2048 the
+    # backward pair takes 512 x 512 (PERF.md section 6, PR 35)
+    tiles = [{k: (p["block_q"], p["block_k"]) for k, p in r["plan"].items()}
+             for r in rows]
+    assert tiles[0] == dict.fromkeys(C.KERNELS, (1024, 1024))
+    assert tiles[1] == {"fwd": (1024, 1024), "bwd_dq": (512, 512),
+                        "bwd_dkv": (512, 512)}
