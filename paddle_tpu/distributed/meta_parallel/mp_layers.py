@@ -230,16 +230,17 @@ def parallel_cross_entropy(logits, label, ignore_index: int = -100,
     reductions over the sharded vocab axis with exactly those collectives.
     """
     vocab_sharded = P(*([None] * (logits.ndim - 1)), mp_axis)
-    logits = _maybe_constraint(logits, vocab_sharded)
-    x = logits.astype(jnp.float32)
-    m = jnp.max(x, axis=-1, keepdims=True)
-    lse = m + jnp.log(jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True))
-    lbl = label.astype(jnp.int32)
-    valid = lbl != ignore_index
-    safe = jnp.where(valid, lbl, 0)
-    picked = jnp.take_along_axis(x, safe[..., None], axis=-1)
-    loss = (lse - picked)[..., 0]
-    return jnp.where(valid, loss, 0.0)
+    with jax.named_scope("loss"):
+        logits = _maybe_constraint(logits, vocab_sharded)
+        x = logits.astype(jnp.float32)
+        m = jnp.max(x, axis=-1, keepdims=True)
+        lse = m + jnp.log(jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True))
+        lbl = label.astype(jnp.int32)
+        valid = lbl != ignore_index
+        safe = jnp.where(valid, lbl, 0)
+        picked = jnp.take_along_axis(x, safe[..., None], axis=-1)
+        loss = (lse - picked)[..., 0]
+        return jnp.where(valid, loss, 0.0)
 
 
 class ParallelCrossEntropy(Layer):

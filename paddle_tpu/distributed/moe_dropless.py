@@ -237,20 +237,27 @@ class DroplessMoE(Layer):
         dt = self.gate_proj.dtype
         if live is None:
             live = jnp.ones((t,), bool)
-        idx, w = self.route(u32)
-        order, rows, inverse = sort_by_expert(idx, live, self.num_experts)
+        # the three parts of an expert layer (obs/parts.py): the scores,
+        # the choice and the sort; the routed experts from the gather to
+        # the weighted sum; the shared expert and its add
+        with jax.named_scope("router"):
+            idx, w = self.route(u32)
+            order, rows, inverse = sort_by_expert(idx, live,
+                                                  self.num_experts)
         u = u32.astype(dt)
-        x = u[order // self.top_k]                       # [t * k, h]
-        a = (F.silu(grouped_matmul(x, self.gate_proj, rows))
-             * grouped_matmul(x, self.up_proj, rows)).astype(dt)
-        y = grouped_matmul(a, self.down_proj, rows)      # [t * k, h] f32
-        y = y[inverse].reshape(t, self.top_k, h)
-        # a row that is not live was computed by no expert: select, do
-        # not multiply (what the kernel left there is not a number)
-        y = jnp.where(live[:, None, None], y, 0.0)
-        out = jnp.sum(y * w[..., None], axis=1)
+        with jax.named_scope("experts"):
+            x = u[order // self.top_k]                   # [t * k, h]
+            a = (F.silu(grouped_matmul(x, self.gate_proj, rows))
+                 * grouped_matmul(x, self.up_proj, rows)).astype(dt)
+            y = grouped_matmul(a, self.down_proj, rows)  # [t * k, h] f32
+            y = y[inverse].reshape(t, self.top_k, h)
+            # a row that is not live was computed by no expert: select,
+            # do not multiply (what the kernel left there is not a number)
+            y = jnp.where(live[:, None, None], y, 0.0)
+            out = jnp.sum(y * w[..., None], axis=1)
         if self.n_shared:
-            out = out + self.shared_experts(u)
+            with jax.named_scope("shared_expert"):
+                out = out + self.shared_experts(u)
         return out, rows
 
 

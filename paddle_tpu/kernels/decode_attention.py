@@ -641,8 +641,9 @@ def append_and_attend(q, k_new, v_new, k_slab, v_slab, pos, *, head0=0,
     route, _ = decode_attention_route(q.shape, k_slab.shape, k_slab.dtype,
                                       kv_heads)
     if route != "slab_in_place":
-        k_slab, v_slab = append_kv(k_slab, v_slab, k_new, v_new, pos,
-                                   head0)
+        with jax.named_scope("kv_append"):
+            k_slab, v_slab = append_kv(k_slab, v_slab, k_new, v_new, pos,
+                                       head0)
         out = _routed(route, q, k_slab, v_slab, cache_lens(pos, sq, b),
                       scale=scale, causal_tail=causal_tail,
                       interpret=interpret, head0=head0, kv_heads=kv_heads)

@@ -836,16 +836,21 @@ def decode_block_layer(x, k_slab, v_slab, seq_pos, *, kv_heads, head_dim,
             f"decode_block_layer: no VMEM tiling fits this shape "
             f"({why}) — gate on fusion_legal/fused_decode_supported "
             f"before calling the fused path")
-    attn, k2, v2 = decode_block_attn(
-        x, k_slab, v_slab, seq_pos, norm1_w, norm1_b, wq, wk, wv,
-        bq, bkv, bv, kv_heads=kv_heads, head_dim=head_dim, norm=norm,
-        eps=eps1, rope_cos=rope_cos, rope_sin=rope_sin,
-        block_k=block_k or plan["block_k"], block_n=plan["block_n"],
-        interpret=interpret)
-    y = decode_block_mlp(
-        x, attn, wo, bo, norm2_w, norm2_b, w1, b1, w2, b2, w_gate,
-        norm=norm, eps=eps2, act=act, block_f=block_f or plan["block_f"],
-        block_o=plan["block_o"], interpret=interpret)
+    # the two kernels are the layer's two parts (obs/parts.py): each
+    # holds its branch's norm, and the second the attention's out-proj
+    with jax.named_scope("attention"):
+        attn, k2, v2 = decode_block_attn(
+            x, k_slab, v_slab, seq_pos, norm1_w, norm1_b, wq, wk, wv,
+            bq, bkv, bv, kv_heads=kv_heads, head_dim=head_dim, norm=norm,
+            eps=eps1, rope_cos=rope_cos, rope_sin=rope_sin,
+            block_k=block_k or plan["block_k"], block_n=plan["block_n"],
+            interpret=interpret)
+    with jax.named_scope("mlp"):
+        y = decode_block_mlp(
+            x, attn, wo, bo, norm2_w, norm2_b, w1, b1, w2, b2, w_gate,
+            norm=norm, eps=eps2, act=act,
+            block_f=block_f or plan["block_f"], block_o=plan["block_o"],
+            interpret=interpret)
     return y, k2, v2
 
 

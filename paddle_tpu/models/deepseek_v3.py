@@ -310,7 +310,8 @@ class DeepseekV3Attention(Layer):
             held, lens, new_cache = row[:, :, 0], jnp.full((b,), s), None
         else:
             buf, _, pos = cache
-            buf = append_rows(buf, row, pos)
+            with jax.named_scope("kv_append"):
+                buf = append_rows(buf, row, pos)
             held, lens = buf[:, :, 0], cache_lens(pos, s, b)
             new_cache = (buf, None, pos + s)
         outs = []
@@ -354,15 +355,20 @@ class DeepseekV3DecoderLayer(Layer):
         layer)."""
         b, s, h = x.shape
         dt = self.input_layernorm.weight.dtype
-        a, cache = self.self_attn(self.input_layernorm(x).astype(dt),
-                                  cos, sin, cache)
-        x = x + a
+        y = self.input_layernorm(x).astype(dt)
+        # a branch's scope holds its residual add: a fusion is known by
+        # its root (obs/parts.py); the expert layer names its own parts
+        with jax.named_scope("attention"):
+            a, cache = self.self_attn(y, cos, sin, cache)
+            x = x + a
         u = self.post_attention_layernorm(x)             # float32
         if self.sparse:
             y, rows = self.mlp(u.reshape(b * s, h), None if live is None
                                else live.reshape(b * s))
-            return x + y.reshape(b, s, h), cache, rows
-        return x + self.mlp(u.astype(dt)), cache, None
+            with jax.named_scope("experts"):
+                return x + y.reshape(b, s, h), cache, rows
+        with jax.named_scope("mlp"):
+            return x + self.mlp(u.astype(dt)), cache, None
 
 
 class DeepseekV3Model(Layer):
@@ -383,10 +389,12 @@ class DeepseekV3Model(Layer):
         experts] int32)``."""
         cfg = self.cfg
         b, s = input_ids.shape
-        emb = self.embed_tokens(input_ids)
-        pos = jnp.asarray(position)[..., None] + jnp.arange(s)
-        cos, sin = _rope_tables(pos, cfg.qk_rope_head_dim, cfg.rope_theta,
-                                jnp.float32)
+        with jax.named_scope("embed"):
+            emb = self.embed_tokens(input_ids)
+        with jax.named_scope("attention"):
+            pos = jnp.asarray(position)[..., None] + jnp.arange(s)
+            cos, sin = _rope_tables(pos, cfg.qk_rope_head_dim,
+                                    cfg.rope_theta, jnp.float32)
         live = None
         if valid is not None:
             live = jnp.arange(s)[None, :] < jnp.broadcast_to(
@@ -426,8 +434,12 @@ class DeepseekV3ForCausalLM(Layer):
             weight_attr=ParamAttr(
                 initializer=I.Normal(0.0, cfg.initializer_range)))
 
+    def _head(self, hidden):
+        with jax.named_scope("head"):
+            return self.lm_head(hidden)
+
     def forward(self, input_ids):
-        return self.lm_head(self.model(input_ids)[0])
+        return self._head(self.model(input_ids)[0])
 
     # ---- what a request carries ----------------------------------------
     def init_cache(self, batch: int, max_len: int, dtype=None):
@@ -449,7 +461,7 @@ class DeepseekV3ForCausalLM(Layer):
         caches = [(k, None, position) for k, _, _ in caches]
         hidden, caches, rows = self.model(input_ids, caches, position,
                                           valid)
-        return self.lm_head(hidden), caches, rows
+        return self._head(hidden), caches, rows
 
     # ---- what the serving engine reads ---------------------------------
     def expert_routing_spec(self):

@@ -149,10 +149,17 @@ class GPTBlock(Layer):
             from ..distributed.meta_parallel.sequence_parallel import seq_sharded
             # LN/dropout run seq-sharded ([b, s/mp, h] — batch-major variant)
             x = _maybe_constraint(x, P(None, "mp", None))
-        a, new_cache = self._attn(self.ln_1(x), cache)
-        x = x + self.drop(a)
-        m = self.fc_out(F.gelu(self.fc_in(self.ln_2(x)), approximate=True))
-        x = x + self.drop(m)
+        # a part's scope holds its branch's residual add: XLA makes the
+        # add the root of the last projection's fusion, and a fusion is
+        # known by its root (obs/parts.py)
+        y = self.ln_1(x)
+        with jax.named_scope("attention"):
+            a, new_cache = self._attn(y, cache)
+            x = x + self.drop(a)
+        y = self.ln_2(x)
+        with jax.named_scope("mlp"):
+            m = self.fc_out(F.gelu(self.fc_in(y), approximate=True))
+            x = x + self.drop(m)
         if cache is not None:
             return x, new_cache
         return x
@@ -202,12 +209,13 @@ class GPTModel(Layer):
         # written as offset + static arange so position_offset may be a
         # traced value (the generate() scan carries it); a [b] offset
         # vector gives per-row positions (ragged continuous batching)
-        off = jnp.asarray(position_offset)
-        pos = off[..., None] + jnp.arange(s)
-        if pos.ndim == 1:
-            pos = pos[None, :]
-        x = self.wte(input_ids) + self.wpe(pos)
-        return self.drop(x)
+        with jax.named_scope("embed"):
+            off = jnp.asarray(position_offset)
+            pos = off[..., None] + jnp.arange(s)
+            if pos.ndim == 1:
+                pos = pos[None, :]
+            x = self.wte(input_ids) + self.wpe(pos)
+            return self.drop(x)
 
     def forward(self, input_ids, caches=None):
         from ..distributed.recompute import remat_wrap
@@ -236,11 +244,12 @@ class GPTForCausalLM(Layer):
                                                 has_bias=False)
 
     def logits(self, hidden):
-        if self.cfg.tie_embeddings:
-            w = self.gpt.wte.weight  # [vocab, h] mp-sharded on vocab
-            lg = jnp.einsum("bsh,vh->bsv", hidden, w)
-            return _maybe_constraint(lg, P(None, None, "mp"))
-        return self.lm_head(hidden)
+        with jax.named_scope("head"):
+            if self.cfg.tie_embeddings:
+                w = self.gpt.wte.weight  # [vocab, h] mp-sharded on vocab
+                lg = jnp.einsum("bsh,vh->bsv", hidden, w)
+                return _maybe_constraint(lg, P(None, None, "mp"))
+            return self.lm_head(hidden)
 
     def forward(self, input_ids):
         hidden = self.gpt(input_ids)

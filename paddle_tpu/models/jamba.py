@@ -353,18 +353,24 @@ class JambaDecoderLayer(Layer):
         # dtype; each branch reads it normed, in the weights' dtype
         dt = self.input_layernorm.weight.dtype
         y = self.input_layernorm(x).astype(dt)
+        # a branch's scope holds its residual add: a fusion is known by
+        # its root (obs/parts.py)
         if self.kind == "attention":
-            a, carried = self.self_attn(y, carried)
+            with jax.named_scope("attention"):
+                a, carried = self.self_attn(y, carried)
+                x = x + a
         else:
-            a, carried = self.mamba(y, carried, valid)
-        x = x + a
+            with jax.named_scope("mixer"):
+                a, carried = self.mamba(y, carried, valid)
+                x = x + a
         # models/llama.py's gated MLP on its own weights, the gate and
         # the branch's output left float32 (:func:`_wide`)
         ff = self.feed_forward
         y = self.pre_ff_layernorm(x).astype(dt)
-        y = (F.silu(_wide(ff.gate_proj, y))
-             * _wide(ff.up_proj, y)).astype(dt)
-        return x + _wide(ff.down_proj, y), carried
+        with jax.named_scope("mlp"):
+            y = (F.silu(_wide(ff.gate_proj, y))
+                 * _wide(ff.up_proj, y)).astype(dt)
+            return x + _wide(ff.down_proj, y), carried
 
 
 class JambaModel(Layer):
@@ -387,7 +393,8 @@ class JambaModel(Layer):
         ``(k, v, pos)`` per attention layer; ``state``: one dict per
         Mamba layer (None: zeros, a fresh sequence).  Returns ``(hidden,
         caches', state')``."""
-        emb = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            emb = self.embed_tokens(input_ids)
         if state is None:
             state = _zero_state(self.cfg, input_ids.shape[0], emb.dtype)
         # 56 residual adds in bfloat16 cost 0.044 of the logits' scale
@@ -449,7 +456,8 @@ class JambaForCausalLM(Layer):
 
     def _head(self, hidden):
         # tied: the embedding table is the head
-        return hidden @ self.jamba.embed_tokens.weight.T
+        with jax.named_scope("head"):
+            return hidden @ self.jamba.embed_tokens.weight.T
 
     def forward(self, input_ids):
         return self._head(self.jamba(input_ids)[0])

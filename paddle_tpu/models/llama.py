@@ -162,9 +162,16 @@ class LlamaDecoderLayer(Layer):
         self.drop = Dropout(cfg.dropout)
 
     def forward(self, x, cos, sin, cache=None):
-        a, new_cache = self.self_attn(self.input_layernorm(x), cos, sin, cache)
-        x = x + self.drop(a)
-        x = x + self.drop(self.mlp(self.post_attention_layernorm(x)))
+        # a part's scope holds its branch's residual add: XLA makes the
+        # add the root of the last projection's fusion, and a fusion is
+        # known by its root (obs/parts.py)
+        y = self.input_layernorm(x)
+        with jax.named_scope("attention"):
+            a, new_cache = self.self_attn(y, cos, sin, cache)
+            x = x + self.drop(a)
+        y = self.post_attention_layernorm(x)
+        with jax.named_scope("mlp"):
+            x = x + self.drop(self.mlp(y))
         if cache is not None:
             return x, new_cache
         return x
@@ -207,11 +214,14 @@ class LlamaModel(Layer):
     def forward(self, input_ids, caches=None, position_offset: int = 0):
         cfg = self.cfg
         b, s = input_ids.shape
-        x = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids)
         # offset + static arange: position_offset may be traced (generate);
         # a [b] offset vector gives per-row positions (ragged batching)
-        pos = jnp.asarray(position_offset)[..., None] + jnp.arange(s)
-        cos, sin = _rope_tables(pos, cfg.head_dim, cfg.rope_theta, x.dtype)
+        with jax.named_scope("attention"):
+            pos = jnp.asarray(position_offset)[..., None] + jnp.arange(s)
+            cos, sin = _rope_tables(pos, cfg.head_dim, cfg.rope_theta,
+                                    x.dtype)
         new_caches = []
         for i, layer in enumerate(self.layers):
             if caches is None:
@@ -232,14 +242,20 @@ class LlamaForCausalLM(Layer):
         self.llama = LlamaModel(cfg)
         self.lm_head = Linear(cfg.hidden_size, cfg.vocab_size, bias_attr=False)
 
+    def _head(self, hidden):
+        with jax.named_scope("head"):
+            return self.lm_head(hidden)
+
     def forward(self, input_ids):
-        return self.lm_head(self.llama(input_ids))
+        return self._head(self.llama(input_ids))
 
     def loss(self, input_ids, labels):
-        logits = self(input_ids).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        tok = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        return -jnp.mean(tok)
+        logits = self(input_ids)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            tok = jnp.take_along_axis(logp, labels[..., None],
+                                      axis=-1)[..., 0]
+            return -jnp.mean(tok)
 
     def chunked_loss(self, input_ids, labels, n_chunks: int = 8):
         """Causal LM loss without materializing [b, s, V] logits (the
@@ -265,7 +281,7 @@ class LlamaForCausalLM(Layer):
     def decode_step(self, input_ids, caches, position: int):
         hidden, new_caches = self.llama(input_ids, caches,
                                         position_offset=position)
-        return self.lm_head(hidden), new_caches
+        return self._head(hidden), new_caches
 
     def fused_decode_supported(self, batch: int = 1,
                                kv_len: Optional[int] = None,
@@ -289,20 +305,22 @@ class LlamaForCausalLM(Layer):
         tables computed once at each row's position (full-width, halves
         duplicated: the kernel applies rotary in matrix form)."""
         cfg = self.cfg
-        x = self.llama.embed_tokens(input_ids)
-        pos = jnp.asarray(position, jnp.int32)
-        if pos.ndim == 0:
-            pos = jnp.full((x.shape[0],), pos, jnp.int32)
-        cos, sin = _rope_tables(pos, cfg.head_dim, cfg.rope_theta,
-                                jnp.float32)                 # [B, d/2]
-        cos_full = jnp.concatenate([cos, cos], axis=-1)
-        sin_full = jnp.concatenate([sin, sin], axis=-1)
+        with jax.named_scope("embed"):
+            x = self.llama.embed_tokens(input_ids)
+        with jax.named_scope("attention"):
+            pos = jnp.asarray(position, jnp.int32)
+            if pos.ndim == 0:
+                pos = jnp.full((x.shape[0],), pos, jnp.int32)
+            cos, sin = _rope_tables(pos, cfg.head_dim, cfg.rope_theta,
+                                    jnp.float32)             # [B, d/2]
+            cos_full = jnp.concatenate([cos, cos], axis=-1)
+            sin_full = jnp.concatenate([sin, sin], axis=-1)
         new_caches = []
         for layer, cache in zip(self.llama.layers, caches):
             x, c = layer.fused_decode_step(x, cos_full, sin_full, cache)
             new_caches.append(c)
         x = self.llama.norm(x)
-        return self.lm_head(x), new_caches
+        return self._head(x), new_caches
 
     def generate(self, input_ids, max_new_tokens: int, **kw):
         """Single-scan autoregressive decoding (models/generation.py)."""

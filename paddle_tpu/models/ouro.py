@@ -187,10 +187,12 @@ class OuroModel(Layer):
         def heads(name, n):         # these three are held [out, in]
             return jnp.einsum("bsh,oh->bso", y, w[name]).reshape(b, s, n, d)
 
-        q = heads("q_proj", cfg.num_heads)
-        k, v = heads("k_proj", cfg.kv_heads), heads("v_proj", cfg.kv_heads)
-        return (apply_rotary_pos_emb(q, cos, sin).astype(dt),
-                apply_rotary_pos_emb(k, cos, sin).astype(dt), v)
+        with jax.named_scope("attention"):
+            q = heads("q_proj", cfg.num_heads)
+            k = heads("k_proj", cfg.kv_heads)
+            v = heads("v_proj", cfg.kv_heads)
+            return (apply_rotary_pos_emb(q, cos, sin).astype(dt),
+                    apply_rotary_pos_emb(k, cos, sin).astype(dt), v)
 
     def _finish(self, w, x, a):
         """Second half: ``x + N2(o_proj(a))``, then the SwiGLU branch
@@ -199,12 +201,16 @@ class OuroModel(Layer):
         eps = self.cfg.rms_norm_eps
         b, s, _ = x.shape
         dt = w["q_proj"].dtype
-        x = x + _norm(a.reshape(b, s, -1) @ w["o_proj"],
-                      w["input_layernorm_2"], eps)
+        # a branch's scope holds its residual add (a fusion is known by
+        # its root, obs/parts.py); the norms inside name themselves
+        with jax.named_scope("attention"):
+            x = x + _norm(a.reshape(b, s, -1) @ w["o_proj"],
+                          w["input_layernorm_2"], eps)
         y = _norm(x, w["post_attention_layernorm"], eps).astype(dt)
-        y = (F.silu(y @ w["gate_proj"]) * (y @ w["up_proj"])) \
-            @ w["down_proj"]
-        return x + _norm(y, w["post_attention_layernorm_2"], eps)
+        with jax.named_scope("mlp"):
+            y = (F.silu(y @ w["gate_proj"]) * (y @ w["up_proj"])) \
+                @ w["down_proj"]
+            return x + _norm(y, w["post_attention_layernorm_2"], eps)
 
     def forward(self, input_ids, caches=None, position_offset=0):
         """``caches``: None (a full causal forward), or the one-slab
@@ -214,10 +220,12 @@ class OuroModel(Layer):
         cfg = self.cfg
         n, passes, kvh = cfg.num_layers, cfg.total_ut_steps, cfg.kv_heads
         b, s = input_ids.shape
-        emb = self.embed_tokens(input_ids)
-        pos = jnp.asarray(position_offset)[..., None] + jnp.arange(s)
-        cos, sin = _rope_tables(pos, cfg.head_dim, cfg.rope_theta,
-                                jnp.float32)
+        with jax.named_scope("embed"):
+            emb = self.embed_tokens(input_ids)
+        with jax.named_scope("attention"):
+            pos = jnp.asarray(position_offset)[..., None] + jnp.arange(s)
+            cos, sin = _rope_tables(pos, cfg.head_dim, cfg.rope_theta,
+                                    jnp.float32)
         stack = self.layers.weights()
         slabs = None
         if caches is not None:
@@ -229,16 +237,17 @@ class OuroModel(Layer):
                 x, slabs = carry
                 w, l = xs
                 q, k, v = self._qkv(w, x, cos, sin)
-                if slabs is None:
-                    a = F.scaled_dot_product_attention(
-                        q, k, v, is_causal=True, training=False)
-                else:
-                    # the plane is a window of the slab's head axis:
-                    # the kernel writes and reads it where it lies
-                    a, ks, vs = append_and_attend(
-                        q, k, v, *slabs, cpos, kv_heads=kvh,
-                        head0=plane_index(t, l, n) * kvh)
-                    slabs = (ks, vs)
+                with jax.named_scope("attention"):
+                    if slabs is None:
+                        a = F.scaled_dot_product_attention(
+                            q, k, v, is_causal=True, training=False)
+                    else:
+                        # the plane is a window of the slab's head axis:
+                        # the kernel writes and reads it where it lies
+                        a, ks, vs = append_and_attend(
+                            q, k, v, *slabs, cpos, kv_heads=kvh,
+                            head0=plane_index(t, l, n) * kvh)
+                        slabs = (ks, vs)
                 return (self._finish(w, x, a), slabs), None
             return body
 
@@ -255,10 +264,12 @@ class OuroModel(Layer):
             (x, slabs), _ = jax.lax.scan(
                 layer(t), (x, slabs), (stack, jnp.arange(n)))
             h = _norm(x, self.norm.weight, cfg.rms_norm_eps)
-            lam = jax.nn.sigmoid((h @ gate_w)[..., 0] + gate_b[0])
-            survive = survive * (1.0 - lam)
-            take = ~done & ((survive <= survive_bar) | (t == passes - 1))
-            out = jnp.where(take[..., None], h, out)
+            with jax.named_scope("exit_gate"):
+                lam = jax.nn.sigmoid((h @ gate_w)[..., 0] + gate_b[0])
+                survive = survive * (1.0 - lam)
+                take = ~done & ((survive <= survive_bar)
+                                | (t == passes - 1))
+                out = jnp.where(take[..., None], h, out)
             return (h, slabs, out, survive, done | take), None
 
         x0 = emb.astype(jnp.float32)
@@ -281,8 +292,12 @@ class OuroForCausalLM(Layer):
         self.lm_head = Linear(cfg.hidden_size, cfg.vocab_size,
                               bias_attr=False)
 
+    def _head(self, hidden):
+        with jax.named_scope("head"):
+            return self.lm_head(hidden)
+
     def forward(self, input_ids):
-        return self.lm_head(self.ouro(input_ids))
+        return self._head(self.ouro(input_ids))
 
     # ---- incremental decode -------------------------------------------
     def init_cache(self, batch: int, max_len: int, dtype=None):
@@ -298,7 +313,7 @@ class OuroForCausalLM(Layer):
     def decode_step(self, input_ids, caches, position):
         hidden, new_caches = self.ouro(input_ids, caches,
                                        position_offset=position)
-        return self.lm_head(hidden), new_caches
+        return self._head(hidden), new_caches
 
     def fused_decode_supported(self, batch: int = 1,
                                kv_len: Optional[int] = None, tp: int = 1):

@@ -26,49 +26,51 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon: float = 1e-
                name=None):
     if isinstance(normalized_shape, int):
         normalized_shape = (normalized_shape,)
-    axes = tuple(range(x.ndim - len(normalized_shape), x.ndim))
-    # fused Pallas path: the common last-dim affine case on TPU (one VPU
-    # pass, no HBM intermediates).  Constraints keep it strictly better
-    # than XLA: dtype-preserving params (no public dtype change vs the
-    # promoting XLA path), lane-aligned h bounded for VMEM, and row
-    # counts that tile into real blocks (no degenerate 1-row grids).
-    h_last = x.shape[-1]
-    rows = x.size // h_last if h_last else 0
-    if (len(axes) == 1 and axes[0] == x.ndim - 1 and weight is not None
-            and bias is not None and h_last % 128 == 0 and h_last <= 8192
-            and rows and rows % 8 == 0
-            and getattr(weight, "dtype", None) == x.dtype
-            and getattr(bias, "dtype", None) == x.dtype):
-        from ...core.flags import flags as _flags
-        from ...kernels.routing import use_pallas as _route
-        if (_flags.use_pallas_norm and _on_tpu()
-                and _route("layer_norm", rows=rows, h=h_last)):
-            import paddle_tpu.kernels as _k
-            return _k.fused_layer_norm_pallas(x, weight, bias, epsilon,
-                                              interpret=False)
-    x32 = x.astype(jnp.float32) if x.dtype in (jnp.float16, jnp.bfloat16) else x
-    mean = jnp.mean(x32, axis=axes, keepdims=True)
-    var = jnp.mean(jnp.square(x32 - mean), axis=axes, keepdims=True)
-    y = (x32 - mean) * jax.lax.rsqrt(var + epsilon)
-    y = y.astype(x.dtype)
-    if weight is not None:
-        y = y * weight
-    if bias is not None:
-        y = y + bias
-    return y
+    with jax.named_scope("norm"):
+        axes = tuple(range(x.ndim - len(normalized_shape), x.ndim))
+        # fused Pallas path: the common last-dim affine case on TPU (one VPU
+        # pass, no HBM intermediates).  Constraints keep it strictly better
+        # than XLA: dtype-preserving params (no public dtype change vs the
+        # promoting XLA path), lane-aligned h bounded for VMEM, and row
+        # counts that tile into real blocks (no degenerate 1-row grids).
+        h_last = x.shape[-1]
+        rows = x.size // h_last if h_last else 0
+        if (len(axes) == 1 and axes[0] == x.ndim - 1 and weight is not None
+                and bias is not None and h_last % 128 == 0 and h_last <= 8192
+                and rows and rows % 8 == 0
+                and getattr(weight, "dtype", None) == x.dtype
+                and getattr(bias, "dtype", None) == x.dtype):
+            from ...core.flags import flags as _flags
+            from ...kernels.routing import use_pallas as _route
+            if (_flags.use_pallas_norm and _on_tpu()
+                    and _route("layer_norm", rows=rows, h=h_last)):
+                import paddle_tpu.kernels as _k
+                return _k.fused_layer_norm_pallas(x, weight, bias, epsilon,
+                                                  interpret=False)
+        x32 = x.astype(jnp.float32) if x.dtype in (jnp.float16, jnp.bfloat16) else x
+        mean = jnp.mean(x32, axis=axes, keepdims=True)
+        var = jnp.mean(jnp.square(x32 - mean), axis=axes, keepdims=True)
+        y = (x32 - mean) * jax.lax.rsqrt(var + epsilon)
+        y = y.astype(x.dtype)
+        if weight is not None:
+            y = y * weight
+        if bias is not None:
+            y = y + bias
+        return y
 
 
 def rms_norm(x, weight=None, bias=None, epsilon: float = 1e-6, begin_norm_axis: int = -1):
     """paddle.incubate.nn.functional.rms_norm parity (Llama-family norm)."""
-    axes = tuple(range(begin_norm_axis % x.ndim, x.ndim)) if begin_norm_axis != -1 else (-1,)
-    x32 = x.astype(jnp.float32) if x.dtype in (jnp.float16, jnp.bfloat16) else x
-    ms = jnp.mean(jnp.square(x32), axis=axes, keepdims=True)
-    y = (x32 * jax.lax.rsqrt(ms + epsilon)).astype(x.dtype)
-    if weight is not None:
-        y = y * weight
-    if bias is not None:
-        y = y + bias
-    return y
+    with jax.named_scope("norm"):
+        axes = tuple(range(begin_norm_axis % x.ndim, x.ndim)) if begin_norm_axis != -1 else (-1,)
+        x32 = x.astype(jnp.float32) if x.dtype in (jnp.float16, jnp.bfloat16) else x
+        ms = jnp.mean(jnp.square(x32), axis=axes, keepdims=True)
+        y = (x32 * jax.lax.rsqrt(ms + epsilon)).astype(x.dtype)
+        if weight is not None:
+            y = y * weight
+        if bias is not None:
+            y = y + bias
+        return y
 
 
 def batch_norm(x, running_mean, running_var, weight=None, bias=None,

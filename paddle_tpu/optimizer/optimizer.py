@@ -93,6 +93,10 @@ class Optimizer:
         convention) go through :meth:`step`, which passes the scheduler's
         host-side lr here so both semantics hold.
         """
+        with jax.named_scope("optimizer"):
+            return self._update(grads, state, params, lr)
+
+    def _update(self, grads, state, params, lr):
         grads = clip_grads(grads, self.grad_clip)
         step = state["step"]
         if lr is None:

@@ -79,6 +79,7 @@ import jax.numpy as jnp
 
 from ..models.generation import _filter_top_p
 from ..nn.functional_call import bind_state, state
+from ..obs.parts import program_name, program_parts
 from .aot import (AOTStoreError, RECURRENT_STATE_REFUSAL,
                   aot_fingerprint, engine_aot_context)
 from .errors import EngineStalledError, RequestRejected
@@ -283,6 +284,46 @@ def _verify_tail(logits, drafts, draft_len, keys, do_sample, temperature,
         accepted = jnp.zeros(committed.shape[:1], jnp.int32)
     new_keys = jax.vmap(lambda kc, a: kc[a])(key_chain, accepted + 1)
     return committed, accepted, new_keys
+
+
+class _PartsRecorded:
+    """A jitted program (``functools.partial(jitted, weights)``) that
+    records which part of the model each of its operations belongs to,
+    once per compile: ahead of the first call at a new shape it lowers
+    and compiles THAT program over the call's own operands, reads the
+    optimized text into a table (``obs.parts.program_parts``) and hands
+    it to the telemetry, which keeps it as a ``program.parts`` span on
+    the engine lane.  jit's own call then reuses the trace this lowering
+    made (jax caches it: the engine's trace counters move once) and
+    compiles again, the first time in a checkout.  ``func`` and ``args``
+    are the partial's, for what lowers or exports the program itself."""
+
+    def __init__(self, program, metrics: ServingMetrics):
+        self.func, self.args = program.func, program.args
+        self._program, self._metrics = program, metrics
+        self._shapes = set()
+
+    def __call__(self, *args):
+        # the caches (operands 0 and 1) keep their shape for the
+        # engine's life; what is behind them is what a compile is keyed
+        # by: a chunk's ``[1, width]`` ids
+        shape = args[2].shape
+        if shape not in self._shapes:
+            self._shapes.add(shape)
+            # the compile cache's key leaves metadata out: a hit may
+            # hand back an executable another tree compiled, whose text
+            # carries that tree's ``op_name``s.  Not for this compile.
+            flag = "jax_compilation_cache_include_metadata_in_key"
+            keyed = getattr(jax.config, flag)
+            jax.config.update(flag, True)
+            try:
+                text = self.func.lower(*self.args,
+                                       *args).compile().as_text()
+            finally:
+                jax.config.update(flag, keyed)
+            self._metrics.on_program_parts(program_name(text),
+                                           program_parts(text))
+        return self._program(*args)
 
 
 class _Slot:
@@ -806,6 +847,15 @@ class EngineCore:
         sizes; at published widths it is gigabytes per prefill width."""
         return state(self.model)
 
+    def _with_parts(self, program):
+        """``program`` as a TRACED engine holds it (its tracer has an
+        ``annotate``: a device trace may be running): behind
+        :class:`_PartsRecorded`.  An untraced engine holds the program
+        itself."""
+        if self.metrics.tracer.annotate is None:
+            return program
+        return _PartsRecorded(program, self.metrics)
+
     def _build_prefill_fn(self) -> Callable:
         model, stateful = self.model, self._stateful
         routed, chunk = self._routed, self._chunk_stride
@@ -828,15 +878,19 @@ class EngineCore:
                     # goes INTO the model
                     logits, caches, state = model.decode_step(
                         ids, caches, pos, state=state, valid=valid)
-            last = jnp.take_along_axis(
-                logits, (valid - 1)[None, None, None], axis=1)[0, 0]
-            out = (last.astype(jnp.float32),
-                   [c[0] for c in caches], [c[1] for c in caches])
-            if routed:
-                # the running load, and this chunk's experts touched at
-                # the chunk's own place in the request's vector
-                return out + (load + rows, touched.at[pos // chunk].set(
-                    jnp.count_nonzero(rows).astype(jnp.int32)))
+            # what the engine adds behind the model is the program's
+            # ``sampling`` part (obs/parts.py)
+            with jax.named_scope("sampling"):
+                last = jnp.take_along_axis(
+                    logits, (valid - 1)[None, None, None], axis=1)[0, 0]
+                out = (last.astype(jnp.float32),
+                       [c[0] for c in caches], [c[1] for c in caches])
+                if routed:
+                    # the running load, and this chunk's experts touched
+                    # at the chunk's own place in the request's vector
+                    return out + (
+                        load + rows, touched.at[pos // chunk].set(
+                            jnp.count_nonzero(rows).astype(jnp.int32)))
             return out + (state,) if stateful else out
 
         # donating the staging rows (and state) threads them chunk to
@@ -1078,6 +1132,7 @@ class EngineCore:
         """Dispatch one prefill chunk of ``st`` (async — no readback)."""
         if self._prefill_fn is None:
             self._prefill_fn = self._build_prefill_fn()
+            self._prefill_fn = self._with_parts(self._prefill_fn)
         off, width, valid = st.plan[st.next_chunk]
         if self.aot_store is not None and self._warm_buckets is not None \
                 and width not in self._warm_buckets:
@@ -1111,7 +1166,7 @@ class EngineCore:
         st.next_chunk += 1
         st.req.prefill_chunks += 1
         self.progress_counter += 1              # chunk ran = progress
-        self.metrics.on_prefill_chunk(valid, seconds=t1 - t0)
+        self.metrics.on_prefill_chunk(valid)
         self.metrics.step_count("prefill_tokens", valid)
         span = self.metrics.tracer.add_span(
             "prefill_chunk", self._lane(st.req), t0, t1,
@@ -1497,25 +1552,31 @@ class EngineCore:
                     # every slot's row advances, parked ones included
                     logits, caches, state = step_fn(
                         last_tok[:, None], caches, seq_pos, state=state)
-            split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-            nxt = sample_rows(split[:, 1], logits[:, 0], do_sample,
-                              temperature, top_k, top_p, mask=mask)
-            # device-side health probe: a poisoned row reads back as the
-            # sentinel through the step's EXISTING single readback (a
-            # no-op on finite logits, so token parity is untouched)
-            nxt = finite_or_sentinel(logits[:, 0], nxt)
-            new_ks = [c[0] for c in caches]
-            new_vs = [c[1] for c in caches]
-            out = (new_ks, new_vs, _advance_live(seq_pos, caches[0][2]),
-                   nxt.astype(jnp.int32), split[:, 0])
-            if routed:
-                # what the step's ONE readback carries behind the
-                # tokens: experts that got a live row (over the expert
-                # layers) and the fullest expert's rows
-                counts = jnp.stack([jnp.count_nonzero(rows),
-                                    jnp.max(rows)]).astype(jnp.int32)
-                return out + (jnp.concatenate([out[3], counts]),
-                              load + rows)
+            # the program's tail is its ``sampling`` part
+            # (obs/parts.py): the draw, the finite check, the positions
+            # and the counters that ride the readback
+            with jax.named_scope("sampling"):
+                split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+                nxt = sample_rows(split[:, 1], logits[:, 0], do_sample,
+                                  temperature, top_k, top_p, mask=mask)
+                # device-side health probe: a poisoned row reads back as
+                # the sentinel through the step's EXISTING single
+                # readback (a no-op on finite logits, so token parity is
+                # untouched)
+                nxt = finite_or_sentinel(logits[:, 0], nxt)
+                new_ks = [c[0] for c in caches]
+                new_vs = [c[1] for c in caches]
+                out = (new_ks, new_vs,
+                       _advance_live(seq_pos, caches[0][2]),
+                       nxt.astype(jnp.int32), split[:, 0])
+                if routed:
+                    # what the step's ONE readback carries behind the
+                    # tokens: experts that got a live row (over the
+                    # expert layers) and the fullest expert's rows
+                    counts = jnp.stack([jnp.count_nonzero(rows),
+                                        jnp.max(rows)]).astype(jnp.int32)
+                    return out + (jnp.concatenate([out[3], counts]),
+                                  load + rows)
             return out + (state,) if stateful else out
 
         # donating the KV slabs (and the recurrent state, where the
@@ -1556,13 +1617,14 @@ class EngineCore:
             self.trace_counts["decode"] += 1  # trace-time side effect
             logits, new_ks, new_vs, new_pos = program(
                 weights, ks, vs, seq_pos, last_tok)
-            split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-            lg = logits[:, 0]
-            nxt = sample_rows(split[:, 1], lg, do_sample,
-                              temperature, top_k, top_p, mask=mask)
-            nxt = finite_or_sentinel(lg, nxt)
-            return (new_ks, new_vs, _advance_live(seq_pos, new_pos),
-                    nxt.astype(jnp.int32), split[:, 0])
+            with jax.named_scope("sampling"):
+                split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+                lg = logits[:, 0]
+                nxt = sample_rows(split[:, 1], lg, do_sample,
+                                  temperature, top_k, top_p, mask=mask)
+                nxt = finite_or_sentinel(lg, nxt)
+                return (new_ks, new_vs, _advance_live(seq_pos, new_pos),
+                        nxt.astype(jnp.int32), split[:, 0])
 
         return functools.partial(
             jax.jit(decode, donate_argnums=(1, 2)), weights)
@@ -1582,6 +1644,7 @@ class EngineCore:
                     f"decode:{self.decode_path}", donate=(0, 1))
             if self._decode_fn is None:
                 self._decode_fn = self._build_decode_fn()
+                self._decode_fn = self._with_parts(self._decode_fn)
         if self._sampling_dev is None:
             self._sampling_dev = (jnp.asarray(self._do_sample),
                                   jnp.asarray(self._temperature),

@@ -96,6 +96,8 @@ class ServingMetrics:
             import jax
             self.tracer.annotate = jax.profiler.TraceAnnotation
         self._step: Optional[StepSpans] = None
+        # (program, table of parts) per compile, from a traced engine
+        self._program_parts: list = []
         # disjoint lane block per engine: the step timeline sits on
         # engine_lane, request r on engine_lane + 1 + r — two engines
         # sharing one tracer never collide on a lane
@@ -179,18 +181,12 @@ class ServingMetrics:
         self._h_tpot = h("serving.tpot_s",
                          "per-output-token latency after the first",
                          unit="s")
-        self._h_step = h("serving.step_s", "engine step wall time",
-                         unit="s")
-        self._h_chunk = h("serving.prefill_chunk_s",
-                          "prefill chunk dispatch wall time", unit="s")
         self._h_queue_wait = h("serving.queue_wait_s",
                                "submit -> admission", unit="s")
         self._h_gather = h("serving.gather_s",
                            "prefix block gather / staging init", unit="s")
         self._g_queue_depth = g("serving.queue_depth",
                                 "waiting requests at the last step")
-        self._g_occupancy = g("serving.slot_occupancy",
-                              "occupied/total slots at the last step")
         # robustness surface (docs/serving.md "Fault tolerance"): the
         # terminal-status counters partition every submitted request —
         # finished + cancelled + deadline_exceeded + failed (+ rejected,
@@ -317,6 +313,10 @@ class ServingMetrics:
         for inst in (*self._own, *self._phase_h.values()):
             inst.reset()
         self.tracer.clear()
+        # a program is compiled once and its table recorded then: a
+        # window that starts after warm-up must still find it
+        for program, parts in self._program_parts:
+            self._add_program_parts(program, parts)
         self._zero_local()
 
     # ------------------------------------------------------------ events
@@ -330,14 +330,12 @@ class ServingMetrics:
         self._c_prefills.inc()
         self._c_prefill_tokens.inc(prompt_len)
 
-    def on_prefill_chunk(self, tokens: int,
-                         seconds: Optional[float] = None) -> None:
+    def on_prefill_chunk(self, tokens: int) -> None:
         """One chunk program dispatched, covering ``tokens`` real (non-
-        padding) prompt tokens over ``seconds`` of host dispatch time."""
+        padding) prompt tokens (its dispatch interval is the request
+        lane's ``prefill_chunk`` span)."""
         self._c_prefill_chunks.inc()
         self._c_prefill_chunk_tokens.inc(tokens)
-        if seconds is not None:
-            self._h_chunk.observe(seconds)
 
     def on_prefix_hit(self, tokens: int) -> None:
         """Admission matched ``tokens`` prompt tokens in the radix cache
@@ -446,6 +444,21 @@ class ServingMetrics:
 
     def set_moe_experts(self, experts: int) -> None:
         self._g_moe_experts.set(experts)
+
+    def on_program_parts(self, program: str, parts: dict) -> None:
+        """One compile of ``program`` (``jit_decode``) and its table
+        ``{operation: (part, phase)}`` (``obs.parts.program_parts``):
+        kept for the engine's life and recorded as ONE zero-length
+        ``program.parts`` span on the engine lane, again after every
+        :meth:`reset`.  What sums a device trace by part reads it
+        (``benchmarks/lib/parts.py``)."""
+        self._program_parts.append((program, parts))
+        self._add_program_parts(program, parts)
+
+    def _add_program_parts(self, program: str, parts: dict) -> None:
+        now = time.perf_counter()
+        self.tracer.add_span("program.parts", self.engine_lane, now, now,
+                             program=program, parts=parts)
 
     def on_compile(self, program: str, n: int = 1) -> None:
         self._c_compiles.inc(n)
@@ -671,8 +684,6 @@ class ServingMetrics:
         self._tokens_local += new_tokens
         self._steps_local += 1
         self._g_queue_depth.set(queue_depth)
-        self._g_occupancy.set(occupancy)
-        self._h_step.observe(step_seconds)
         if phases:
             for name, start, end in phases:
                 hp = self._phase_h.get(name)

@@ -205,6 +205,10 @@ ALLOWLIST: Dict[str, str] = {
     **{n: _OBS for n in (
         "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span",
         "Tracer",
+        # obs/parts.py: op_name and HLO-text parsing, pure string work;
+        # contract = tests/test_program_parts.py
+        "parts_on_path", "part_of", "operation_key", "program_name",
+        "program_parts",
     )},
 }
 

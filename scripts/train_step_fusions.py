@@ -23,10 +23,12 @@ leaf; those ``jax.checkpoint`` adds when it is LOWERED are not in the
 jaxpr and not counted), the program's ``code`` / ``temp`` / ``alias``
 bytes and the sum of ``estimated_cycles``.  With ``--trace DIR`` (a traced
 benchmark run of the SAME tree: the trace names operations as this
-compile does) it also sums the traced device time by part of the step:
-weight gradients, updates, forward, recomputed forward, backward, the
-head's forward with the cross-entropy (what writes a vocabulary-wide
-result), norms and elementwise, the rest (PERF.md section 5).
+compile does) it also sums the traced device time by part of the model
+and phase, from the scopes the step carries: the compiled text's table
+(``paddle_tpu.obs.parts.program_parts``) over the trace's leaf events
+(``benchmarks/lib/parts.by_part``), and the benchmark's four training
+metrics of it (``recomputed_forward_ms``, ``head_loss_ms``,
+``optimizer_ms``, ``scope_coverage.train``).
 
 With ``--steps N`` it needs a TPU and RUNS the same step instead: model,
 corpus and optimizer state from ``--seed`` as the benchmark makes them,
@@ -245,13 +247,11 @@ def parse_entry(hlo: str):
     return entry, root, with_conv
 
 
-def fusion_report(hlo: str, update_outputs, matrix_shapes,
-                  vocab: int = 0) -> dict:
+def fusion_report(hlo: str, update_outputs, matrix_shapes) -> dict:
     """The rows and counts of this script for one compiled step.
     ``update_outputs``: the indices, among the step's flat outputs, of the
     updated parameters and optimizer-state arrays; ``matrix_shapes``: the
-    dimension tuples of the parameters of two or more axes; ``vocab``:
-    the vocabulary size, for ``phases`` (see :func:`step_phase`)."""
+    dimension tuples of the parameters of two or more axes."""
     entry, root, with_conv = parse_entry(hlo)
     writers = set()
     results = entry[root]["operands"] if entry[root]["opcode"] == "tuple" \
@@ -262,7 +262,7 @@ def fusion_report(hlo: str, update_outputs, matrix_shapes,
             name = entry[name]["operands"][0]
         writers.add(name)
     wanted = {",".join(map(str, s)) for s in matrix_shapes}
-    rows, phases, cycles = [], {}, 0
+    rows, cycles = [], 0
     for name, ins in entry.items():
         cyc = re.search(r'"estimated_cycles":"(\d+)"', ins["attrs"])
         cycles += int(cyc.group(1)) if cyc else 0
@@ -273,7 +273,6 @@ def fusion_report(hlo: str, update_outputs, matrix_shapes,
         grad = ins["opcode"] == "fusion" and "transpose(" in ins["attrs"] \
             and name not in writers \
             and any(dims in wanted for _, dims in shapes)
-        phases[name] = step_phase(ins, name in writers, conv, grad, vocab)
         if ins["opcode"] != "fusion" or (name not in writers and not grad):
             continue
         bounds = re.search(r'"iteration_bounds":\[([^\]]*)\]', ins["attrs"])
@@ -286,7 +285,7 @@ def fusion_report(hlo: str, update_outputs, matrix_shapes,
                 r"\d+", bounds.group(1))] if bounds else None,
             "estimated_cycles": int(cyc.group(1)) if cyc else None})
     return {
-        "fusions": rows, "phases": phases,
+        "fusions": rows,
         "update_fusions": sum(r["writes_update"] for r in rows),
         "update_fusions_with_matmul": sum(
             r["writes_update"] and r["convolution"] for r in rows),
@@ -295,51 +294,33 @@ def fusion_report(hlo: str, update_outputs, matrix_shapes,
         "estimated_cycles": cycles}
 
 
-def step_phase(ins: dict, writes_update: bool, conv: bool, grad: bool,
-               vocab: int) -> str:
-    """The part of a training step an instruction of ENTRY belongs to,
-    from what it writes, what it holds and the ``op_name`` jax gave its
-    root (``jvp`` / ``transpose(jvp)`` / ``checkpoint/rematted_computation``).
-    A matmul fusion counts whole under its matmul's part, prologues and
-    epilogues (a residual add, a norm's statistic) included."""
-    if writes_update:
-        return "update_with_weight_gradient_matmul" if conv else "update"
-    if grad:
-        return "weight_gradient_matmul" if conv else "weight_gradient_other"
-    if vocab and re.search(rf"[\[,]{vocab}[\],]", ins["type"]):
-        return "head_forward_and_cross_entropy"
-    op_name = re.search(r'op_name="([^"]*)"', ins["attrs"])
-    op_name = op_name.group(1) if op_name else ""
-    if not (conv or "pallas_call" in op_name):
-        return "norms_and_elementwise" if ins["opcode"] == "fusion" \
-            else "data_movement_and_rest"
-    if "rematted_computation" in op_name:
-        return "recomputed_forward"
-    return "backward" if "transpose(" in op_name else "forward"
-
-
-def phases_of_trace(trace_path: str, phases: dict, prefix: str) -> dict:
-    """A traced run's device time inside the programs named ``prefix``,
-    summed by :func:`step_phase` of each operation: ``{"programs",
-    "mean_ms", "phases": {phase: [ms per program, operations]}}``.  The
-    trace names an operation by its HLO line; a name the compiled program
-    lacks (the trace is another tree's) raises."""
-    from benchmarks.lib import xplane
-    from scripts.trace_ops_by_program import ops_by_program
+def parts_of_trace(trace_path: str, table: dict, prefix: str) -> dict:
+    """A traced run's device time inside the programs named ``prefix``
+    by part and phase of ``table`` (the compiled step's
+    ``program_parts``), and the benchmark's training metrics of it.  A
+    traced name the compiled program lacks (the trace is another
+    tree's) raises."""
+    from benchmarks.lib import parts, xplane
     path = trace_path if os.path.isfile(trace_path) \
         else xplane.find_xplane(trace_path)
-    row = ops_by_program(
-        xplane.load(path), prefix,
-        key=lambda line: phases[line.split(" = ")[0].lstrip("%")])
+    row = parts.by_part(xplane.load(path), prefix, table)
     return {"programs": row["programs"], "mean_ms": row["mean_ms"],
-            "phases": {name: [round(ms, 3), round(calls)]
-                       for name, ms, calls in row["ops"]}}
+            "ops_ms": row["ops_ms"],
+            "parts": {f"{part}.{phase}": round(ms, 3)
+                      for (part, phase), ms in row["parts"].items()},
+            "largest_unscoped": row["unscoped"],
+            "recomputed_forward_ms": parts.part_ms(row, phase="recomputed"),
+            "head_loss_ms": parts.part_ms(row, "head", "loss"),
+            "optimizer_ms": parts.part_ms(row, "optimizer"),
+            "scope_coverage.train": parts.coverage_percent([row])}
 
 
 def train_step_report(builder, cfg: dict, mix: dict, optimizer: str,
                       device) -> dict:
-    """Compile ``cfg``'s step under ``mix`` for ``device`` and report."""
+    """Compile ``cfg``'s step under ``mix`` for ``device`` and report;
+    ``parts`` is the step's table ``{operation: (part, phase)}``."""
     import jax
+    from paddle_tpu.obs.parts import program_parts
     step, args = abstract_train_step(builder, cfg, mix, optimizer)
     t0 = time.perf_counter()
     traced, compiled = compile_for_chip(step, args, device)
@@ -348,13 +329,13 @@ def train_step_report(builder, cfg: dict, mix: dict, optimizer: str,
     # the step returns (new params, new state, loss): the arrays of the
     # first two, the step counter aside, are what an update writes
     leaves = jax.tree.leaves((params, ostate))
+    text = compiled.as_text()
     rep = fusion_report(
-        compiled.as_text(),
-        [i for i, leaf in enumerate(leaves) if leaf.ndim],
-        {p.shape for p in jax.tree.leaves(params) if p.ndim >= 2},
-        cfg.get("vocab_size", 0))
+        text, [i for i, leaf in enumerate(leaves) if leaf.ndim],
+        {p.shape for p in jax.tree.leaves(params) if p.ndim >= 2})
     mem = compiled.memory_analysis()
-    rep.update(grad_barriers=count_primitive(traced.jaxpr.jaxpr,
+    rep.update(parts=program_parts(text),
+               grad_barriers=count_primitive(traced.jaxpr.jaxpr,
                                              "optimization_barrier"),
                optimizer=optimizer, compile_s=round(compile_s, 1),
                gradient_leaves=len(jax.tree.leaves(params)),
@@ -398,11 +379,11 @@ def main(argv=None) -> int:
                             describe_v5e().devices[0])
     for row in rep.pop("fusions"):
         print(json.dumps(row))
-    phases = rep.pop("phases")
+    table = rep.pop("parts")
     print(json.dumps({"workload": args.workload, **rep}))
     if args.trace:
-        print(json.dumps(phases_of_trace(args.trace, phases,
-                                         mix["step_module_prefix"])))
+        print(json.dumps(parts_of_trace(args.trace, table,
+                                        mix["step_module_prefix"])))
     return 0
 
 
