@@ -44,11 +44,21 @@ rule is the design constraint):
     overwrites them.  Accepted lengths vary per slot; shapes never do.
 
 Host plane: ONE device->host readback per step phase — the decode
-harvest reads the sampled token vector once, and a step that completes
+harvest reads a sampled token vector once, and a step that completes
 prefills reads their batched first tokens once (all prefill dispatches
 stay async until then).  Admission, radix-tree matching, eviction,
 eos/length bookkeeping and metrics all run on host ints the engine
 already holds.
+
+One program ahead: nothing in the decode program needs the host between
+steps (the last tokens, positions, keys and slabs are device arrays one
+step hands to the next), so a step dispatches ITS decode program first
+and only then reads the tokens of the program the PREVIOUS step
+dispatched (``_InFlight``): while the host waits for, harvests and
+accounts one program's tokens the chip already runs the next.  Where the
+next dispatch needs this step's tokens on the host (speculation: the
+drafts come from them) the same code reads each program in the step
+that dispatched it (``EngineCore.overlap``).
 
 Per-slot sampling reuses ``generation._filter_top_p`` directly (its
 threshold broadcasts over rows) and generalises ``_filter_top_k`` to a
@@ -66,6 +76,7 @@ token, sampling included.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import math
@@ -329,16 +340,47 @@ class _PartsRecorded:
 class _Slot:
     """Host mirror of one pool slot's request progress."""
 
-    __slots__ = ("req", "pos", "match", "draft", "allowed")
+    __slots__ = ("req", "pos", "match", "draft", "allowed", "pending",
+                 "parked")
 
     def __init__(self, req: Request, prompt_len: int,
                  match: Optional[MatchResult] = None,
                  draft=None, allowed=None):
         self.req = req
-        self.pos = prompt_len       # cache length == next write offset
+        # cache length as far as the host has HARVESTED: the device's
+        # row count is ``pos + pending``
+        self.pos = prompt_len
         self.match = match          # pinned radix-cache path, if any
         self.draft = draft          # per-request NGramDraftTable (spec)
         self.allowed = allowed      # frozenset of allowed token ids
+        # programs dispatched for this request whose token the host has
+        # not read yet (0 or 1)
+        self.pending = 0
+        # the row was parked on the device ahead of the slot's release
+        self.parked = False
+
+
+class _InFlight:
+    """One dispatched decode (or verify) program whose tokens the host
+    has not read.  It carries what the harvest needs WITHOUT looking at
+    the engine's present: ``owners``, the ``{slot: _Slot}`` the program
+    computed a token for, taken at dispatch (a slot released and adopted
+    again in between has another ``_Slot``, so the older program's token
+    can never reach the newer request), and ``step``, the spans of the
+    step that dispatched it: the counts that arrive with the tokens (a
+    routing model's experts touched) land on THAT step's span beside the
+    program's other counts, whichever step reads them.  It sits in
+    ``EngineCore._inflight`` from its dispatch until its tokens are on
+    the host (or its plane is rebuilt), and leaves it once."""
+
+    __slots__ = ("toks", "owners", "step", "drafted")
+
+    def __init__(self, toks, owners: Dict[int, _Slot], step,
+                 drafted: Optional[int]):
+        self.toks = toks
+        self.owners = owners
+        self.step = step            # metrics.StepSpans of the dispatch
+        self.drafted = drafted      # draft tokens of a verify window
 
 
 class _Prefill:
@@ -786,6 +828,10 @@ class EngineCore:
                 self.metrics.tracer.event, lane=self.metrics.engine_lane)
         self._slots: Dict[int, _Slot] = {}
         self._prefills: List[_Prefill] = []      # FCFS, mid-prefill
+        # dispatched programs the host has not read, oldest first: one
+        # between steps where the engine runs a program ahead, none
+        # otherwise (a second only while a faulted step is retried)
+        self._inflight: collections.deque = collections.deque()
         # per-slot device row state (fixed [num_slots] shapes)
         self._last_tok = jnp.zeros((num_slots,), jnp.int32)
         key0 = jax.random.PRNGKey(0)
@@ -1330,7 +1376,7 @@ class EngineCore:
             # were already adopted and their first tokens sampled — a
             # raise for one must not drop the others' first tokens
             try:
-                self._emit(st.slot, tok, first_token=True)
+                self._emit(self._slots[st.slot], tok, first_token=True)
             except Exception as e:
                 self.metrics.on_fault("harvest", repr(e),
                                       step=self._step_in_flight)
@@ -1495,6 +1541,23 @@ class EngineCore:
             return None
         return np.asarray(self._expert_load)
 
+    def overlap(self):
+        """``(overlap, why not one_ahead)``: whether a step dispatches
+        its decode program BEFORE it reads the previous one's tokens
+        (``one_ahead``) or reads every program in the step that
+        dispatched it (``none``).  Decided by what the engine can see,
+        each step, in the ONE step body: the next dispatch of a
+        speculating engine needs this step's tokens on the host (the
+        drafts come from the n-gram tables the harvest feeds, and the
+        host picks verify or decode by them), so it reads first; once
+        the ladder bypasses speculation it runs ahead like every other
+        engine.  A static ``allowed_tokens`` mask needs no token.  Every
+        row of the recovery matrix (docs/serving.md) holds one program
+        ahead, so the fault configuration forces nothing here."""
+        if self.spec_on and not self.spec_bypass:
+            return "none", "speculation"
+        return "one_ahead", None
+
     def _emit_decode_block(self) -> None:
         """The discrete obs event that marks WHICH path this engine's
         single decode program compiled with (and why, on fallback) —
@@ -1508,6 +1571,7 @@ class EngineCore:
         append, append_why = self.kv_append()
         scan, scan_why = self.scan_route()
         expert, expert_why = self.expert_route()
+        overlap, overlap_why = self.overlap()
         self.metrics.on_decode_block(
             active=self.decode_path in ("fused", "tp_fused_block"),
             reason=None if not self.fused_decode
@@ -1517,7 +1581,8 @@ class EngineCore:
             attention_route=route, attention_reason=why,
             kv_append=append, kv_append_reason=append_why,
             scan_route=scan, scan_reason=scan_why,
-            expert_route=expert, expert_reason=expert_why)
+            expert_route=expert, expert_reason=expert_why,
+            overlap=overlap, overlap_reason=overlap_why)
 
     def _build_decode_fn(self) -> Callable:
         model, stateful = self.model, self._stateful
@@ -1823,12 +1888,132 @@ class EngineCore:
             return None
         return drafts, lens, total
 
+    # ------------------------------------------- one program in flight
+    def _park_ending(self) -> Dict[int, _Slot]:
+        """The slots the step's decode program runs LIVE, ``{slot:
+        _Slot}``.  A slot whose request will end by LENGTH with the
+        token still on the device (the host knows that without the
+        token) is parked first, ahead of its release, and rides the
+        program as any parked slot does; submit() holds ``prompt_len +
+        max_new_tokens`` within ``max_seq``, so the same rule keeps a
+        row one short of its end from being run past it.  An ``eos`` is
+        known only at harvest: that slot runs once more (an OVERRUN row,
+        ``_harvest_program``).  The request stays placed, and counted in
+        flight, until its last token has been emitted."""
+        live = {}
+        for slot, st in self._slots.items():
+            if not st.parked and st.pending and len(st.req.tokens) \
+                    + st.pending >= st.req.max_new_tokens:
+                self.pool.park(slot)
+                st.parked = True
+            if not st.parked:
+                live[slot] = st
+        return live
+
+    def _dispatch(self, live: Dict[int, _Slot], spec,
+                  spans) -> None:
+        """Dispatch the step's decode program (the verify program where
+        ``spec`` holds drafts) over ``live`` and put it in flight with
+        what its harvest will need; the token vector's transfer to the
+        host starts as soon as the program ends, whoever waits for it.
+        The step's span describes THIS program: its counts are taken
+        here, and what arrives with its tokens joins them at the
+        harvest (``_InFlight.step``)."""
+        counts = spans.counts
+        counts["active_slots"] = len(live)
+        counts["loop_passes"] = self.loop_passes
+        # slots whose recurrent state the program reads and writes: all
+        # of them, parked ones included
+        counts["state_slots"] = self.num_slots if self._stateful else 0
+        # free slots read False in the mirror, so this counts occupied
+        # slots only; > 0 exactly when the program's sampling branch
+        # runs
+        counts["sampling_slots"] = int(np.count_nonzero(self._do_sample))
+        # the rows the device holds: one more than the host has
+        # harvested for a slot whose last token is still unread
+        counts["live_kv_rows"] = sum(st.pos + st.pending
+                                     for st in live.values())
+        # 1 where an earlier program's tokens are still unread: this one
+        # starts behind it with no host in between
+        counts["decode_ahead"] = int(bool(self._inflight))
+        drafted = None
+        if spec is not None:
+            drafts, draft_len, drafted = spec
+            toks = self._verify_dispatch(drafts, draft_len)
+        else:
+            toks = self._decode_dispatch()
+        # a request that finished at admission (eos or length on its
+        # first token) rides live until this step's eviction: its row
+        # is nobody's
+        owners = {slot: st for slot, st in live.items()
+                  if not st.req.finished}
+        self._inflight.append(_InFlight(toks, owners, spans, drafted))
+        for st in owners.values():
+            st.pending += 1
+        toks.copy_to_host_async()
+
+    def _harvest_program(self, handle: _InFlight, toks) -> int:
+        """Hand one read program's tokens to the requests it was
+        dispatched for; returns the tokens emitted.  A row whose request
+        ended before the read (an ``eos`` found one program late, a
+        cancel or a deadline in between) is dropped: it reaches no
+        ``req.tokens``, stream, journal record or draft table."""
+        metrics = self.metrics
+        verify = handle.drafted is not None
+        if self._routed and not verify:
+            metrics.late_step_counts(
+                handle.step,
+                experts_touched=int(toks[self.num_slots]),
+                expert_rows_max=int(toks[self.num_slots + 1]))
+        # the program already advanced EVERY slot's device state: a
+        # raise mid-loop (a user stream callback, an emit bug) must not
+        # drop the LATER slots' tokens — on the watchdog's retry they
+        # would silently skip one token and desync from generate()
+        # parity.  Finish the loop, fail the implicated request,
+        # re-raise only outside the watchdog (inside it the containment
+        # is already complete — no retry needed).
+        harvest_exc = None
+        emitted = accepted_total = overrun = 0
+        for slot in sorted(handle.owners):
+            st = handle.owners[slot]
+            st.pending -= 1
+            if st.req.finished:
+                # ended since the dispatch (a stream callback may also
+                # REENTRANTLY cancel a sibling mid-loop)
+                overrun += 1
+                continue
+            try:
+                if not verify:
+                    emitted += self._harvest(st, slot, int(toks[slot]))
+                else:
+                    a = int(toks[slot, self.spec_k + 1])
+                    accepted_total += a
+                    emitted += self._harvest_window(
+                        st, slot, toks[slot, :a + 1])
+            except Exception as e:
+                metrics.on_fault("harvest", repr(e),
+                                 step=self._step_in_flight)
+                self._finalize(st.req, "failed",
+                               f"token emit failed: {e!r}")
+                if harvest_exc is None:
+                    harvest_exc = e
+        if overrun:
+            metrics.on_overrun(overrun)
+        if verify:
+            metrics.on_spec(handle.drafted, accepted_total)
+        if harvest_exc is not None and not self.fault_tolerant:
+            raise harvest_exc
+        return emitted
+
     # -------------------------------------------------------- step loop
     def step(self) -> int:
         """One engine iteration: admit (radix match + staging), advance
-        prefill chunks, one decode step over all active slots, harvest
-        tokens / evict finished.  Returns the number of requests still
-        in flight (prefilling + running + queued).
+        prefill chunks, dispatch one decode step over all live slots,
+        read and harvest the tokens of the program in flight (the
+        previous step's, one program ahead; this step's, otherwise),
+        evict finished.  Returns the number of requests still in flight
+        (prefilling + running + queued), non-zero while a dispatched
+        program is unread.
 
         With ``fault_tolerance`` configured this is the WATCHDOG
         boundary: a step exception is caught, attributed (optional
@@ -1841,7 +2026,7 @@ class EngineCore:
         if self.health.circuit_open:
             # fail-fast mode: the breaker already failed all work and
             # submit() rejects — stepping is a no-op, never a rebuild
-            return self.scheduler.active + self.scheduler.queue_depth
+            return self._work_left()
         try:
             out = self._step_impl()
         except Exception as e:
@@ -1870,7 +2055,10 @@ class EngineCore:
         too.  The step span carries the step's counts
         (``metrics.STEP_COUNTS``); trace-counter deltas / head-of-line
         skips / evictions become discrete events.  The per-slot token
-        readback stays the step's ONLY device sync."""
+        readback stays the step's ONLY device sync beside a completed
+        prefill's first tokens; one program ahead it waits for the
+        program the PREVIOUS step dispatched while this step's runs
+        behind it (``_InFlight``, ``overlap``)."""
         t0 = time.perf_counter()
         metrics = self.metrics
         step_i = self._step_index
@@ -1913,20 +2101,9 @@ class EngineCore:
             # step.first_token_readback where a prefill completed
             metrics.phase("prefill")
             new_tokens = self._advance_prefills()
-            if self._slots:
-                counts["active_slots"] = len(self._slots)
-                counts["loop_passes"] = self.loop_passes
-                # slots whose recurrent state this step's decode program
-                # reads and writes: all of them, parked ones included
-                counts["state_slots"] = self.num_slots \
-                    if self._stateful else 0
-                # free slots read False in the mirror, so this counts
-                # occupied slots only; > 0 exactly when the decode
-                # program's sampling branch runs this step
-                counts["sampling_slots"] = int(
-                    np.count_nonzero(self._do_sample))
-                counts["live_kv_rows"] = sum(
-                    st.pos for st in self._slots.values())
+            live = self._park_ending()
+            dispatched = False
+            if live:
                 # speculative draft phase (pure host, spec_on only):
                 # None -> normal decode this step, else the batched
                 # fixed-shape verify program commits up to spec_k+1
@@ -1939,7 +2116,7 @@ class EngineCore:
                 if faults is not None:
                     armed = faults.check("nan_logits")
                     if armed is not None:
-                        self._poison_slot(min(self._slots), step_i)
+                        self._poison_slot(min(live), step_i)
                 # decode faults cannot be pinned on one slot — the
                 # watchdog attributes them to the decode path (ladder
                 # candidate when fused or speculating, retry/quarantine
@@ -1957,55 +2134,24 @@ class EngineCore:
                         # fires BEFORE dispatch: nothing was mutated
                         # yet, so the ladder's retry step is clean
                         faults.fire("spec_verify")
-                if spec is not None:
-                    drafts, draft_len, drafted = spec
-                    nxt = self._verify_dispatch(drafts, draft_len)
-                else:
-                    nxt = self._decode_dispatch()
+                self._dispatch(live, spec, spans)
+                dispatched = True
+            # the programs this step leaves unread: the one it just
+            # dispatched, where the next dispatch can go without its
+            # tokens; none where it dispatched nothing (the last
+            # program drains) or reads before it dispatches
+            keep = int(dispatched and self.overlap()[0] == "one_ahead")
+            while len(self._inflight) > keep:
                 metrics.phase("readback")
-                toks = np.asarray(nxt)     # THE per-step device readback
+                # THE per-step device readback; a raise leaves the
+                # program in flight for the retried step to read
+                toks = np.asarray(self._inflight[0].toks)
+                handle = self._inflight.popleft()
                 metrics.phase("harvest")
-                if self._routed and spec is None:
-                    counts["experts_touched"] = int(toks[self.num_slots])
-                    counts["expert_rows_max"] = int(
-                        toks[self.num_slots + 1])
-                self._fault_phase = None
-                # the readback already advanced EVERY slot's device
-                # state: a raise mid-loop (a user stream callback, an
-                # emit bug) must not drop the LATER slots' tokens — on
-                # the watchdog's retry they would silently skip one
-                # token and desync from generate() parity.  Finish the
-                # loop, fail the implicated request, re-raise only
-                # outside the watchdog (inside it the containment is
-                # already complete — no retry needed).
-                harvest_exc = None
-                accepted_total = 0
-                for slot in sorted(self._slots):
-                    # a stream callback may REENTRANTLY cancel/purge a
-                    # sibling (first-of-N-wins clients): re-fetch, and
-                    # skip slots that vanished mid-loop
-                    st = self._slots.get(slot)
-                    if st is None:
-                        continue
-                    try:
-                        if spec is None:
-                            new_tokens += self._harvest(slot,
-                                                        int(toks[slot]))
-                        else:
-                            a = int(toks[slot, self.spec_k + 1])
-                            accepted_total += a
-                            new_tokens += self._harvest_window(
-                                slot, toks[slot, :a + 1])
-                    except Exception as e:
-                        metrics.on_fault("harvest", repr(e), step=step_i)
-                        self._finalize(st.req, "failed",
-                                       f"token emit failed: {e!r}")
-                        if harvest_exc is None:
-                            harvest_exc = e
-                if spec is not None:
-                    metrics.on_spec(int(drafted), accepted_total)
-                if harvest_exc is not None and not self.fault_tolerant:
-                    raise harvest_exc
+                new_tokens += self._harvest_program(handle, toks)
+            # what follows is the core's: a fault in it is no decode
+            # path's (per-slot harvest faults were contained above)
+            self._fault_phase = None
             metrics.phase("bookkeeping")
             self._evict_finished()
             if self.journal is not None:
@@ -2026,7 +2172,7 @@ class EngineCore:
             new_tokens=new_tokens,
             step_seconds=time.perf_counter() - t0,
             phases=spans.phases)
-        return self.scheduler.active + self.scheduler.queue_depth
+        return self._work_left()
 
     def _journal_progress(self) -> None:
         """Batch this step's delivered high-water marks into ONE journal
@@ -2090,7 +2236,7 @@ class EngineCore:
                 if backoff > 0:
                     time.sleep(backoff)
         self._publish_health()
-        return self.scheduler.active + self.scheduler.queue_depth
+        return self._work_left()
 
     def _subsystem_fault(self, subsystem: str, exc: Exception) -> None:
         """Count one fault against an OPTIONAL subsystem; at the ladder
@@ -2168,6 +2314,9 @@ class EngineCore:
                     self._finalize(req, "finished", req.finish_reason,
                                    now=now)
                 self._release_slot(slot, now)
+            # what the old plane's programs still owe goes with it:
+            # every request they ran for was failed above
+            self._inflight.clear()
             self._build_device_plane()
             if self.health.circuit_open:
                 self._open_circuit(reason)
@@ -2214,8 +2363,7 @@ class EngineCore:
                          lane=self.metrics.engine_lane,
                          count=skips - skips_before, step=step_i)
 
-    def _emit(self, slot: int, tok: int, first_token: bool = False) -> None:
-        st = self._slots[slot]
+    def _emit(self, st: _Slot, tok: int, first_token: bool = False) -> None:
         req = st.req
         req.tokens.append(tok)
         if st.draft is not None:
@@ -2263,12 +2411,7 @@ class EngineCore:
         elif len(req.tokens) >= req.max_new_tokens:
             req.finished, req.finish_reason = True, "length"
 
-    def _harvest(self, slot: int, tok: int) -> int:
-        st = self._slots.get(slot)
-        if st is None:
-            return 0  # reentrantly cancelled by a callback mid-harvest
-        if st.req.finished:
-            return 0  # finished at admit (eos/length on the first token)
+    def _harvest(self, st: _Slot, slot: int, tok: int) -> int:
         if tok == NONFINITE_SENTINEL:
             # the in-program finiteness probe tripped for THIS row: fail
             # exactly the implicated request (slot reclaimed by
@@ -2282,10 +2425,10 @@ class EngineCore:
                            "non-finite logits in decode")
             return 0
         st.pos += 1
-        self._emit(slot, tok)
+        self._emit(st, tok)
         return 1
 
-    def _harvest_window(self, slot: int, toks) -> int:
+    def _harvest_window(self, st: _Slot, slot: int, toks) -> int:
         """Commit one slot's verify window — its accepted draft prefix
         plus the bonus token — through the SAME per-token path as
         sequential decode (:meth:`_harvest`), in order.  The loop
@@ -2296,10 +2439,9 @@ class EngineCore:
         the full accepted length) is never read again."""
         emitted = 0
         for tok in toks:
-            st = self._slots.get(slot)
-            if st is None or st.req.finished:
+            if st.req.finished:
                 break
-            got = self._harvest(slot, int(tok))
+            got = self._harvest(st, slot, int(tok))
             if got == 0:
                 break              # sentinel failed the request
             emitted += got
@@ -2483,6 +2625,21 @@ class EngineCore:
                                now=now)
 
     # ----------------------------------------------------- conveniences
+    def has_work(self) -> bool:
+        """Whether another ``step()`` has anything to do: a request
+        queued or placed, or a dispatched program still unread (its
+        request may have ended by ``eos`` since: the read drops the row
+        and counts it)."""
+        return self.scheduler.has_work() or bool(self._inflight)
+
+    def _work_left(self) -> int:
+        """What ``step()`` returns: the requests in flight (a request
+        stays placed until its last token has been emitted), and never
+        zero while a dispatched program is unread, so that ``while
+        eng.step()`` drains it."""
+        return self.scheduler.active + self.scheduler.queue_depth \
+            or len(self._inflight)
+
     def stall_snapshot(self) -> Dict[str, object]:
         """Host-state diagnostic attached to
         :class:`~paddle_tpu.serving.errors.EngineStalledError` (and
@@ -2498,6 +2655,7 @@ class EngineCore:
             "health": self.health.state,
             "degraded_subsystems": list(self.ladder.disabled_subsystems),
             "progress_counter": self.progress_counter,
+            "programs_in_flight": len(self._inflight),
             "steps": self._step_index,
             "tensor_parallel": self.tensor_parallel,
             "speculation": self.spec_on and not self.spec_bypass,
@@ -2516,7 +2674,7 @@ class EngineCore:
         steps = 0
         stalled = 0
         last_progress = self.progress_counter
-        while self.scheduler.has_work():
+        while self.has_work():
             if max_steps is not None and steps >= max_steps:
                 raise RuntimeError(
                     f"serving did not drain within {max_steps} steps")
@@ -2528,7 +2686,7 @@ class EngineCore:
             else:
                 stalled += 1
                 if stall_steps is not None and stalled >= stall_steps \
-                        and self.scheduler.has_work():
+                        and self.has_work():
                     raise EngineStalledError(stalled,
                                              self.stall_snapshot())
         return steps

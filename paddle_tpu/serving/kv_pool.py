@@ -254,8 +254,16 @@ class KVPool:
         self.free_count += 1
         self._free.append(slot)
         self._free.sort(reverse=True)
-        # park the freed row at position 0 so its ride-along decode writes
-        # stay at the row head (bounded) until the next adopt overwrites it
+        self.park(slot)
+
+    def park(self, slot: int) -> None:
+        """Hold ``slot``'s row at position 0: the decode program rides it
+        along as a no-op (its writes stay at the row head, bounded, until
+        the next adopt overwrites it; a routing model sends it to no
+        expert).  ``free`` parks what it frees; the engine parks a slot
+        AHEAD of its release where the host already knows that the
+        token still on the device is the request's last (engine.py, "one
+        program ahead")."""
         self.seq_pos = self.seq_pos.at[slot].set(0)
 
     def reset(self) -> None:

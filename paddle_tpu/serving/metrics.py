@@ -49,7 +49,14 @@ STEP_COUNTS = ("admitted", "prefill_tokens", "prefills_completed",
                # token readback (0 for every other model): experts that
                # got at least one LIVE row, summed over the expert
                # layers, and the fullest expert's rows in any layer
-               "experts_touched", "expert_rows_max")
+               "experts_touched", "expert_rows_max",
+               # one decode program in flight (docs/serving.md "One
+               # program ahead"): 1 where this step's decode program was
+               # dispatched before the previous one's tokens were read;
+               # tokens this step read and dropped because their request
+               # had ended before the read (a program ran its row once
+               # more than the request needed)
+               "decode_ahead", "overrun_tokens")
 
 # admission-projection clamps: a degenerate measurement window (one
 # finish inside a denormal-small busy window, or a finish against an
@@ -284,6 +291,10 @@ class ServingMetrics:
         self._c_spec_accept = c("spec.accepted_tokens",
                                 "draft tokens the verify program "
                                 "accepted (free decode steps)")
+        self._c_overrun = c("serving.overrun_tokens",
+                            "tokens read and dropped because their "
+                            "request had ended before the read (the "
+                            "next program was already in flight)")
         self._last_health_state: Optional[str] = None
         self._phase_h: Dict[str, Histogram] = {}
         self._zero_local()
@@ -358,7 +369,9 @@ class ServingMetrics:
                         scan_route: str = "",
                         scan_reason: Optional[str] = None,
                         expert_route: str = "",
-                        expert_reason: Optional[str] = None) -> None:
+                        expert_reason: Optional[str] = None,
+                        overlap: str = "",
+                        overlap_reason: Optional[str] = None) -> None:
         """The engine resolved its decode path (emitted once, when the
         single decode program is built): ``active`` says whether the
         fused decode-block kernels compiled in, ``reason`` carries the
@@ -383,7 +396,12 @@ class ServingMetrics:
         over its experts, likewise (``gmm`` / ``ragged_dot``,
         distributed/moe_dropless.py; empty for a model without expert
         layers) and ``expert_reason`` why the decode program's is not
-        the kernel.  Lands
+        the kernel; ``overlap`` whether the engine dispatches a step's
+        decode program before it reads the previous one's tokens
+        (``one_ahead``) or reads each program before the next
+        (``none``), and ``overlap_reason`` why not ``one_ahead``
+        (``speculation``: the next dispatch needs this step's tokens on
+        the host).  Lands
         as a ``decode_block`` discrete event on the engine lane
         (glossary: docs/observability.md)."""
         self.tracer.event("decode_block", lane=self.engine_lane,
@@ -397,7 +415,9 @@ class ServingMetrics:
                           scan_route=scan_route,
                           scan_reason=scan_reason or "",
                           expert_route=expert_route,
-                          expert_reason=expert_reason or "")
+                          expert_reason=expert_reason or "",
+                          overlap=overlap,
+                          overlap_reason=overlap_reason or "")
 
     def on_aot_load(self, programs: int, seconds: float,
                     build_s: Optional[float] = None) -> None:
@@ -532,6 +552,15 @@ class ServingMetrics:
         self._spec_draft_local += drafted
         self._spec_accept_local += accepted
 
+    def on_overrun(self, tokens: int) -> None:
+        """A harvest dropped ``tokens`` rows whose request had ended
+        before their program was read (an ``eos`` the host learnt one
+        program late, a cancel or a deadline in between): work the chip
+        did, never a result.  Also ``overrun_tokens`` on the step's
+        span."""
+        self._c_overrun.inc(tokens)
+        self._step.counts["overrun_tokens"] += tokens
+
     def on_spec_disable(self, reason: str) -> None:
         """The degradation ladder (or an unsatisfiable constraint)
         turned speculation off — drop the discrete event so the trace
@@ -652,6 +681,17 @@ class ServingMetrics:
         """Add ``n`` to one of :data:`STEP_COUNTS` of the step in flight
         (a host int the caller already holds — never a device value)."""
         self._step.counts[key] += n
+
+    def late_step_counts(self, st: StepSpans, **counts: int) -> None:
+        """Counts of step ``st``'s decode program that arrive WITH its
+        tokens (a routing model's ``experts_touched`` /
+        ``expert_rows_max``).  One program ahead the tokens are read a
+        step later, when ``st``'s span has closed: the counts still land
+        on THAT span, so that every count on one ``serving.step`` span
+        that describes a decode program describes the same program."""
+        st.counts.update(counts)
+        if st._root is not None:
+            st._root.attrs.update(counts)
 
     def end_step(self, st: StepSpans) -> None:
         """Close the open phase, put the counts on the ``serving.step``
@@ -832,4 +872,6 @@ class ServingMetrics:
             "spec_draft_tokens": self._c_spec_draft.value,
             "spec_accepted_tokens": self._c_spec_accept.value,
             "spec_acceptance_rate": r(self.spec_acceptance_rate),
+            # one decode program in flight (keys only ever ADD)
+            "overrun_tokens": self._c_overrun.value,
         }
