@@ -21,6 +21,16 @@ block that holds the row's place while it streams, sets the row in
 VMEM, and writes the block back when the stream has ended.  A parked
 slot (``pos`` 0) streams nothing.
 
+A cached prefill CHUNK (``latent_chunk_attention``) attends the same way
+after the XLA append has put its rows into the request's staging: a grid
+step takes ``QUERY_TILE`` positions under all the heads (one shared row
+under every head, so ``QUERY_TILE x heads`` MXU rows) and streams the
+staging's tiles from row 0 up to the tile that holds its last query's
+own row, never the ``max_seq`` rows past it.  Within the last tile a
+score is masked by ``key <= pos + query`` and a row past the tile's last
+query is zeroed before it reaches a product, so whatever the staging
+holds there never reaches the result.
+
 ``decode_attention.py``'s kernels do not serve this cache: 32 queries a
 KV head is past ``SLAB_MAX_QUERIES``, 576 fails ``mosaic_slab_rule``'s
 ``head_dim % 128``, and they hold K and V apart.
@@ -37,11 +47,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["latent_decode_attention", "latent_attention_route",
-           "TILE_ROWS"]
+           "latent_chunk_attention", "latent_chunk_route", "attended_rows",
+           "TILE_ROWS", "QUERY_TILE"]
 
 # positions one VMEM tile of the stream holds (two are in flight):
 # 512 x 640 lanes x 2 B = 655 KB each
 TILE_ROWS = 512
+# chunk positions one grid step of the chunk kernel takes, under every
+# head: 32 x 32 heads = 1,024 MXU rows, a [1024, 640] bfloat16 query
+# tile (1.3 MB) and a [1024, 512] float32 accumulator (2 MB).  At 64
+# the program needs over 24 MiB of VMEM and the compiler's static
+# schedule is no shorter per row
+QUERY_TILE = 32
+# scoped VMEM the chunk kernel may use: its blocks double-buffered, the
+# row tiles, the statistics and a [1024, 512] float32 score tile's
+# temporaries (Mosaic needs between 12 and 16 MiB at the JoyAI widths)
+CHUNK_VMEM_LIMIT = 24 * 1024 * 1024
 # the aligned block of positions the fresh row is written back in (a
 # packed bfloat16 tile is 16 sublanes)
 WRITE_ROWS = 16
@@ -72,11 +93,63 @@ def latent_attention_route(slab_shape, dtype):
     return "latent_in_place", None
 
 
+def latent_chunk_route(staging_shape, width: int, dtype):
+    """``(route, reason)`` of a cached prefill chunk's attention over a
+    request's latent staging ``[1, max_seq, 1, row width]``, traced
+    HERE: ``("latent_chunk", None)`` or ``("xla_dense", why)``.  The
+    decode kernel's rules (the flag, one row head, the dtype, whole
+    16-row blocks), and the chunk must fit the staging.  Static per
+    compiled program, a function of shape, width and dtype."""
+    route, why = latent_attention_route(staging_shape, dtype)
+    if route != "latent_in_place":
+        return route, why
+    if width > staging_shape[1]:
+        return "xla_dense", (f"chunk width {width} is past max_seq "
+                             f"{staging_shape[1]}")
+    return "latent_chunk", None
+
+
 def _tile_rows(max_seq: int) -> int:
     bk = min(TILE_ROWS, max_seq)
     while max_seq % bk:
         bk //= 2
     return bk
+
+
+def _key_tiles(end, bk: int):
+    """Row tiles that hold positions ``[0, end)``: a Python int on the
+    host, a traced scalar in the kernel."""
+    return (end + bk - 1) // bk
+
+
+def attended_rows(max_seq: int, offset: int, width: int) -> int:
+    """Staging rows a layer of the chunk kernel streams for a chunk of
+    ``width`` positions appended at ``offset``: whole tiles up to the one
+    that holds the chunk's last row (what its last query tile reads)."""
+    bk = _tile_rows(max_seq)
+    return min(_key_tiles(offset + width, bk), max_seq // bk) * bk
+
+
+def _fetch(rows_any, buf, sem, b, slot, ki, bk: int):
+    """The DMA of row tile ``ki`` of slab row ``b`` into ``buf[slot]``."""
+    return pltpu.make_async_copy(rows_any.at[b, pl.ds(ki * bk, bk)],
+                                 buf.at[slot], sem.at[slot])
+
+
+def _fold(state, s, values):
+    """One online-softmax step: scores ``s [n, k]`` (masked), ``values
+    [k, lora]`` in the rows' dtype, ``state`` the float32 ``(m [n, 1],
+    l [n, 1], acc [n, lora])``."""
+    m_prev, l_prev, acc = state
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_safe = jnp.where(m_next == _NEG_INF, 0.0, m_next)
+    p = jnp.exp(s - m_safe)
+    alpha = jnp.exp(m_prev - m_safe)
+    pv = jax.lax.dot_general(
+        p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+        precision=_MXU, preferred_element_type=jnp.float32)
+    return (m_next, alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
+            acc * alpha + pv)
 
 
 def _kernel(len_ref, q_ref, new_ref, rows_any, o_ref, out_any, buf, wbuf,
@@ -96,27 +169,11 @@ def _kernel(len_ref, q_ref, new_ref, rows_any, o_ref, out_any, buf, wbuf,
     nlive = jax.lax.div(n_rows + bk - 1, bk)
 
     def fetch(slot, ki):
-        return pltpu.make_async_copy(
-            rows_any.at[b, pl.ds(ki * bk, bk)], buf.at[slot],
-            rsem.at[slot])
+        return _fetch(rows_any, buf, rsem, b, slot, ki, bk)
 
     @pl.when(nlive > 0)
     def _prefetch():
         fetch(0, 0).start()
-
-    def fold(state, s, values):
-        """One online-softmax step: scores ``s [H, n]`` (masked),
-        ``values [n, lora]`` in the slab's dtype."""
-        m_prev, l_prev, acc = state
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_safe = jnp.where(m_next == _NEG_INF, 0.0, m_next)
-        p = jnp.exp(s - m_safe)
-        alpha = jnp.exp(m_prev - m_safe)
-        pv = jax.lax.dot_general(
-            p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
-            precision=_MXU, preferred_element_type=jnp.float32)
-        return (m_next, alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
-                acc * alpha + pv)
 
     def tile(ki, state):
         slot = jax.lax.rem(ki, 2)
@@ -132,7 +189,7 @@ def _kernel(len_ref, q_ref, new_ref, rows_any, o_ref, out_any, buf, wbuf,
             preferred_element_type=jnp.float32) * scale     # [H, bk]
         kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         s = jnp.where(kpos < n_rows, s, _NEG_INF)
-        return fold(state, s, rows[:, :lora])
+        return _fold(state, s, rows[:, :lora])
 
     init = (jnp.full((heads, 1), _NEG_INF, jnp.float32),
             jnp.zeros((heads, 1), jnp.float32),
@@ -206,3 +263,94 @@ def latent_decode_attention(q, new_row, slab, pos, *, lora: int,
         interpret=interpret,
     )(jnp.asarray(pos, jnp.int32), q, new_row.reshape(b, 1, w), flat)
     return ol, flat.reshape(slab.shape)
+
+
+def _query_tile(width: int) -> int:
+    tq = min(QUERY_TILE, width)
+    while width % tq:
+        tq //= 2
+    return tq
+
+
+def _chunk_kernel(pos_ref, q_ref, rows_any, o_ref, buf, m_ref, l_ref, rsem,
+                  *, S, bk, tq, heads, lora, scale):
+    """Row ``b`` of the staging, query tile ``qi``: ``q_ref [1, tq x
+    heads, w]`` (position-major: MXU row ``r`` is position ``r //
+    heads``), ``rows_any [b, S, w]`` the staging with the chunk's rows
+    in it, ``pos_ref`` the rows held BEFORE the chunk.  ``o_ref [1, tq x
+    heads, lora]`` float32 is the accumulator."""
+    b, qi = pl.program_id(0), pl.program_id(1)
+    first = pos_ref[b] + qi * tq            # the tile's first query's row
+    end = first + tq                        # rows [0, end) reach the tile
+    nk = jnp.minimum(_key_tiles(end, bk), S // bk)
+    _fetch(rows_any, buf, rsem, b, 0, 0, bk).start()
+    n = tq * heads
+    m_ref[...] = jnp.full((n, 1), _NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros((n, 1), jnp.float32)
+    o_ref[0] = jnp.zeros((n, lora), jnp.float32)
+    # the last row each MXU row's query sees
+    last = first + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0) // heads
+
+    def tile(ki, carry):
+        slot = jax.lax.rem(ki, 2)
+
+        @pl.when(ki + 1 < nk)
+        def _next():
+            _fetch(rows_any, buf, rsem, b, 1 - slot, ki + 1, bk).start()
+
+        _fetch(rows_any, buf, rsem, b, slot, ki, bk).wait()
+        at = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        rows = jnp.where(at < end, buf[slot], 0)            # [bk, w]
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())), precision=_MXU,
+            preferred_element_type=jnp.float32) * scale     # [n, bk]
+        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        s = jnp.where(kpos <= last, s, _NEG_INF)
+        m_ref[...], l_ref[...], o_ref[0] = _fold(
+            (m_ref[...], l_ref[...], o_ref[0]), s, rows[:, :lora])
+        return carry
+
+    jax.lax.fori_loop(0, nk, tile, 0)
+    o_ref[0] = o_ref[0] / l_ref[...]
+
+
+def latent_chunk_attention(q, staging, pos, *, lora: int, scale: float,
+                           interpret: Optional[bool] = None):
+    """A cached prefill chunk's queries against the latent rows held,
+    its own rows included (appended before the call).
+
+    ``q [b, s, heads, w]`` (the absorbed queries ``[W_uk^T qn, R(qr)]``,
+    the staging's dtype), ``staging [b, max_seq, 1, w]``, ``pos [b]``
+    int32 the rows each staging row held BEFORE the chunk.  Returns ``ol
+    [b, s, heads, lora]`` float32: ``ol_i = sum_r p_r c_r`` over the rows
+    ``r <= pos + i``, each query tile reading the staging only up to the
+    tile that holds its last query's row (:func:`attended_rows`)."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    b, s, heads, w = q.shape
+    max_seq = staging.shape[1]
+    bk, tq = _tile_rows(max_seq), _query_tile(s)
+    n = tq * heads
+    kernel = functools.partial(_chunk_kernel, S=max_seq, bk=bk, tq=tq,
+                               heads=heads, lora=lora, scale=scale)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, s // tq),
+        in_specs=[pl.BlockSpec((1, n, w), lambda bi, qi, p: (bi, qi, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, n, lora), lambda bi, qi, p: (bi, qi, 0)),
+        scratch_shapes=[pltpu.VMEM((2, bk, w), staging.dtype),
+                        pltpu.VMEM((n, 1), jnp.float32),
+                        pltpu.VMEM((n, 1), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,))])
+    ol = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, s * heads, lora), jnp.float32),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=CHUNK_VMEM_LIMIT),
+        name="latent_chunk_attention",
+        interpret=interpret,
+    )(jnp.asarray(pos, jnp.int32), q.reshape(b, s * heads, w),
+      staging.reshape(b, max_seq, w))
+    return ol.reshape(b, s, heads, lora)

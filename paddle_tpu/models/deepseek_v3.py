@@ -36,7 +36,9 @@ R(kr_s)``, ``ol_i = sum_s p_s c_s``, ``o_i = W_uv,i ol_i``: one shared
 576-wide "KV head" under all the query heads whose V is the first
 ``kv_lora_rank`` columns of its K, never expanded.  A decode step runs
 it in ``kernels/latent_attention.py`` (the slot's live rows streamed
-once, the fresh row appended in place), a chunk as two XLA dots.  The
+once, the fresh row appended in place), a cached chunk in the same
+module's chunk kernel (each query tile streams the rows up to its own
+causal edge), a chunk that attends to itself alone as two XLA dots.  The
 EXPANDED form (K and V rebuilt per head from the rows held, 192-wide
 scores) is what the plain reference of
 ``benchmarks/builders/deepseek_v3.py`` computes and the tests hold this
@@ -68,7 +70,10 @@ import jax.numpy as jnp
 
 from ..distributed.moe_dropless import (DroplessMoE, GatedMLP, _wide,
                                         grouped_matmul_route)
-from ..kernels.latent_attention import (latent_attention_route,
+from ..kernels.latent_attention import (attended_rows,
+                                        latent_attention_route,
+                                        latent_chunk_attention,
+                                        latent_chunk_route,
                                         latent_decode_attention)
 from ..nn import initializer as I
 from ..nn.layer import Layer, ParamAttr
@@ -314,13 +319,25 @@ class DeepseekV3Attention(Layer):
                 buf = append_rows(buf, row, pos)
             held, lens = buf[:, :, 0], cache_lens(pos, s, b)
             new_cache = (buf, None, pos + s)
-        outs = []
-        for q0 in range(0, s, ATTN_QUERY_BLOCK):
-            q1 = min(q0 + ATTN_QUERY_BLOCK, s)
-            outs.append(self.absorbed(
-                qn[:, q0:q1], qr[:, q0:q1], held,
-                _seen(lens, s, held.shape[1], q0, q1 - q0)))
-        o = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+        if cache is not None and latent_chunk_route(
+                buf.shape, s, buf.dtype)[0] == "latent_chunk":
+            # the chunk's queries read the rows up to their own causal
+            # edge, not all max_len
+            w_uk, w_uv = self._up()
+            ol = latent_chunk_attention(
+                self._absorbed_query(qn, qr, w_uk), buf,
+                jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,)),
+                lora=cfg.kv_lora_rank, scale=self.softmax_scale)
+            o = jnp.einsum("bshc,chv->bshv", ol.astype(x.dtype), w_uv,
+                           preferred_element_type=jnp.float32)
+        else:
+            outs = []
+            for q0 in range(0, s, ATTN_QUERY_BLOCK):
+                q1 = min(q0 + ATTN_QUERY_BLOCK, s)
+                outs.append(self.absorbed(
+                    qn[:, q0:q1], qr[:, q0:q1], held,
+                    _seen(lens, s, held.shape[1], q0, q1 - q0)))
+            o = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
         o = o.astype(x.dtype).reshape(b, s, cfg.num_heads * cfg.v_head_dim)
         return _wide(self.o_proj, o), new_cache
 
@@ -483,6 +500,22 @@ class DeepseekV3ForCausalLM(Layer):
         """``(route, reason)`` of a decode step's attention over the
         latent slabs (``kernels.latent_attention``)."""
         return latent_attention_route(slab_shape, dtype)
+
+    def chunk_attention_route(self, staging_shape, width: int, dtype):
+        """``(route, reason)`` of a cached prefill chunk's attention over
+        a request's latent staging (``kernels.latent_attention``)."""
+        return latent_chunk_route(staging_shape, width, dtype)
+
+    def attended_rows(self, staging_shape, offset: int, width: int,
+                      dtype) -> int:
+        """Staging rows a layer of that chunk's program reads: whole row
+        tiles up to the chunk's last row on the kernel, every row on
+        ``xla_dense``."""
+        max_seq = staging_shape[1]
+        if latent_chunk_route(staging_shape, width, dtype)[0] \
+                == "latent_chunk":
+            return attended_rows(max_seq, offset, width)
+        return max_seq
 
     def serving_refusals(self) -> dict:
         """Engine features that hold K and V rows of one shape, or a

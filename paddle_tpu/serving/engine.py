@@ -1214,6 +1214,11 @@ class EngineCore:
         self.progress_counter += 1              # chunk ran = progress
         self.metrics.on_prefill_chunk(valid)
         self.metrics.step_count("prefill_tokens", valid)
+        rows_of = getattr(self.model, "attended_rows", None)
+        # staging rows a layer of the chunk's program read, where the
+        # model says
+        attended = {} if rows_of is None else {"attended_rows": rows_of(
+            self._staging_shape(), off, width, self.pool.ks[0].dtype)}
         span = self.metrics.tracer.add_span(
             "prefill_chunk", self._lane(st.req), t0, t1,
             chunk=st.next_chunk - 1, width=width, tokens=valid,
@@ -1221,7 +1226,8 @@ class EngineCore:
             # whether the chunk started from an earlier chunk's
             # recurrent state (False on a request's first, and always
             # for a model that carries none)
-            state_carried=self._stateful and st.next_chunk > 1)
+            state_carried=self._stateful and st.next_chunk > 1,
+            **attended)
         if self._routed and span is not None:
             # ``experts_touched`` lands with the first token's readback
             st.chunk_spans.append((off // self._chunk_stride, span))
@@ -1532,6 +1538,22 @@ class EngineCore:
         step, why = route_of(self.num_slots)
         return f"prefill={chunk},decode={step}", why
 
+    def prefill_attention_route(self):
+        """``(route, reason)`` of the prefill programs' attention over a
+        request's staging at the widest chunk, ``("", None)`` for a
+        model that declares none (``model.chunk_attention_route``,
+        static per compiled program like :meth:`attention_route`)."""
+        route_of = getattr(self.model, "chunk_attention_route", None)
+        if route_of is None:
+            return "", None
+        return route_of(self._staging_shape(), self._widest_chunk(),
+                        self.pool.ks[0].dtype)
+
+    def _staging_shape(self):
+        """One request's staging rows of one layer, as the prefill
+        programs see them: a slot slab's shape for ONE row."""
+        return (1,) + tuple(self.pool.ks[0].shape[1:])
+
     def expert_load(self):
         """Rows each expert got since the engine was built, ``[expert
         layers, experts]`` (decode steps and prefill chunks, live rows
@@ -1571,6 +1593,7 @@ class EngineCore:
         append, append_why = self.kv_append()
         scan, scan_why = self.scan_route()
         expert, expert_why = self.expert_route()
+        prefill, prefill_why = self.prefill_attention_route()
         overlap, overlap_why = self.overlap()
         self.metrics.on_decode_block(
             active=self.decode_path in ("fused", "tp_fused_block"),
@@ -1582,6 +1605,8 @@ class EngineCore:
             kv_append=append, kv_append_reason=append_why,
             scan_route=scan, scan_reason=scan_why,
             expert_route=expert, expert_reason=expert_why,
+            prefill_attention_route=prefill,
+            prefill_attention_reason=prefill_why,
             overlap=overlap, overlap_reason=overlap_why)
 
     def _build_decode_fn(self) -> Callable:

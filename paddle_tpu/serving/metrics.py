@@ -370,6 +370,8 @@ class ServingMetrics:
                         scan_reason: Optional[str] = None,
                         expert_route: str = "",
                         expert_reason: Optional[str] = None,
+                        prefill_attention_route: str = "",
+                        prefill_attention_reason: Optional[str] = None,
                         overlap: str = "",
                         overlap_reason: Optional[str] = None) -> None:
         """The engine resolved its decode path (emitted once, when the
@@ -396,7 +398,13 @@ class ServingMetrics:
         over its experts, likewise (``gmm`` / ``ragged_dot``,
         distributed/moe_dropless.py; empty for a model without expert
         layers) and ``expert_reason`` why the decode program's is not
-        the kernel; ``overlap`` whether the engine dispatches a step's
+        the kernel; ``prefill_attention_route`` how a model that
+        declares one attends over a request's staging in the prefill
+        programs (``latent_chunk``: kernels/latent_attention.py's chunk
+        kernel, which reads the rows up to each query tile's causal
+        edge; ``xla_dense``: every row; empty for every other model)
+        and ``prefill_attention_reason`` why not the kernel;
+        ``overlap`` whether the engine dispatches a step's
         decode program before it reads the previous one's tokens
         (``one_ahead``) or reads each program before the next
         (``none``), and ``overlap_reason`` why not ``one_ahead``
@@ -416,6 +424,9 @@ class ServingMetrics:
                           scan_reason=scan_reason or "",
                           expert_route=expert_route,
                           expert_reason=expert_reason or "",
+                          prefill_attention_route=prefill_attention_route,
+                          prefill_attention_reason=(
+                              prefill_attention_reason or ""),
                           overlap=overlap,
                           overlap_reason=overlap_reason or "")
 

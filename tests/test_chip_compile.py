@@ -290,6 +290,22 @@ def test_latent_decode_attention(topo):
     assert mem.temp_size_in_bytes < (1 << 20), mem.temp_size_in_bytes
 
 
+@pytest.mark.parametrize("width", [512, 16])
+def test_latent_chunk_attention(topo, width):
+    """The latent-row chunk kernel over a request's 4096-row staging of
+    640 lanes, 32 query heads, at the cell's chunk and at a narrow last
+    chunk: Mosaic takes it under the scoped VMEM it states, and the
+    program holds nothing but its output."""
+    from paddle_tpu.kernels.latent_attention import latent_chunk_attention
+    s = _one(topo)
+    mem = _compile(functools.partial(latent_chunk_attention, lora=512,
+                                     scale=192 ** -0.5, interpret=False),
+                   s((1, width, 32, 640)), s((1, 4096, 1, 640)),
+                   s((1,), jnp.int32))
+    assert mem.output_size_in_bytes == width * 32 * 512 * 4
+    assert mem.temp_size_in_bytes < (1 << 20), mem.temp_size_in_bytes
+
+
 def test_latent_expert_decode_step_prefill_chunk_and_reference(
         topo, monkeypatch, capsys):
     """JoyAI-LLM-Flash at the cell's depth (5 layers, every expert,

@@ -221,6 +221,54 @@ def test_the_decode_kernel_is_the_absorbed_attention(ids, monkeypatch):
         == ("xla_dense", "max_seq 40 is not a multiple of 16")
 
 
+@pytest.mark.parametrize("offset,width", [
+    (0, 64), (488, 64), (960, 64), (0, 8), (509, 8), (1016, 8)],
+    ids=["full_first", "full_mid", "full_last", "narrow_first",
+         "narrow_mid", "narrow_last"])
+def test_the_chunk_kernel_is_the_absorbed_attention(offset, width,
+                                                     monkeypatch):
+    """``kernels/latent_attention.py``'s chunk kernel (interpreted) in a
+    cached chunk's attention branch, against the XLA absorbed form with
+    ``_seen`` on the same rows: a staging of 1,024 rows (two row tiles)
+    whose rows held before the chunk are random and whose rows at and
+    past ``offset + width`` hold NaN.  The kernel's output stays finite
+    (nothing past the chunk's last row reaches it) where the XLA form's
+    does not (``p = 0`` times NaN), at chunks of two query tiles and of
+    part of one, at the staging's start, across the tiles' boundary and
+    at its end."""
+    from paddle_tpu.kernels.latent_attention import latent_chunk_route
+    model = make_model()
+    cfg = model.cfg
+    attn = model.model.layers[1].self_attn
+    rng = np.random.default_rng(offset + width)
+    u = jnp.asarray(rng.normal(size=(1, width, cfg.hidden_size)),
+                    jnp.float32)
+    cos, sin = ds._rope_tables(offset + jnp.arange(width),
+                               cfg.qk_rope_head_dim, cfg.rope_theta,
+                               jnp.float32)
+    rows = rng.normal(size=(1, 1024, 1, cfg.cache_row_width))
+    rows[:, offset:] = np.nan
+    staging = jnp.asarray(rows, jnp.float32)
+    clean = staging.at[:, offset + width:].set(0.0)
+    assert latent_chunk_route(staging.shape, width, jnp.float32) \
+        == ("latent_chunk", None)
+    got, (kept, _, _) = attn(u, cos, sin, (staging, None, offset))
+    with monkeypatch.context() as m:
+        m.setattr(flags, "pallas_routing", "never")
+        assert latent_chunk_route(staging.shape, width, jnp.float32) \
+            == ("xla_dense", "FLAGS_pallas_routing=never")
+        want, (held, _, _) = attn(u, cos, sin, (clean, None, offset))
+        dense = attn(u, cos, sin, (staging, None, offset))[0]
+    assert np.isfinite(np.asarray(got)).all()
+    assert rel_err(got, np.asarray(want)) <= 1e-5
+    end = offset + width
+    np.testing.assert_array_equal(np.asarray(kept[:, :end]),
+                                  np.asarray(held[:, :end]))
+    assert np.isfinite(np.asarray(dense)).all() == (end == 1024)
+    assert latent_chunk_route((1, 1024, 1, 128), 2048, jnp.float32) \
+        == ("xla_dense", "chunk width 2048 is past max_seq 1024")
+
+
 def test_the_rotary_pairing_is_the_interleaved_one():
     """De-interleaving then rotating halves is the rotation of the pairs
     ``(2j, 2j+1)`` in another column order, so ``q . k`` is the
@@ -748,6 +796,8 @@ def test_spans_counts_gauges_and_the_load():
         assert [(s.attrs["chunk"], s.attrs["width"], s.attrs["tokens"],
                  s.attrs["offset"]) for s in chunks] \
             == [(0, 16, 16, 0), (1, 16, 16, 16), (2, 8, 5, 32)]
+        # 96 rows are ONE row tile: each chunk's program read all of it
+        assert [s.attrs["attended_rows"] for s in chunks] == [96] * 3
         # the padded chunk's 3 pad tokens reach no expert: at most
         # 5 tokens x top-2 x 2 layers
         assert all(0 < s.attrs["experts_touched"] <= 16 for s in chunks)
@@ -758,8 +808,24 @@ def test_spans_counts_gauges_and_the_load():
         assert "no TPU" in event["expert_reason"]
         assert event["attention_route"] == "latent_in_place"
         assert event["kv_append"] == "in_kernel"
+        assert (event["prefill_attention_route"],
+                event["prefill_attention_reason"]) == ("latent_chunk", "")
         assert eng.core.attention_route() == ("latent_in_place", None)
+        assert eng.core.prefill_attention_route() == ("latent_chunk", None)
         assert eng.core.expert_route()[0] == event["expert_route"]
+        # at the cell's geometry: whole 512-row tiles up to the chunk's
+        # last row, every row where the kernel does not run
+        bf16 = jnp.bfloat16
+        assert [model.attended_rows((1, 4096, 1, 640), off, w, bf16)
+                for off, w in ((0, 512), (0, 64), (1024, 512),
+                               (2560, 16), (3584, 512))] \
+            == [512, 512, 1536, 3072, 4096]
+        routing, flags.pallas_routing = flags.pallas_routing, "never"
+        try:
+            assert model.attended_rows((1, 4096, 1, 640), 0, 512,
+                                       bf16) == 4096
+        finally:
+            flags.pallas_routing = routing
         load = eng.core.expert_load()
         assert load.shape == (2, 8)
         # 37 prompt tokens and 4 decode steps (the first token comes
@@ -776,6 +842,13 @@ def test_spans_counts_gauges_and_the_load():
         eng.serve_batch([np.arange(5)], max_new_tokens=3)
         assert eng.core.expert_load() is None
         assert eng.core.expert_route() == ("", None)
+        assert eng.core.prefill_attention_route() == ("", None)
+        event, = [a for name, _, _, a in eng.tracer.events()
+                  if name == "decode_block"]
+        assert event["prefill_attention_route"] == ""
+        chunks = [s for s in eng.tracer.spans() if s.name == "prefill_chunk"]
+        assert chunks and all("attended_rows" not in s.attrs
+                              for s in chunks)
         snap = eng.registry.snapshot()
         assert snap["serving.moe.experts"] == 0
         assert snap["serving.cache.row_bytes"] == 2 * 2 * 4 * 16 * 4
